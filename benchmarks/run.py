@@ -781,7 +781,7 @@ def telemetry_bench(json_path: str = "BENCH_telemetry.json",
         print(f"# telemetry: achieved GB/s vs roofline "
               f"({hw.hbm_bw / 1e9:.0f} GB/s), spans -> "
               f"{trace_path or '<memory ring>'}")
-        policy = PipePolicy(mode="ff", interpret=True)
+        policy = PipePolicy(mode="ff")
 
         def check_util(label, util):
             if not (0.0 < util["utilization"] <= 1.0):
@@ -1131,7 +1131,9 @@ def main() -> None:
                  "telemetry_json", "trace_jsonl"):
         setattr(args, flag, _resolve_out(getattr(args, flag), args.out_dir))
     if args.sharded and "jax" not in sys.modules:
-        # must land before the first jax import anywhere in the process
+        # CPU-only: forced host devices stand in for a mesh (a TPU run gets
+        # its devices from the chip); must land before the first jax
+        # import anywhere in the process
         flags = os.environ.get("XLA_FLAGS", "")
         if "xla_force_host_platform_device_count" not in flags:
             os.environ["XLA_FLAGS"] = \

@@ -27,11 +27,12 @@ that iterate a set of pipes. Two access patterns are covered:
 * :class:`RingPipe` — regular block copies. ``streams > 1`` splits each
   word into disjoint row ranges issued as concurrent DMAs (the paper's
   multi-producer M2C2 design, static load balancing).
-* :class:`GatherRingPipe` — irregular per-row gathers. Each word is a
-  bundle of ``tile[0]`` single-row DMAs whose source rows come from a
-  dynamically-indexed slicer (scalar-prefetched indices); the row bundle is
-  the stream decomposition, giving ``depth x rows`` outstanding requests of
-  memory-level parallelism (the burst-coalesced-LSU analogue).
+* :class:`GatherRingPipe` — irregular gathers. Each word is a bundle of
+  ``tile[0]`` DMAs (single rows, or whole blocks such as KV pages) whose
+  sources come from a dynamically-indexed slicer (scalar-prefetched
+  indices); the bundle is the stream decomposition, giving
+  ``depth x tile[0]`` outstanding requests of memory-level parallelism (the
+  burst-coalesced-LSU analogue).
 
 The source slicer can depend only on the word index (and scalar-prefetch
 values), never on consumer state — the feed-forward restriction, enforced
@@ -78,15 +79,20 @@ class RingPipe:
 
     @property
     def n_dmas(self) -> int:
-        """Concurrent DMAs per word (one semaphore each)."""
+        """Concurrent DMAs per word."""
         return self.spec.streams
+
+    @property
+    def n_sems(self) -> int:
+        """DMA semaphores per ring slot."""
+        return self.n_dmas
 
     @property
     def scratch_shapes(self) -> Tuple:
         """The scratch this pipe owns: (VMEM ring buffer, DMA semaphores)."""
         return (
             pltpu.VMEM(self.spec.buffer_shape, self.spec.dtype),
-            pltpu.SemaphoreType.DMA((self.spec.depth, self.n_dmas)),
+            pltpu.SemaphoreType.DMA((self.spec.depth, self.n_sems)),
         )
 
     # -- binding (in kernel) ------------------------------------------------
@@ -165,15 +171,24 @@ class RingPipe:
 
 
 class GatherRingPipe(RingPipe):
-    """Emitter for one irregular (per-row gather) ring pipe.
+    """Emitter for one irregular (gather) ring pipe.
 
-    Each pipe word is a bundle of ``tile[0]`` rows fetched from dynamically
-    indexed locations; ``bind`` takes a *row* slicer
-    ``row_slicer(word, r) -> hbm-ref-slice`` (one source row), typically
-    indexed through a scalar-prefetched index vector. Rows are the stream
-    decomposition (spec.streams is ignored for DMA splitting): a word issues
-    ``rows`` concurrent single-row DMAs, so the ring sustains
-    ``(depth-1) * rows`` outstanding irregular requests.
+    Each pipe word is a bundle of ``tile[0]`` chunks fetched from
+    dynamically indexed locations; ``bind`` takes a *chunk* slicer
+    ``chunk_slicer(word, r) -> hbm-ref-slice``, typically indexed through a
+    scalar-prefetched index vector. For a rank-2 tile ``(rows, cols)`` a
+    chunk is one row (it lands in row ``r`` of the slot); for a rank-3 tile
+    ``(chunks, rows, cols)`` a chunk is a whole ``(rows, cols)`` block (it
+    lands in ``slot[r]``). Only the rank-3 form compiles for a TPU: its DMA
+    engine moves whole native row tiles, such as a KV page, or the single
+    row of an ``[N, 1, C]`` 32-bit table. Chunks are the stream
+    decomposition (spec.streams is ignored for DMA splitting): a word
+    issues ``tile[0]`` concurrent DMAs, so the ring sustains
+    ``(depth-1) * tile[0]`` outstanding irregular requests.
+
+    The chunks of one word share their slot's semaphore (each wait
+    consumes one chunk's bytes): a semaphore per chunk would outgrow the
+    TPU's few KiB of semaphore memory at deep rings of wide bundles.
     """
 
     @property
@@ -184,18 +199,22 @@ class GatherRingPipe(RingPipe):
     def n_dmas(self) -> int:
         return self.rows
 
+    @property
+    def n_sems(self) -> int:
+        return 1
+
     def bind(self, buf, sems,
-             row_slicer: Callable) -> "GatherRingPipe":
-        return super().bind(buf, sems, row_slicer)
+             chunk_slicer: Callable) -> "GatherRingPipe":
+        return super().bind(buf, sems, chunk_slicer)
 
     def _copies(self, word):
         slot = word % self.spec.depth
+        blocks = len(self.spec.tile) > 2
         for r in range(self.rows):
+            dst = (self._buf.at[slot, r] if blocks
+                   else self._buf.at[slot, pl.ds(r, 1)])
             yield pltpu.make_async_copy(
-                self._slicer(word, r),
-                self._buf.at[slot, pl.ds(r, 1)],
-                self._sems.at[slot, r],
-            )
+                self._slicer(word, r), dst, self._sems.at[slot, 0])
 
 
 # -- whole-schedule helpers (the DAE word schedule) --------------------------
@@ -233,3 +252,73 @@ def pad_to(x: jnp.ndarray, multiple: int, axis: int) -> jnp.ndarray:
     pads = [(0, 0)] * x.ndim
     pads[axis] = (0, rem)
     return jnp.pad(x, pads)
+
+
+# -- lane packing: narrow heads share one 128-lane word ------------------------
+#
+# The TPU's DMA engine moves whole 128-lane rows: a copy whose minor dim is
+# 64 is refused even when 64 is the array's full extent. Attention kernels
+# therefore stream K/V in words of ``p * hd`` lanes, ``p`` adjacent heads
+# side by side (``p = 2`` at hd 64), instead of padding hd in HBM, which
+# would double the bytes decode streams. A query meets only its own head's
+# lanes: it is zero in the other heads' lanes (block-diagonal for decode,
+# one lane block per q head for prefill), so every score is exactly its
+# own head's dot product, and the output's diagonal blocks are the heads'.
+
+
+def lane_pack(heads: int, hd: int) -> int:
+    """Heads per lane word: the largest divisor ``p`` of ``heads`` with
+    ``p * hd <= 128`` (1 when ``hd >= 128``)."""
+    p = max(1, min(heads, 128 // hd))
+    while heads % p:
+        p -= 1
+    return p
+
+
+def pack_heads(x: jnp.ndarray, p: int) -> jnp.ndarray:
+    """``[..., H, S, D]`` -> ``[..., H/p, S, p*D]``: heads ``j*p .. j*p+p-1``
+    side by side in the lanes of row group ``j``."""
+    *lead, h, s, d = x.shape
+    x = x.reshape(*lead, h // p, p, s, d)
+    x = jnp.swapaxes(x, -3, -2)
+    return x.reshape(*lead, h // p, s, p * d)
+
+
+def pack_blockdiag(q: jnp.ndarray, p: int) -> jnp.ndarray:
+    """``[..., H, G, D]`` -> ``[..., H/p, p*G, p*D]``: head ``a`` of each
+    group owns rows ``a*G ..`` and lanes ``a*D ..``; every other lane is 0."""
+    *lead, h, g, d = q.shape
+    q = q.reshape(*lead, h // p, p, g, 1, d)
+    eye = jnp.eye(p, dtype=bool)[:, None, :, None]       # [p, 1, p, 1]
+    out = jnp.where(eye, q, jnp.zeros((), q.dtype))      # [.., p, g, p, d]
+    return out.reshape(*lead, h // p, p * g, p * d)
+
+
+def unpack_blockdiag(o: jnp.ndarray, p: int) -> jnp.ndarray:
+    """Inverse of :func:`pack_blockdiag` on a kernel output: keep each
+    head's diagonal block. ``[..., H/p, p*G, p*D]`` -> ``[..., H, G, D]``."""
+    *lead, j, pg, pd = o.shape
+    g, d = pg // p, pd // p
+    o = o.reshape(*lead, j, p, g, p, d)
+    o = jnp.diagonal(o, axis1=-4, axis2=-2)              # [.., j, g, d, p]
+    o = jnp.moveaxis(o, -1, -3)                          # [.., j, p, g, d]
+    return o.reshape(*lead, j * p, g, d)
+
+
+def spread_lanes(x: jnp.ndarray, p: int, groups: int) -> jnp.ndarray:
+    """``[BH, S, D]`` q rows -> ``[BH, S, p*D]``: row ``bh`` reads KV row
+    ``bh // groups``, so it sits in that row's lane block
+    ``(bh // groups) % p`` and is zero elsewhere."""
+    bh, s, d = x.shape
+    lane = (jnp.arange(bh) // groups) % p
+    mask = lane[:, None, None, None] == jnp.arange(p)[None, None, :, None]
+    out = jnp.where(mask, x[:, :, None, :], jnp.zeros((), x.dtype))
+    return out.reshape(bh, s, p * d)
+
+
+def gather_lanes(o: jnp.ndarray, p: int, groups: int) -> jnp.ndarray:
+    """Inverse of :func:`spread_lanes` on a kernel output."""
+    bh, s, pd = o.shape
+    lane = (jnp.arange(bh) // groups) % p
+    o = o.reshape(bh, s, p, pd // p)
+    return jnp.take_along_axis(o, lane[:, None, None, None], axis=2)[:, :, 0]

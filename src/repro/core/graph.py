@@ -106,13 +106,13 @@ from repro.core import planner
 from repro.core.emitter import GatherRingPipe, RingPipe, acquire, release
 from repro.core.meshspec import MeshSpec, SINGLE_DEVICE, localize_workload, \
     resolve_sharding
-from repro.core.pipe import DEFAULT_VMEM_BUDGET_BYTES, Pipe
+from repro.core.pipe import DEFAULT_VMEM_BUDGET_BYTES, Pipe, clamp_streams
 from repro.core.pipeline_model import EdgeEstimate, GraphStage, Workload, \
     estimate_graph
 from repro.core.planner import PlanError
 from repro.core.program import BlockIn, ProducerCtx, ProgramCtx, ScalarIn, \
     ScheduleOpaqueError, Stream, StreamProgram, _OpaqueScalar, \
-    _clamped_streams, compile_program, program_workload
+    compile_program, program_workload
 
 _VMEM_BUDGET_BYTES = DEFAULT_VMEM_BUDGET_BYTES
 
@@ -592,12 +592,12 @@ def check_fusion(producer: StreamProgram, consumer: StreamProgram,
 def _stream_overrides(program: StreamProgram, depth: int,
                       streams: int) -> Dict[str, Pipe]:
     """Re-size every stream of a program to (depth, streams), clamping
-    streams to the tile's divisibility per stream (the planner's global
-    choice refined per edge)."""
+    streams per stream so each DMA keeps whole native row tiles (the
+    planner's global choice refined per edge)."""
     return {
         st.name: dataclasses.replace(
             st.spec, depth=depth,
-            streams=_clamped_streams(st.spec.tile[0], streams))
+            streams=clamp_streams(st.spec.tile[0], streams, st.spec.dtype))
         for st in program.streams
     }
 
@@ -1464,7 +1464,7 @@ def compile_graph(graph: StreamGraph, *, policy=None,
         w, d, s = resolved[n.name]
         tile = _node_tile(n)
         pipe = Pipe(tile=tile, dtype=_node_dtype(n), depth=max(d, 1),
-                    streams=_clamped_streams(tile[0], s))
+                    streams=clamp_streams(tile[0], s, _node_dtype(n)))
         e = fused_in.get(n.name)
         in_edges = [ed for ed in graph.edges if ed.dst == n.name]
         rationale = ""

@@ -66,8 +66,10 @@ class Pipe:
         # stays a whole-sublane copy. The planner prefers >=128-lane words.
         if self.tile[-1] % _SUBLANE != 0:
             raise ValueError(f"tile lane dim {self.tile[-1]} must be a multiple of {_SUBLANE}")
-        if self.tile[-2] % _SUBLANE != 0:
-            raise ValueError(f"tile sublane dim {self.tile[-2]} must be a multiple of {_SUBLANE}")
+        # a single-row sublane dim is the full extent of a [N, 1, C] operand
+        # (the row-gather layout the TPU's DMA engine accepts)
+        if self.tile[-2] != 1 and self.tile[-2] % _SUBLANE != 0:
+            raise ValueError(f"tile sublane dim {self.tile[-2]} must be 1 or a multiple of {_SUBLANE}")
 
     # -- resource accounting (the BRAM analogue) ---------------------------
 
@@ -97,6 +99,28 @@ class Pipe:
 
     def with_streams(self, streams: int) -> "Pipe":
         return dataclasses.replace(self, streams=streams)
+
+
+def sublane_rows(dtype) -> int:
+    """Rows of one native TPU tile of ``dtype``: 8 for 32-bit types, 16 for
+    16-bit and 32 for 8-bit ones (narrow types pack along sublanes)."""
+    return _SUBLANE * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
+def split_ok(rows: int, streams: int, dtype) -> bool:
+    """Can a ``rows``-row tile split into ``streams`` DMAs of whole native
+    tiles? The TPU compiler refuses a DMA whose rows are not a multiple of
+    :func:`sublane_rows`, so a split below that is never planned."""
+    return streams == 1 or (rows % streams == 0
+                            and (rows // streams) % sublane_rows(dtype) == 0)
+
+
+def clamp_streams(rows: int, streams: int, dtype) -> int:
+    """Largest halving of ``streams`` that :func:`split_ok` accepts."""
+    s = max(1, int(streams))
+    while not split_ok(rows, s, dtype):
+        s //= 2
+    return max(1, s)
 
 
 # Single source of the planning VMEM budget (v5e has ~128 MiB; keep slack

@@ -91,6 +91,31 @@ TPU_V5E = HardwareModel(
 )
 
 
+# Hardware model per TPU ``device_kind`` (as ``jax.devices()[0].device_kind``
+# reports it). A TPU whose kind is missing here is an error, not a default.
+HW_BY_DEVICE_KIND = {
+    "TPU v5 lite": TPU_V5E,
+    "TPU v5e": TPU_V5E,
+}
+
+
+def device_hardware(device) -> HardwareModel:
+    """The planner's hardware model for ``device``.
+
+    A TPU is looked up by its ``device_kind`` in :data:`HW_BY_DEVICE_KIND`
+    and an unknown kind raises. Any other platform (the CPU, where kernels
+    run interpreted) plans against the deployment target, ``TPU_V5E``.
+    """
+    if device.platform != "tpu":
+        return TPU_V5E
+    try:
+        return HW_BY_DEVICE_KIND[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no hardware model for TPU device_kind {device.device_kind!r}; "
+            f"known kinds: {sorted(HW_BY_DEVICE_KIND)}") from None
+
+
 @dataclasses.dataclass(frozen=True)
 class Workload:
     """One kernel's stream program, in pipe words.

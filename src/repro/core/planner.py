@@ -18,7 +18,7 @@ from repro import obs
 from repro.core import profiling
 from repro.core.meshspec import MeshSpec, SINGLE_DEVICE, resolve_mesh
 from repro.core.pipe import DEFAULT_VMEM_BUDGET_BYTES, Pipe, \
-    required_depth, vmem_budget_ok
+    required_depth, split_ok, sublane_rows, vmem_budget_ok
 from repro.core.pipeline_model import (
     HardwareModel,
     TPU_V5E,
@@ -91,6 +91,13 @@ def plan_pipe(
         if tile[0] % streams != 0:
             skipped.append(
                 f"streams={streams}: tile[0]={tile[0]} not divisible")
+            continue
+        # regular words split across DMAs; a split below the dtype's native
+        # row tiling is refused by the TPU compiler (gathers widen instead)
+        if w.regular and not split_ok(tile[0], streams, dtype):
+            skipped.append(
+                f"streams={streams}: {tile[0] // streams}-row DMAs split "
+                f"the {sublane_rows(dtype)}-row tiling")
             continue
         pipe = base_pipe.with_depth(depth).with_streams(streams)
         if not vmem_budget_ok([pipe], vmem_budget_bytes):

@@ -55,8 +55,9 @@ from repro import obs
 from repro.core import planner
 from repro.core.emitter import GatherRingPipe, RingPipe, acquire, release
 from repro.core.meshspec import MeshSpec, localize_workload, resolve_sharding
-from repro.core.pipe import Pipe
-from repro.core.pipeline_model import TPU_V5E, HardwareModel, Workload
+from repro.core.pipe import Pipe, clamp_streams
+from repro.core.pipeline_model import HardwareModel, Workload, \
+    device_hardware
 
 # ---------------------------------------------------------------------------
 # PipePolicy: one frozen knob bundle for every kernel call site
@@ -75,9 +76,15 @@ class PipePolicy:
       depth: ring slots — int, "auto" (roofline-planned per call site), or
         "measured" (empirically tuned at the kernel's default tile).
       streams: producer DMAs per word — int, "auto", or "measured".
-      interpret: run the Pallas kernel in interpret mode (CPU container).
+      interpret: run the Pallas kernel in interpret mode. ``None`` (the
+        default) derives it from the backend (:func:`default_interpret`):
+        compiled on a TPU, interpreted elsewhere. The resolved bool is
+        what the field holds after construction.
       hw: hardware model the planner sizes against (TPU_V5E / ARRIA_CX);
-        also part of the tuned-plan cache key.
+        also part of the tuned-plan cache key. ``None`` (the default)
+        takes the model of the local device
+        (:func:`~repro.core.pipeline_model.device_hardware`); on a TPU
+        whose kind has no model that is an error.
       stream_options: candidate stream counts the planner/tuner may pick
         from.
       mesh: the mesh topology this policy's call sites run under
@@ -91,12 +98,16 @@ class PipePolicy:
     mode: str = "ff"
     depth: Union[int, str] = "auto"
     streams: Union[int, str] = "auto"
-    interpret: bool = True
-    hw: HardwareModel = TPU_V5E
+    interpret: Optional[bool] = None
+    hw: Optional[HardwareModel] = None
     stream_options: Tuple[int, ...] = (1, 2, 4)
     mesh: Optional[MeshSpec] = None
 
     def __post_init__(self):
+        if self.interpret is None:
+            object.__setattr__(self, "interpret", default_interpret())
+        if self.hw is None:
+            object.__setattr__(self, "hw", device_hardware(jax.devices()[0]))
         if not isinstance(self.mode, str):
             raise TypeError(f"mode must be a str, got {self.mode!r}")
         if self.mesh is not None and not isinstance(self.mesh, MeshSpec):
@@ -117,6 +128,12 @@ class PipePolicy:
         """Resolve this policy's (depth, streams) for one call site."""
         return planner.resolve_policy(op, self, workload=workload, tile=tile,
                                       dtype=dtype)
+
+
+def default_interpret() -> bool:
+    """Interpret mode derived from the backend: Pallas kernels compile for
+    a TPU and run in the interpreter on every other backend."""
+    return jax.default_backend() != "tpu"
 
 
 class _PolicyStack(threading.local):
@@ -504,15 +521,6 @@ def program_workload(program: StreamProgram) -> Workload:
     )
 
 
-def _clamped_streams(tile0: int, streams: int) -> int:
-    """Largest power-of-two-reduced stream count dividing the tile's
-    leading dim (the planner's global choice refined per stream)."""
-    s = max(1, int(streams))
-    while s > 1 and tile0 % s:
-        s //= 2
-    return max(1, s)
-
-
 def _traced_compile(fn):
     """Wrap the program lowering in an obs span (no-op when tracing is
     off) so compile time and ring structure land in the trace."""
@@ -560,7 +568,8 @@ def compile_program(program: StreamProgram, *,
     streams) for the program's synthesized workload under the policy
     (gather streams keep their declared stream count — their row bundle
     is part of the word geometry), and ``policy.interpret`` supplies the
-    interpret flag unless ``interpret=`` is passed explicitly.
+    interpret flag unless ``interpret=`` is passed explicitly. With
+    neither, the flag comes from the backend (:func:`default_interpret`).
     ``sharding`` localizes that planning to the mesh: pass a
     :class:`~repro.runtime.sharding.ShardingContext` (or a bare
     :class:`~repro.core.meshspec.MeshSpec`), or leave ``None`` to pick up
@@ -584,12 +593,14 @@ def compile_program(program: StreamProgram, *,
             st.name: dataclasses.replace(
                 st.spec, depth=depth,
                 streams=(st.spec.streams if st.gather else
-                         _clamped_streams(st.spec.tile[0], streams)))
+                         clamp_streams(st.spec.tile[0], streams,
+                                       st.spec.dtype)))
             for st in program.streams
         }
         if interpret is None:
             interpret = policy.interpret
-    interpret = True if interpret is None else interpret
+    if interpret is None:
+        interpret = default_interpret()
     scalar_ins = [i for i in program.inputs if isinstance(i, ScalarIn)]
     tensor_ins = [i for i in program.inputs if not isinstance(i, ScalarIn)]
     specs: Dict[str, Pipe] = {s.name: s.spec for s in program.streams}

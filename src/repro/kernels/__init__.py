@@ -9,8 +9,10 @@ design model, one subpackage per hot spot:
 
 Each subpackage: kernel.py (pl.pallas_call + BlockSpec), ops.py (jit
 wrapper + exact tile-schedule cost model + registration), ref.py (pure-jnp
-oracle). Kernels validate under interpret=True on CPU; real-TPU lowering is
-the target.
+oracle). Kernels compile on a TPU and run in the Pallas interpreter
+elsewhere (``PipePolicy.interpret`` derives the mode from the backend);
+``tests/test_tpu_compile.py`` compiles the served path's kernels for a
+described v5e.
 
 The StreamProgram/registry contract — what a *new* kernel must provide
 ----------------------------------------------------------------------

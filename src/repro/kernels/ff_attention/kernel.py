@@ -10,12 +10,17 @@ the K/V stream pipelines at full depth regardless.
 
 Layout: q,k,v are [BH, S, D] with KV heads already broadcast-indexed by the
 wrapper (GQA: q head h reads kv head h // group). Grid is 1-D over
-(bh, qi, kj), kj innermost, causal blocks skipped via predication.
+(bh, qi, kj), kj innermost, causal blocks skipped via predication. The op
+wrapper hands the kernel lane-packed KV rows
+(:func:`~repro.core.emitter.lane_pack`): D is ``p * hd`` lanes, each q row
+is zero outside its own head's lane block, and ``scale`` (``1/sqrt(hd)``)
+is passed in rather than derived from D.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -32,13 +37,15 @@ def build_program(bh: int, s: int, skv: int, d: int, *,
                   kv_groups: int = 1, block_q: int = 128, block_kv: int = 128,
                   causal: bool = True, dtype=jnp.float32, k_dtype=None,
                   v_dtype=None, out_dtype=None,
-                  depth: int = 2, streams: int = 1) -> StreamProgram:
+                  depth: int = 2, streams: int = 1,
+                  scale: Optional[float] = None) -> StreamProgram:
     """Declare the prefill-attention stream program at one shape point.
     ``dtype`` is the q/out element type; ``k_dtype``/``v_dtype`` (default
-    ``dtype``) size their own pipe edges."""
+    ``dtype``) size their own pipe edges; ``scale`` defaults to
+    ``1/sqrt(d)``."""
     assert s % block_q == 0 and skv % block_kv == 0, (s, skv, block_q, block_kv)
     nq, nkv = s // block_q, skv // block_kv
-    scale = 1.0 / (d ** 0.5)
+    scale = 1.0 / (d ** 0.5) if scale is None else scale
     out_dtype = out_dtype or dtype
     k_spec = Pipe(tile=(block_kv, d), dtype=k_dtype or dtype, depth=depth,
                   streams=streams)
@@ -130,7 +137,7 @@ def build_program(bh: int, s: int, skv: int, d: int, *,
 @functools.partial(
     jax.jit,
     static_argnames=("kv_groups", "block_q", "block_kv", "depth", "streams",
-                     "causal", "interpret"))
+                     "causal", "scale", "interpret"))
 def flash_attention_ff(
     q: jnp.ndarray,               # [BH, S, D]
     k: jnp.ndarray,               # [BKVH, S, D]
@@ -142,7 +149,8 @@ def flash_attention_ff(
     depth: int = 2,
     streams: int = 1,
     causal: bool = True,
-    interpret: bool = True,
+    scale: Optional[float] = None,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     bh, s, d = q.shape
     kvbh, skv, dk = k.shape
@@ -150,5 +158,5 @@ def flash_attention_ff(
     program = build_program(bh, s, skv, d, kv_groups=kv_groups,
                             block_q=block_q, block_kv=block_kv, causal=causal,
                             dtype=q.dtype, k_dtype=k.dtype, v_dtype=v.dtype,
-                            depth=depth, streams=streams)
+                            depth=depth, streams=streams, scale=scale)
     return compile_program(program, interpret=interpret)(q, k, v)

@@ -8,7 +8,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import autotune
-from repro.core.emitter import cdiv, pad_to
+from repro.core.emitter import cdiv, gather_lanes, lane_pack, pack_heads, \
+    pad_to, spread_lanes
 from repro.core.pipeline_model import Workload
 from repro.core.program import PipePolicy, make_entrypoint
 from repro.kernels.ff_attention.kernel import build_program, \
@@ -75,19 +76,26 @@ def _apply(q, k, v, *, kv_groups: int = 1, causal: bool = True,
         return attention_ref(q, k, v, kv_groups=kv_groups, causal=causal)
     bh, s, d = q.shape
     skv = k.shape[1]
+    # p KV rows side by side in each lane word; a q row reads only its
+    # own KV row's lane block (any p dividing the KV row count will do)
+    p = lane_pack(k.shape[0], d)
+    qw = spread_lanes(q, p, kv_groups)
+    kw = pack_heads(k, p)
+    vw = pack_heads(v, p)
 
     def _run(bq, bkv, depth, streams):
-        qp = pad_to(q, bq, 1)
-        kp = pad_to(k, bkv, 1)
-        vp = pad_to(v, bkv, 1)
+        qp = pad_to(qw, bq, 1)
+        kp = pad_to(kw, bkv, 1)
+        vp = pad_to(vw, bkv, 1)
         if kp.shape[1] > skv and not causal:
             raise ValueError(
                 "non-causal attention requires Skv to be a block multiple "
                 "(padded keys would receive softmax mass)")
-        return flash_attention_ff(
-            qp, kp, vp, kv_groups=kv_groups, block_q=bq, block_kv=bkv,
+        out = flash_attention_ff(
+            qp, kp, vp, kv_groups=kv_groups * p, block_q=bq, block_kv=bkv,
             depth=depth, streams=streams, causal=causal,
-            interpret=policy.interpret)
+            scale=1.0 / (d ** 0.5), interpret=policy.interpret)
+        return gather_lanes(out, p, kv_groups)
 
     w, tile = attention_workload(bh, s, d, causal=causal, block_q=block_q,
                                  block_kv=block_kv, dtype=q.dtype)

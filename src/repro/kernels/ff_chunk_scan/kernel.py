@@ -27,6 +27,7 @@ factorization), so every exp() is in (0,1] and f32-stable:
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -179,7 +180,7 @@ def chunk_scan_ff(
     inclusive: bool = True,
     depth: int = 2,
     streams: int = 1,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     bh, s, n = q.shape
     p = v.shape[2]

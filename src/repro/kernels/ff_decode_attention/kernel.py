@@ -11,11 +11,18 @@ bound), which is exactly what the roofline table shows for decode cells.
 Layout: q is [B, KVH, G, D] (G = padded query-head group per KV head, GQA),
 cache k/v are [B, KVH, S, D], ``lengths[B]`` is scalar-prefetched and gives
 the live cache prefix. Grid: 1-D over (b*kvh, kv_block), kv innermost.
+The op wrapper hands the kernel lane-packed heads
+(:func:`~repro.core.emitter.lane_pack`): "KVH" counts lane words of
+``lane_heads`` heads, D is ``lane_heads * hd`` lanes and q is
+block-diagonal, so ``scale`` (``1/sqrt(hd)``) is passed in rather than
+derived from D, and the output keeps only its diagonal blocks (each head's
+rows against its own lanes; the rest is zero).
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -28,16 +35,34 @@ from repro.core.program import BlockIn, ScalarIn, ScratchSpec, Stream, \
 _NEG_INF = -1e30
 
 
+def _finalize(ctx, acc, l_sc, lane_heads: int, out_dtype) -> None:
+    """Normalize the accumulator into the output block; with lane-packed
+    heads keep only the diagonal (head-own-lanes) blocks."""
+    l = l_sc[:, :1]
+    l = jnp.where(l == 0.0, 1.0, l)
+    out = acc[...] / l
+    if lane_heads > 1:
+        rows, lanes = out.shape
+        r = jax.lax.broadcasted_iota(jnp.int32, out.shape, 0)
+        c = jax.lax.broadcasted_iota(jnp.int32, out.shape, 1)
+        out = jnp.where(r // (rows // lane_heads) == c // (lanes // lane_heads),
+                        out, 0.0)
+    ctx.out[0, 0] = out.astype(out_dtype)
+
+
 def build_program(b: int, kvh: int, g_pad: int, s: int, d: int, *,
                   block_kv: int = 128, dtype=jnp.float32, k_dtype=None,
                   v_dtype=None, out_dtype=None,
-                  depth: int = 2, streams: int = 1) -> StreamProgram:
+                  depth: int = 2, streams: int = 1,
+                  scale: Optional[float] = None,
+                  lane_heads: int = 1) -> StreamProgram:
     """Declare the decode-attention stream program at one shape point.
     ``dtype`` is the q/out element type; ``k_dtype``/``v_dtype`` (default
-    ``dtype``) size their own cache pipe edges."""
+    ``dtype``) size their own cache pipe edges; ``scale`` defaults to
+    ``1/sqrt(d)``; ``lane_heads`` is the heads packed per lane word."""
     assert s % block_kv == 0, (s, block_kv)
     nkv = s // block_kv
-    scale = 1.0 / (d ** 0.5)
+    scale = 1.0 / (d ** 0.5) if scale is None else scale
     out_dtype = out_dtype or dtype
     k_spec = Pipe(tile=(block_kv, d), dtype=k_dtype or dtype, depth=depth,
                   streams=streams)
@@ -91,9 +116,7 @@ def build_program(b: int, kvh: int, g_pad: int, s: int, d: int, *,
 
         @pl.when(kj == nkv - 1)
         def _():
-            l = l_sc[:, :1]
-            l = jnp.where(l == 0.0, 1.0, l)
-            ctx.out[0, 0] = (acc[...] / l).astype(out_dtype)
+            _finalize(ctx, acc, l_sc, lane_heads, out_dtype)
 
     q_index_map = lambda g, lens: ((g // nkv) // kvh, (g // nkv) % kvh, 0, 0)
     return StreamProgram(
@@ -125,7 +148,9 @@ def build_program(b: int, kvh: int, g_pad: int, s: int, d: int, *,
 def build_paged_program(b: int, kvh: int, g_pad: int, n_pages: int,
                         page: int, d: int, *, dtype=jnp.float32,
                         kv_dtype=None, out_dtype=None,
-                        depth: int = 2, streams: int = 1) -> StreamProgram:
+                        depth: int = 2, streams: int = 1,
+                        scale: Optional[float] = None,
+                        lane_heads: int = 1) -> StreamProgram:
     """Paged-KV decode attention: the consumer half of the
     ``paged_decode_attention`` StreamGraph.
 
@@ -138,7 +163,7 @@ def build_paged_program(b: int, kvh: int, g_pad: int, n_pages: int,
     (same tile order, same f32 accumulation), which is what makes the paged
     path bitwise-equal to the contiguous cache path.
     """
-    scale = 1.0 / (d ** 0.5)
+    scale = 1.0 / (d ** 0.5) if scale is None else scale
     out_dtype = out_dtype or dtype
     kv_spec = Pipe(tile=(2 * page, d), dtype=kv_dtype or dtype, depth=depth,
                    streams=streams)
@@ -189,9 +214,7 @@ def build_paged_program(b: int, kvh: int, g_pad: int, n_pages: int,
 
         @pl.when(kj == n_pages - 1)
         def _():
-            l = l_sc[:, :1]
-            l = jnp.where(l == 0.0, 1.0, l)
-            ctx.out[0, 0] = (acc[...] / l).astype(out_dtype)
+            _finalize(ctx, acc, l_sc, lane_heads, out_dtype)
 
     q_index_map = lambda g, lens: ((g // n_pages) // kvh,
                                    (g // n_pages) % kvh, 0, 0)
@@ -221,7 +244,8 @@ def build_paged_program(b: int, kvh: int, g_pad: int, n_pages: int,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("block_kv", "depth", "streams", "interpret"))
+    static_argnames=("block_kv", "depth", "streams", "scale", "lane_heads",
+                     "interpret"))
 def decode_attention_ff(
     q: jnp.ndarray,           # [B, KVH, G_pad, D]
     k: jnp.ndarray,           # [B, KVH, S, D]
@@ -231,11 +255,14 @@ def decode_attention_ff(
     block_kv: int = 128,
     depth: int = 2,
     streams: int = 1,
-    interpret: bool = True,
+    scale: Optional[float] = None,
+    lane_heads: int = 1,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     b, kvh, g_pad, d = q.shape
     _, _, s, _ = k.shape
     program = build_program(b, kvh, g_pad, s, d, block_kv=block_kv,
                             dtype=q.dtype, k_dtype=k.dtype, v_dtype=v.dtype,
-                            depth=depth, streams=streams)
+                            depth=depth, streams=streams, scale=scale,
+                            lane_heads=lane_heads)
     return compile_program(program, interpret=interpret)(lengths, q, k, v)

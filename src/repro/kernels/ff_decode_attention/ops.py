@@ -8,7 +8,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import autotune
-from repro.core.emitter import cdiv
+from repro.core.emitter import cdiv, lane_pack, pack_blockdiag, \
+    pack_heads, unpack_blockdiag
 from repro.core.pipeline_model import Workload
 from repro.core.program import PipePolicy, make_entrypoint
 from repro.kernels.ff_decode_attention.kernel import build_program, \
@@ -31,35 +32,40 @@ def decode_attention_cost(b: int, h: int, kvh: int, s: int, d: int,
 def decode_attention_workload(b: int, h: int, kvh: int, s: int, d: int,
                               *, block_kv: int = 128, dtype=jnp.bfloat16
                               ) -> Tuple[Workload, Tuple[int, int]]:
-    """One word per (b, kvh, kj): a K and a V cache tile. The whole KV
-    cache streams once — the paper's regular, DLCD-free favourable case."""
+    """One word per (b, lane word, kj): a K and a V cache tile of ``p``
+    lane-packed heads (:func:`~repro.core.emitter.lane_pack`). The whole
+    KV cache streams once — the paper's regular, DLCD-free favourable
+    case."""
     itemsize = jnp.dtype(dtype).itemsize
     nkv = cdiv(s, block_kv)
     group = max(h // kvh, 1)
+    p = lane_pack(kvh, d)
     w = Workload(
-        n_words=b * kvh * nkv,
-        word_bytes=float(2 * block_kv * d * itemsize),
-        flops_per_word=4.0 * group * block_kv * d,
+        n_words=b * (kvh // p) * nkv,
+        word_bytes=float(2 * block_kv * p * d * itemsize),
+        flops_per_word=4.0 * p * group * block_kv * d,
         regular=True,
     )
-    return w, (block_kv, d)
+    return w, (block_kv, p * d)
 
 
 def paged_decode_attention_workload(b: int, h: int, kvh: int, n_pages: int,
                                     page: int, d: int, *, dtype=jnp.bfloat16
                                     ) -> Tuple[Workload, Tuple[int, int]]:
-    """One word per (b, kvh, page): a merged K+V page tile gathered through
-    the block table. Same math as :func:`decode_attention_workload` at
-    ``block_kv == page``, but the stream arrives via an irregular gather."""
+    """One word per (b, lane word, page): a merged K+V page tile of ``p``
+    lane-packed heads gathered through the block table. Same math as
+    :func:`decode_attention_workload` at ``block_kv == page``, but the
+    stream arrives via an irregular gather."""
     itemsize = jnp.dtype(dtype).itemsize
     group = max(h // kvh, 1)
+    p = lane_pack(kvh, d)
     w = Workload(
-        n_words=b * kvh * n_pages,
-        word_bytes=float(2 * page * d * itemsize),
-        flops_per_word=4.0 * group * page * d,
+        n_words=b * (kvh // p) * n_pages,
+        word_bytes=float(2 * page * p * d * itemsize),
+        flops_per_word=4.0 * p * group * page * d,
         regular=True,
     )
-    return w, (2 * page, d)
+    return w, (2 * page, p * d)
 
 
 # KV-cache tile candidates for mode="autotune" (the cache stream's word
@@ -76,8 +82,9 @@ def _apply(q, k, v, lengths=None, *, kv_heads: int = None,
     """Decode attention for one new token.
 
     q: [B, H, D]; k, v: [B, KVH, S, D]; lengths: [B] int32 (defaults to S).
-    Returns [B, H, D]. The wrapper regroups q heads per KV head and pads the
-    group to the 8-sublane granule.
+    Returns [B, H, D]. The wrapper regroups q heads per KV head, pads the
+    group to the 8-sublane granule, and lane-packs ``p`` heads per KV word
+    (:func:`~repro.core.emitter.lane_pack`; q block-diagonal).
     policy.mode="ff"|"autotune"(measured plan)|"baseline"|"ref".
     """
     del kv_heads    # accepted for legacy signature compatibility
@@ -91,16 +98,21 @@ def _apply(q, k, v, lengths=None, *, kv_heads: int = None,
         qg = q.reshape(b, kvh, group, d)
         return decode_attention_ref(qg, k, v, lengths).reshape(b, h, d)
     g_pad = -(-group // 8) * 8
+    p = lane_pack(kvh, d)
     qg = q.reshape(b, kvh, group, d)
     qg = jnp.pad(qg, ((0, 0), (0, 0), (0, g_pad - group), (0, 0)))
+    qp = pack_blockdiag(qg, p)
+    kp, vp = pack_heads(k, p), pack_heads(v, p)
     lens = lengths.astype(jnp.int32)
 
     def _run(bkv, depth, streams):
         if s % bkv != 0:
             raise ValueError(f"block_kv={bkv} does not divide S={s}")
-        return decode_attention_ff(
-            qg, k, v, lens, block_kv=bkv, depth=depth, streams=streams,
+        out = decode_attention_ff(
+            qp, kp, vp, lens, block_kv=bkv, depth=depth, streams=streams,
+            scale=1.0 / (d ** 0.5), lane_heads=p,
             interpret=policy.interpret)
+        return unpack_blockdiag(out, p)
 
     w, tile = decode_attention_workload(b, h, kvh, s, d, block_kv=block_kv,
                                         dtype=k.dtype)

@@ -27,22 +27,25 @@ def gather_cost(n: int, cols: int, *, depth: int = 4,
     )
 
 
-def gather_workload(n: int, cols: int, *,
+def gather_workload(n: int, cols: int, *, block_rows: int = 1,
+                    blocks_per_word: int = _ROWS,
                     dtype=jnp.float32) -> Tuple[Workload, Tuple[int, int]]:
-    """One word per 8-row bundle of irregular single-row loads — the
-    paper's IR access pattern: latency per word, hidden by (depth-1) x rows
-    outstanding row DMAs. The planner's ``streams`` choice is modeled as
+    """One word per bundle of ``blocks_per_word`` irregular loads of
+    ``block_rows`` rows each (single rows by default) — the paper's IR
+    access pattern: latency per word, hidden by (depth-1) x bundle
+    outstanding DMAs. The planner's ``streams`` choice is modeled as
     concurrent 8-row producers; emission realizes it by widening the bundle
     to ``8 * streams`` rows per word (budget re-checked in ``_apply``)."""
     itemsize = jnp.dtype(dtype).itemsize
+    rows = blocks_per_word * block_rows
     w = Workload(
-        n_words=max(cdiv(n, _ROWS), 1),
-        word_bytes=float(_ROWS * cols * itemsize),
+        n_words=max(cdiv(n, blocks_per_word), 1),
+        word_bytes=float(rows * cols * itemsize),
         flops_per_word=0.0,
         regular=False,
-        store_bytes_per_word=float(_ROWS * cols * itemsize),
+        store_bytes_per_word=float(rows * cols * itemsize),
     )
-    return w, (_ROWS, cols)
+    return w, (rows, cols)
 
 
 def _apply(table, idx, *, policy: PipePolicy):

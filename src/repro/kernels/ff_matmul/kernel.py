@@ -16,7 +16,7 @@ Word schedule: 1-D grid over (mi, ni, ki) with k innermost; the output block
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -99,7 +99,7 @@ def matmul_ff(
     depth: int = 2,
     streams: int = 1,
     out_dtype=None,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """DAE-pipelined matmul. Shapes must be multiples of ``block`` (use
     ops.matmul for auto-padding)."""

@@ -397,7 +397,7 @@ def run_smoke(spec: KernelSpec, *, policy=None, depth=None, streams=None,
     if policy is None:
         policy = PipePolicy(mode="ff", depth=legacy.get("depth", "auto"),
                             streams=legacy.get("streams", "auto"),
-                            interpret=legacy.get("interpret", True))
+                            interpret=legacy.get("interpret"))
     elif legacy:
         raise TypeError(
             f"run_smoke({spec.name}): pass either policy= or the "

@@ -29,7 +29,7 @@ from repro.configs.base import (ARCH_IDS, SHAPES, get_config,
                                 shape_applicable)
 from repro.launch import steps as steps_lib
 from repro.launch.hlo_stats import collective_stats
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.models import build_model
 from repro.runtime import sharding as shlib
 
@@ -128,7 +128,7 @@ def run_cell(arch_id: str, shape_name: str, *, multi_pod: bool,
     if mesh_axes is not None:
         names = tuple(n for n, _ in mesh_axes)
         sizes = tuple(s for _, s in mesh_axes)
-        mesh = jax.make_mesh(sizes, names)
+        mesh = make_mesh(sizes, names)
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
     try:

@@ -46,6 +46,7 @@ import numpy as np
 from repro import obs
 from repro.configs.base import ARCH_IDS, get_config, smoke_config
 from repro.launch import steps as steps_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.runtime import sharding as shlib
 from repro.runtime.paged_kv import OutOfBlocks, PagedKVCache
@@ -146,6 +147,7 @@ def _summarize(emits: Dict[int, List[float]], requests: List[Request],
         "prefill_s": prefill_s,
         "decode_s": decode_s,
         "decode_steps": steps,
+        "per_request_tokens": [len(emits.get(r.rid, [])) for r in requests],
     }
 
 
@@ -464,7 +466,7 @@ def serve_bench(args) -> Dict[str, object]:
         # layer; the paged scheduler keeps its gather-attention graph)
         cfg = cfg.replace(layer_graph=True)
     from repro.core.program import PipePolicy
-    policy = PipePolicy(mode=args.policy_mode, interpret=True)
+    policy = PipePolicy(mode=args.policy_mode)
     from repro.models import build_model
     model = build_model(cfg)
     mesh = make_host_mesh()
@@ -504,7 +506,7 @@ def serve_bench(args) -> Dict[str, object]:
 
         with shlib.use_sharding(mesh,
                                 overrides=dict(cfg.rule_overrides or {})):
-            params = model.init(jax.random.key(0))
+            params = model.init(jax.random.key(args.seed))
             lockstep = run_lockstep(
                 model, params, cfg, requests, n_slots=args.slots,
                 page=args.page, eos_id=args.eos_id, policy=policy)
@@ -613,6 +615,7 @@ def main(argv=None):
     ap.add_argument("--json", default=None,
                     help="write the benchmark dict to this path")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     result = serve_bench(args)
     ls, pg = result["lockstep"], result["paged"]
     print(f"impl={result['impl']} policy={args.policy_mode} "

@@ -23,6 +23,7 @@ import numpy as np
 from repro.configs.base import ARCH_IDS, get_config, smoke_config
 from repro.data import HostPipeline, SyntheticSpec, batch_at
 from repro.launch import steps as steps_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.optim import adamw
 from repro.runtime import sharding as shlib
@@ -66,6 +67,7 @@ def main(argv=None):
                     help="enable live telemetry and write "
                          "obs.metrics_snapshot() to PATH at exit")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     from repro.models import build_model
@@ -85,7 +87,7 @@ def main(argv=None):
     policy = None
     if args.policy_mode is not None:
         from repro.core.program import PipePolicy
-        policy = PipePolicy(mode=args.policy_mode, interpret=True)
+        policy = PipePolicy(mode=args.policy_mode)
 
     # plan-service hooks (same contract as launch/serve.py): --plan-db
     # feeds the autotune lookup chain, --record-profile captures the

@@ -10,7 +10,7 @@ parameters as :class:`ParamSpec` trees, which give us three views for free:
 Attention/scan/matmul call sites go through ``repro.kernels`` wrappers with
 an ``impl`` switch: "xla" (HLO-visible reference path — used when lowering
 for the dry-run and on CPU) or "ff" (the feed-forward Pallas kernels — the
-TPU fast path, validated in interpret mode).
+TPU fast path; compiled on a TPU, interpreted elsewhere).
 """
 
 from __future__ import annotations
@@ -206,18 +206,43 @@ def attention_xla(q, k, v, *, causal: bool, positions_q=None,
     return jnp.concatenate(outs, axis=1)
 
 
-def _session_kernel_policy(interpret: bool):
+def _session_kernel_policy():
     """Derive the kernel policy from the session `repro.policy` context (so
-    no-touch A/B runs reach model code), pinning only what the layer
-    contract fixes; modes the attention kernels don't speak (e.g.
-    chunk_scan's "xla") fall back to "ff". "autotune" passes through — the
-    serve/train ``--policy-mode autotune`` path and the plan service
-    (record/replay through the PlanDB lookup chain) depend on it."""
+    no-touch A/B runs reach model code); modes the attention kernels don't
+    speak (e.g. chunk_scan's "xla") fall back to "ff". "autotune" passes
+    through — the serve/train ``--policy-mode autotune`` path and the plan
+    service (record/replay through the PlanDB lookup chain) depend on it.
+    Interpret mode is the policy's own (derived from the backend)."""
     from repro.core.program import current_policy
     pol = current_policy()
     if pol.mode not in ("ff", "baseline", "ref", "autotune"):
         pol = pol.replace(mode="ff")
-    return pol.replace(interpret=interpret)
+    return pol
+
+
+def _on_mesh(fn, *args, batched: Tuple[bool, ...]):
+    """Call a compiled-kernel op under the ambient mesh.
+
+    GSPMD cannot partition a Mosaic kernel, so under a multi-device
+    :func:`~repro.runtime.sharding.use_sharding` context the call runs in
+    ``shard_map`` (:func:`~repro.runtime.streams.shard_streams`): the
+    ``batched`` operands (and the output) split their leading dim over the
+    mesh axes the "batch" rule names when it divides evenly; every other
+    operand, and every other mesh axis, is replicated, so those devices
+    compute the same result. Without a mesh the call is direct.
+    """
+    from repro.runtime import sharding as shlib
+    ctx = shlib.current()
+    if ctx is None or ctx.mesh.size == 1:
+        return fn(*args)
+    from jax.sharding import PartitionSpec as P
+
+    from repro.runtime.streams import shard_streams
+    split = shlib.divisible("batch", args[0].shape[0], ctx)
+    bspec = shlib.spec_for(("batch",), ctx) if split else P()
+    return shard_streams(
+        fn, in_specs=tuple(bspec if b else P() for b in batched),
+        out_specs=bspec, ctx=ctx)(*args)
 
 
 def _session_scan_policy(cfg_impl: str):
@@ -232,7 +257,7 @@ def _session_scan_policy(cfg_impl: str):
 
 
 def attention_op(q, k, v, *, causal: bool, impl: str = "xla",
-                 lengths=None, interpret: bool = True) -> jnp.ndarray:
+                 lengths=None) -> jnp.ndarray:
     """Dispatch between the XLA path and the ff_attention Pallas kernel."""
     if impl == "xla":
         return attention_xla(q, k, v, causal=causal, lengths=lengths)
@@ -243,14 +268,16 @@ def attention_op(q, k, v, *, causal: bool, impl: str = "xla",
     kh = k.transpose(0, 2, 1, 3).reshape(b * kvh, k.shape[1], d)
     vh = v.transpose(0, 2, 1, 3).reshape(b * kvh, v.shape[1], d)
     block_q = min(128, max(8, s))
-    out = ff_attn(qh, kh, vh, kv_groups=h // kvh, causal=causal,
-                  block_q=block_q, block_kv=128,
-                  policy=_session_kernel_policy(interpret))
+    pol = _session_kernel_policy()
+    out = _on_mesh(
+        lambda q_, k_, v_: ff_attn(q_, k_, v_, kv_groups=h // kvh,
+                                   causal=causal, block_q=block_q,
+                                   block_kv=128, policy=pol),
+        qh, kh, vh, batched=(True, True, True))
     return out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
 
 
 def decode_attention_op(q, k, v, lengths, *, impl: str = "xla",
-                        interpret: bool = True,
                         block_kv: Optional[int] = None) -> jnp.ndarray:
     """q: [B,H,D] one token; k,v: [B,Skv,KVH,D] cache; lengths: [B].
     ``block_kv`` pins the ff KV tile (serving pins it to the paged cache's
@@ -277,33 +304,34 @@ def decode_attention_op(q, k, v, lengths, *, impl: str = "xla",
     if pad:
         kh = jnp.pad(kh, ((0, 0), (0, 0), (0, pad), (0, 0)))
         vh = jnp.pad(vh, ((0, 0), (0, 0), (0, pad), (0, 0)))
-    return ff_dec(q, kh, vh, lengths, block_kv=block_kv,
-                  policy=_session_kernel_policy(interpret))
+    pol = _session_kernel_policy()
+    return _on_mesh(
+        lambda *a: ff_dec(*a, block_kv=block_kv, policy=pol),
+        q, kh, vh, lengths, batched=(True, True, True, True))
 
 
 def paged_decode_attention_op(q, kv_pool, block_tables, lengths, *,
-                              impl: str = "xla",
-                              interpret: bool = True) -> jnp.ndarray:
+                              impl: str = "xla") -> jnp.ndarray:
     """Decode attention through a paged KV pool (continuous batching).
 
-    q: [B,H,D] one token; kv_pool: [nb, 2, page, KVH, D] (one layer's
-    block pool); block_tables: [B, n_pages] (entries >= nb are sentinels);
-    lengths: [B] (0 = inactive slot). "xla" dereferences the table densely;
-    "ff" runs the fused gather->attention StreamGraph.
+    q: [B,H,D] one token; kv_pool: [nb, J, 2, page, lanes] (one layer's
+    block pool, lane-packed heads — ``runtime/paged_kv.py``);
+    block_tables: [B, n_pages] (entries >= nb are sentinels); lengths: [B]
+    (0 = inactive slot). "xla" dereferences the table densely; "ff" runs
+    the fused gather->attention StreamGraph.
     """
+    from repro.runtime import paged_kv
     if impl == "xla":
-        nb, _, page, kvh, d = kv_pool.shape
-        b = q.shape[0]
-        npg = block_tables.shape[-1]
-        bt = jnp.clip(block_tables.astype(jnp.int32), 0, nb - 1)
-        kv = kv_pool[bt]                  # [B, npg, 2, page, KVH, D]
-        k = kv[:, :, 0].reshape(b, npg * page, kvh, d)
-        v = kv[:, :, 1].reshape(b, npg * page, kvh, d)
+        _, j, _, _, lanes = kv_pool.shape
+        k, v = paged_kv.dense_kv(kv_pool, block_tables,
+                                 j * lanes // q.shape[-1])
         out = attention_xla(q[:, None], k, v, causal=False, lengths=lengths)
         return out[:, 0]
-    from repro.runtime.paged_kv import paged_decode_attention
-    return paged_decode_attention(q, kv_pool, block_tables, lengths,
-                                  policy=_session_kernel_policy(interpret))
+    pol = _session_kernel_policy()
+    return _on_mesh(
+        lambda *a: paged_kv.paged_decode_attention(*a, policy=pol),
+        q, kv_pool, block_tables, lengths,
+        batched=(True, False, True, True))
 
 
 # ---------------------------------------------------------------------------

@@ -30,7 +30,10 @@ seconds, bitwise flags, plan-stat breakdowns), gating CI on ``ok``.
 
 Workers run as ``python -m repro.runtime.chaos <scenario> ...`` so the
 orchestrator controls their device topology (``XLA_FLAGS``) and plan
-caches per process — the restart legitimately starts cold.
+caches per process — the restart legitimately starts cold. This is a
+CPU-only harness: every worker is pinned to ``JAX_PLATFORMS=cpu`` with
+forced host devices. It never runs on the chip, where one process holds
+the TPU and a child that needs it would fail or hang.
 """
 
 from __future__ import annotations
@@ -88,7 +91,7 @@ def _worker_train(args) -> None:
     from repro.runtime.fault_tolerance import FTConfig, Supervisor
 
     t_start = time.perf_counter()
-    pol = PipePolicy(mode="autotune", interpret=True)
+    pol = PipePolicy(mode="autotune")
     with autotune.tuning_config(cache_path=args.plan_cache, warmup=0,
                                 iters=1, top_k=2):
         cfg = FTConfig(ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every,
@@ -173,7 +176,7 @@ def _worker_remesh(args) -> None:
     b = jax.random.normal(jax.random.key(2), (DIM, DIM), jnp.float32)
 
     def pol(spec):
-        return PipePolicy(mode="autotune", interpret=True, mesh=spec)
+        return PipePolicy(mode="autotune", mesh=spec)
 
     # offline sweep for the topology we will *fail over to* -> PlanDB
     with autotune.tuning_config(cache_path=sweep_cache, warmup=0, iters=1,

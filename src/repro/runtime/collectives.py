@@ -30,14 +30,6 @@ import jax
 import jax.numpy as jnp
 
 
-def axis_size(axis_name: str) -> int:
-    """Static size of a mapped axis. jax >= 0.5 has jax.lax.axis_size;
-    older versions constant-fold psum(1, axis) to the same int."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 @dataclasses.dataclass(frozen=True)
 class RingStream:
     """The inter-device pipe of one mapped mesh axis.
@@ -54,7 +46,7 @@ class RingStream:
     reverse: bool = False
 
     def n_words(self) -> int:
-        return axis_size(self.axis_name)
+        return jax.lax.axis_size(self.axis_name)
 
     def index(self):
         return jax.lax.axis_index(self.axis_name)

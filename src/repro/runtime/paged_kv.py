@@ -7,27 +7,32 @@ the serving cache around fixed-size KV blocks (pages):
 
   * :class:`BlockAllocator` / :class:`PagedKVCache` — a host-side free-list
     allocator over a device-resident block pool
-    ``[L, n_blocks, 2, page, KVH, hd]`` (axis 2: k=0 / v=1), with
-    per-request block tables. Admission reserves ``ceil(prompt+max_new /
+    ``[L, n_blocks, J, 2, page, lanes]`` (axis 3: k=0 / v=1), with
+    per-request block tables. The KV heads are lane-packed
+    (:func:`~repro.core.emitter.lane_pack`): each of the ``J`` lane words
+    holds ``p = KVH / J`` adjacent heads side by side, ``lanes = p * hd``
+    (128 at hd 64), so one page of one lane word — its K rows then its V
+    rows — is a contiguous ``(2*page, lanes)`` block that a single DMA of
+    whole 128-lane rows moves. Admission reserves ``ceil(prompt+max_new /
     page)`` blocks; retirement recycles them, so KV memory scales with the
     *live* token count instead of ``B * S_max``.
-  * :func:`gather_indices` — flattens a block table into the row-index
-    stream an ``ff_gather`` producer walks: word ``w = (b*KVH + h)*n_pages
-    + kj`` covers page ``kj``'s K rows then its V rows for one kv head.
+  * :func:`gather_indices` — flattens a block table into the page-index
+    stream an ``ff_gather`` producer walks: word ``w = (b*J + j)*n_pages +
+    kj`` fetches page ``kj`` of lane word ``j`` for request ``b``.
   * ``paged_decode_attention`` StreamGraph — the registered two-node graph
-    (block-table gather producer → online-softmax decode-attention
-    consumer). The gather bundles ``2*page`` row DMAs per word, its
-    ``(2*page, d)`` out blocks line up word-for-word with the consumer's
-    kv pipe, and ``check_fusion`` legalizes the edge with wpb=1: the
-    gathered pages stream through a VMEM ring and never round-trip HBM.
-    Tuned jointly via :func:`repro.core.autotune.resolve_graph`.
+    (block-table page gather producer → online-softmax decode-attention
+    consumer). The gather moves one ``(2*page, lanes)`` page per word, its
+    out blocks line up word-for-word with the consumer's kv pipe, and
+    ``check_fusion`` legalizes the edge with wpb=1: the gathered pages
+    stream through a VMEM ring and never round-trip HBM. Tuned jointly via
+    :func:`repro.core.autotune.resolve_graph`.
 
 The consumer's softmax math is identical to the contiguous
-``ff_decode_attention`` kernel at ``block_kv == page`` (same tile order,
-same f32 accumulation), so paged decode is *bitwise-equal* to the
-contiguous-cache path — rows past ``length`` (zero fill or stale recycled
-block contents) mask to ``-1e30`` and their ``exp`` underflows to exactly
-0.0. ``tests/test_serving_paged.py`` asserts this.
+``ff_decode_attention`` kernel at ``block_kv == page`` (same lane packing,
+same tile order, same f32 accumulation), so paged decode is
+*bitwise-equal* to the contiguous-cache path — rows past ``length`` (zero
+fill or stale recycled block contents) mask to ``-1e30`` and their ``exp``
+underflows to exactly 0.0. ``tests/test_serving_paged.py`` asserts this.
 """
 
 from __future__ import annotations
@@ -40,36 +45,70 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import autotune
+from repro.core.emitter import lane_pack, pack_blockdiag, unpack_blockdiag
 from repro.core.program import current_policy
 
 
 # ---------------------------------------------------------------------------
-# Block-table -> gather-row indexing
+# Pool layout: lane-packed heads
 # ---------------------------------------------------------------------------
 
 
-def gather_indices(block_tables, *, page: int, kv_heads: int,
+def pool_lanes(kv_heads: int, head_dim: int) -> Tuple[int, int]:
+    """``(J, lanes)``: the pool's lane words per page and their width."""
+    p = lane_pack(kv_heads, head_dim)
+    return kv_heads // p, p * head_dim
+
+
+def pack_pool(kv: jnp.ndarray) -> jnp.ndarray:
+    """``[..., 2, page, KVH, hd]`` (per-head) -> ``[..., J, 2, page, lanes]``
+    (the pool layout)."""
+    *lead, two, page, kvh, hd = kv.shape
+    j, lanes = pool_lanes(kvh, hd)
+    return jnp.moveaxis(kv.reshape(*lead, two, page, j, lanes), -2, -4)
+
+
+def unpack_pool(pool: jnp.ndarray, kv_heads: int) -> jnp.ndarray:
+    """Inverse of :func:`pack_pool`: ``[..., J, 2, page, lanes]`` ->
+    ``[..., 2, page, KVH, hd]``."""
+    *lead, j, two, page, lanes = pool.shape
+    x = jnp.moveaxis(pool, -4, -2)
+    return x.reshape(*lead, two, page, kv_heads, j * lanes // kv_heads)
+
+
+def dense_kv(kv_pool, block_tables, kv_heads: int):
+    """Dereference a layer's pool through the block tables densely:
+    ``(k, v)``, each ``[B, n_pages*page, KVH, hd]`` (sentinel entries clip
+    to a real block; callers mask by length)."""
+    nb, _, _, page, _ = kv_pool.shape
+    b, npg = block_tables.shape
+    bt = jnp.clip(jnp.asarray(block_tables, jnp.int32), 0, nb - 1)
+    kv = unpack_pool(kv_pool[bt], kv_heads)   # [B, npg, 2, page, KVH, hd]
+    k = kv[:, :, 0].reshape(b, npg * page, kv_heads, -1)
+    v = kv[:, :, 1].reshape(b, npg * page, kv_heads, -1)
+    return k, v
+
+
+# ---------------------------------------------------------------------------
+# Block-table -> gather page indexing
+# ---------------------------------------------------------------------------
+
+
+def gather_indices(block_tables, *, lane_words: int,
                    n_blocks: int) -> jnp.ndarray:
-    """Row indices into the row-flattened pool ``[nb*2*page*KVH, hd]`` for
+    """Page indices into the pool viewed as ``[nb*J, 2*page, lanes]`` for
     one decode step.
 
     ``block_tables``: [B, n_pages] int32 (entries >= ``n_blocks`` are
-    sentinels for unallocated pages; they clip to a real row and the
+    sentinels for unallocated pages; they clip to a real block and the
     consumer's length mask discards whatever they fetch). Returns the
-    [B*KVH*n_pages*2*page] index stream in ``ff_gather`` word order:
-    word ``(b*KVH + h)*n_pages + kj`` reads page ``kj``'s K rows
-    (offsets 0..page-1) then its V rows.
+    [B*J*n_pages] index stream in ``ff_gather`` word order: word
+    ``(b*J + j)*n_pages + kj`` reads lane word ``j`` of page ``kj``.
     """
     bt = jnp.clip(jnp.asarray(block_tables, jnp.int32), 0, n_blocks - 1)
-    which = jnp.arange(2, dtype=jnp.int32)
-    off = jnp.arange(page, dtype=jnp.int32)
-    heads = jnp.arange(kv_heads, dtype=jnp.int32)
-    # [B, KVH, n_pages, 2, page]: row = ((blk*2 + which)*page + off)*KVH + h
-    rows = ((bt[:, None, :, None, None] * 2
-             + which[None, None, None, :, None]) * page
-            + off[None, None, None, None, :]) * kv_heads \
-        + heads[None, :, None, None, None]
-    return rows.reshape(-1).astype(jnp.int32)
+    j = jnp.arange(lane_words, dtype=jnp.int32)
+    idx = bt[:, None, :] * lane_words + j[None, :, None]   # [B, J, n_pages]
+    return idx.reshape(-1).astype(jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -79,20 +118,20 @@ def gather_indices(block_tables, *, page: int, kv_heads: int,
 
 def paged_decode_attention_ref(q, kv_pool, block_tables, lengths):
     """XLA oracle: dereference the block table densely, then masked softmax.
-    q: [B, H, d]; kv_pool: [nb, 2, page, KVH, d]; block_tables: [B, n_pages];
-    lengths: [B]. Returns [B, H, d] (zeros for length-0 rows)."""
+    q: [B, H, hd]; kv_pool: [nb, J, 2, page, lanes]; block_tables:
+    [B, n_pages]; lengths: [B]. Returns [B, H, hd] (zeros for length-0
+    rows)."""
     b, h, d = q.shape
-    nb, _, page, kvh, _ = kv_pool.shape
-    npg = block_tables.shape[-1]
+    _, j, _, _, lanes = kv_pool.shape
+    kvh = j * lanes // d
     group = h // kvh
-    bt = jnp.clip(jnp.asarray(block_tables, jnp.int32), 0, nb - 1)
-    kv = kv_pool[bt]                     # [B, n_pages, 2, page, KVH, d]
-    k = kv[:, :, 0].reshape(b, npg * page, kvh, d).transpose(0, 2, 1, 3)
-    v = kv[:, :, 1].reshape(b, npg * page, kvh, d).transpose(0, 2, 1, 3)
+    k, v = dense_kv(kv_pool, block_tables, kvh)
+    k = k.transpose(0, 2, 1, 3)
+    v = v.transpose(0, 2, 1, 3)
     qg = q.reshape(b, kvh, group, d).astype(jnp.float32)
     s_ = jnp.einsum("bhgd,bhsd->bhgs", qg,
                     k.astype(jnp.float32)) * (1.0 / (d ** 0.5))
-    cols = jnp.arange(npg * page)
+    cols = jnp.arange(k.shape[2])
     s_ = jnp.where(cols[None, None, None] < lengths[:, None, None, None],
                    s_, -1e30)
     p = jax.nn.softmax(s_, axis=-1)
@@ -110,31 +149,36 @@ def build_paged_decode_graph(*, b: int, kvh: int, g_pad: int, n_pages: int,
                              page: int, d: int, dtype=jnp.float32,
                              kv_dtype=None, depth: int = 2,
                              streams: int = 1):
-    """Declare the paged-decode StreamGraph at one shape point: an
-    ``ff_gather`` producer walking the block-table row stream feeding the
-    paged online-softmax consumer through a fusable ``(2*page, d)`` edge.
+    """Declare the paged-decode StreamGraph at one shape point (``kvh``
+    KV heads of ``d`` lanes, ``g_pad`` padded query heads each): an
+    ``ff_gather`` producer walking the block-table page stream feeding the
+    paged online-softmax consumer through a fusable ``(2*page, lanes)``
+    edge.
 
-    The gather's row bundle is pinned to ``2*page`` rows per word (one
-    merged K+V page) so its out blocks coincide with the consumer's kv
-    words — the geometry ``check_fusion`` needs for wpb=1.
+    Both nodes run on lane-packed heads (:func:`pool_lanes`): the gather
+    moves one whole ``(2*page, lanes)`` page block per word, so its out
+    blocks coincide with the consumer's kv words — the geometry
+    ``check_fusion`` needs for wpb=1.
     """
     from repro.core.graph import GraphEdge, GraphNode, StreamGraph
     from repro.kernels.ff_decode_attention.kernel import build_paged_program
     from repro.kernels.ff_decode_attention.ops import \
         paged_decode_attention_workload
-    from repro.kernels.ff_gather.kernel import _ROWS
     from repro.kernels.ff_gather.kernel import build_program as gather_prog
     from repro.kernels.ff_gather.ops import gather_workload
 
     kv_dtype = kv_dtype or dtype
-    assert (2 * page) % _ROWS == 0, (page, _ROWS)
-    n_rows = b * kvh * n_pages * 2 * page
-    gather = gather_prog(n_rows, d, dtype=kv_dtype, depth=depth,
-                         streams=(2 * page) // _ROWS)
-    attn = build_paged_program(b, kvh, g_pad, n_pages, page, d, dtype=dtype,
-                               kv_dtype=kv_dtype, depth=depth,
-                               streams=streams)
-    w_g, t_g = gather_workload(n_rows, d, dtype=kv_dtype)
+    j, lanes = pool_lanes(kvh, d)
+    p = kvh // j
+    n = b * j * n_pages
+    gather = gather_prog(n, lanes, block_rows=2 * page, blocks_per_word=1,
+                         dtype=kv_dtype, depth=depth)
+    attn = build_paged_program(b, j, p * g_pad, n_pages, page, lanes,
+                               dtype=dtype, kv_dtype=kv_dtype, depth=depth,
+                               streams=streams, scale=1.0 / (d ** 0.5),
+                               lane_heads=p)
+    w_g, t_g = gather_workload(n, lanes, block_rows=2 * page,
+                               blocks_per_word=1, dtype=kv_dtype)
     w_a, t_a = paged_decode_attention_workload(
         b, kvh * g_pad, kvh, n_pages, page, d, dtype=kv_dtype)
     return StreamGraph(
@@ -144,7 +188,7 @@ def build_paged_decode_graph(*, b: int, kvh: int, g_pad: int, n_pages: int,
             GraphNode("attn", attn, workload=w_a, plan_tile=t_a),
         ),
         edges=(
-            GraphEdge("gather", "attn", "kv"),
+            GraphEdge("gather", "attn", "kv", reshape=(n * 2 * page, lanes)),
         ),
     )
 
@@ -153,13 +197,14 @@ def paged_decode_attention(q, kv_pool, block_tables, lengths, *,
                            policy=None) -> jnp.ndarray:
     """Decode attention for one new token through the block table.
 
-    q: [B, H, d]; kv_pool: [n_blocks, 2, page, KVH, d] (one layer's pool);
-    block_tables: [B, n_pages] int32; lengths: [B] int32 (0 = inactive
-    slot). Returns [B, H, d].
+    q: [B, H, hd]; kv_pool: [n_blocks, J, 2, page, lanes] (one layer's
+    pool); block_tables: [B, n_pages] int32; lengths: [B] int32 (0 =
+    inactive slot). Returns [B, H, hd].
     """
     policy = current_policy() if policy is None else policy
     b, h, d = q.shape
-    nb, _, page, kvh, _ = kv_pool.shape
+    nb, j, _, page, lanes = kv_pool.shape
+    kvh = j * lanes // d
     n_pages = block_tables.shape[-1]
     assert h % kvh == 0, (h, kvh)
     group = h // kvh
@@ -169,13 +214,14 @@ def paged_decode_attention(q, kv_pool, block_tables, lengths, *,
     g_pad = -(-group // 8) * 8
     qg = q.reshape(b, kvh, group, d)
     qg = jnp.pad(qg, ((0, 0), (0, 0), (0, g_pad - group), (0, 0)))
-    idx = gather_indices(block_tables, page=page, kv_heads=kvh, n_blocks=nb)
-    table = kv_pool.reshape(nb * 2 * page * kvh, d)
+    qp = pack_blockdiag(qg, kvh // j)
+    idx = gather_indices(block_tables, lane_words=j, n_blocks=nb)
+    table = kv_pool.reshape(nb * j, 2 * page, lanes)
 
     def build(depth=2, streams=1):
         return build_paged_decode_graph(
             b=b, kvh=kvh, g_pad=g_pad, n_pages=n_pages, page=page, d=d,
-            dtype=qg.dtype, kv_dtype=kv_pool.dtype, depth=depth,
+            dtype=qp.dtype, kv_dtype=kv_pool.dtype, depth=depth,
             streams=streams)
 
     from repro.core import graph as graphlib
@@ -187,7 +233,7 @@ def paged_decode_attention(q, kv_pool, block_tables, lengths, *,
         cg = graphlib.compile_graph(
             build(depth=depth, streams=streams),
             policy=policy.replace(mode="ff", depth=depth, streams=streams))
-        return lambda: cg(idx, table, lens, qg)
+        return lambda: cg(idx, table, lens, qp)
 
     choice = autotune.resolve_graph(
         "paged_decode_attention", policy, workload=w, tile=tile,
@@ -206,7 +252,7 @@ def paged_decode_attention(q, kv_pool, block_tables, lengths, *,
         build(depth=choice.depth, streams=choice.streams),
         policy=policy.replace(mode=mode, depth=choice.depth,
                               streams=choice.streams))
-    out = cg(idx, table, lens, qg)
+    out = unpack_blockdiag(cg(idx, table, lens, qp), kvh // j)
     return out[:, :, :group, :].reshape(b, h, d)
 
 
@@ -221,33 +267,40 @@ def scatter_prefill(pool, k, v, block_tables, lengths, *, page: int,
                     n_blocks: int):
     """Write prefill KV into the pool through the block tables.
 
-    pool: [L, nb, 2, page, KVH, hd]; k, v: [L, B, S_p, KVH, hd];
+    pool: [L, nb, J, 2, page, lanes]; k, v: [L, B, S_p, KVH, hd];
     block_tables: [B, n_pages]; lengths: [B]. Positions past ``lengths``
     route to the sentinel block id ``n_blocks`` and drop.
     """
-    s_p = k.shape[2]
+    n_l, b, s_p = k.shape[:3]
+    j, lanes = pool.shape[2], pool.shape[-1]
     pos = jnp.arange(s_p)
     bt = jnp.asarray(block_tables, jnp.int32)
     blk = bt[:, jnp.clip(pos // page, 0, bt.shape[1] - 1)]     # [B, S_p]
     blk = jnp.where(pos[None] < lengths[:, None], blk, n_blocks)
     off = jnp.broadcast_to(pos % page, blk.shape)
-    pool = pool.at[:, blk, 0, off].set(k, mode="drop")
-    pool = pool.at[:, blk, 1, off].set(v, mode="drop")
+
+    def rows(x):    # [L, B, S_p, KVH, hd] -> [B, S_p, L, J, lanes]
+        return x.reshape(n_l, b, s_p, j, lanes).transpose(1, 2, 0, 3, 4)
+
+    pool = pool.at[:, blk, :, 0, off].set(rows(k), mode="drop")
+    pool = pool.at[:, blk, :, 1, off].set(rows(v), mode="drop")
     return pool
 
 
 def scatter_token(pool_layer, block_tables, lengths, k_new, v_new,
                   n_blocks: int):
     """Append one token's K/V at position ``lengths`` (per row) into one
-    layer's pool. pool_layer: [nb, 2, page, KVH, hd]; k_new, v_new:
+    layer's pool. pool_layer: [nb, J, 2, page, lanes]; k_new, v_new:
     [B, KVH, hd]. Sentinel table entries (>= n_blocks) drop the write."""
-    page = pool_layer.shape[2]
+    _, j, _, page, lanes = pool_layer.shape
     b = k_new.shape[0]
     bt = jnp.asarray(block_tables, jnp.int32)
     blk = bt[jnp.arange(b), jnp.clip(lengths // page, 0, bt.shape[1] - 1)]
     off = lengths % page
-    pool_layer = pool_layer.at[blk, 0, off].set(k_new, mode="drop")
-    pool_layer = pool_layer.at[blk, 1, off].set(v_new, mode="drop")
+    pool_layer = pool_layer.at[blk, :, 0, off].set(
+        k_new.reshape(b, j, lanes), mode="drop")
+    pool_layer = pool_layer.at[blk, :, 1, off].set(
+        v_new.reshape(b, j, lanes), mode="drop")
     return pool_layer
 
 
@@ -296,12 +349,13 @@ class BlockAllocator:
 class PagedKVCache:
     """Device-resident paged KV pool + host-side slot/block bookkeeping.
 
-    The pool is one array ``[L, n_blocks, 2, page, KVH, hd]`` shared by all
-    decode slots; each slot owns a block table (host list of block ids).
-    ``device_state()`` materializes the per-layer view the model consumes:
-    ``{"kv_pool": [L, nb, 2, page, KVH, hd], "block_tables": [L, B, n_pages],
-    "lengths": unused-by-model}``. Unallocated table entries hold the
-    sentinel id ``n_blocks`` (scatters drop, gathers clip + mask).
+    The pool is one array ``[L, n_blocks, J, 2, page, lanes]`` (lane-packed
+    heads, :func:`pool_lanes`) shared by all decode slots; each slot owns a
+    block table (host list of block ids). :meth:`cache_view` materializes
+    the per-layer view the model consumes: ``{"kv_pool": [L, nb, J, 2,
+    page, lanes], "block_tables": [L, B, n_pages]}``. Unallocated table
+    entries hold the sentinel id ``n_blocks`` (scatters drop, gathers clip
+    + mask).
     """
 
     def __init__(self, *, n_layers: int, n_blocks: int, page: int,
@@ -314,8 +368,8 @@ class PagedKVCache:
         self.head_dim = head_dim
         self.n_slots = n_slots
         self.n_pages_max = n_pages_max
-        self.pool = jnp.zeros(
-            (n_layers, n_blocks, 2, page, kv_heads, head_dim), dtype)
+        j, lanes = pool_lanes(kv_heads, head_dim)
+        self.pool = jnp.zeros((n_layers, n_blocks, j, 2, page, lanes), dtype)
         self.allocator = BlockAllocator(n_blocks)
         # host bookkeeping: per-slot block ids / lengths (sentinel-filled)
         self._tables = np.full((n_slots, n_pages_max), n_blocks, np.int32)
@@ -404,9 +458,10 @@ class PagedKVCache:
 # Graph registration (smoke point for BENCH_graph / test_graphs)
 # ---------------------------------------------------------------------------
 
-# b=2 kv_heads=2 g_pad=8 n_pages=4 page=16 d=64 over a 12-block pool;
-# block tables drawn from a permutation so the gather is genuinely
-# non-contiguous, lengths mixed (one partial page, one full table)
+# b=2 kv_heads=2 g_pad=8 n_pages=4 page=16 d=64 over a 12-block pool (the
+# two hd-64 heads share one 128-lane word); block tables drawn from a
+# permutation so the gather is genuinely non-contiguous, lengths mixed (one
+# partial page, one full table)
 _SMOKE = dict(b=2, kvh=2, g_pad=8, n_pages=4, page=16, d=64, nb=12)
 
 
@@ -420,57 +475,71 @@ def _paged_build(*, depth: int = 2, streams: int = 1):
 
 def _paged_inputs(key):
     """Operands in CompiledGraph.arg_names order:
-    (gather.idx, gather.table, attn.lengths, attn.q)."""
+    (gather.idx, gather.table, attn.lengths, attn.q) — the pool viewed as
+    ``[nb*J, 2*page, lanes]`` pages, q lane-packed block-diagonally."""
     c = _SMOKE
-    n_rows = c["nb"] * 2 * c["page"] * c["kvh"]
-    table = jax.random.normal(key, (n_rows, c["d"]), jnp.float32)
+    j, lanes = pool_lanes(c["kvh"], c["d"])
+    table = jax.random.normal(key, (c["nb"] * j, 2 * c["page"], lanes),
+                              jnp.float32)
     perm = jax.random.permutation(
         jax.random.fold_in(key, 1), c["nb"])[:c["b"] * c["n_pages"]]
     bt = perm.reshape(c["b"], c["n_pages"]).astype(jnp.int32)
-    idx = gather_indices(bt, page=c["page"], kv_heads=c["kvh"],
-                         n_blocks=c["nb"])
+    idx = gather_indices(bt, lane_words=j, n_blocks=c["nb"])
     lens = jnp.array([37, c["n_pages"] * c["page"]], jnp.int32)
     q = 0.3 * jax.random.normal(jax.random.fold_in(key, 2),
                                 (c["b"], c["kvh"], c["g_pad"], c["d"]),
                                 jnp.float32)
-    return (idx, table, lens, q)
+    return (idx, table, lens, pack_blockdiag(q, c["kvh"] // j))
+
+
+def _paged_heads(idx, table):
+    """Per-head dense K/V [b, KVH, S, d] of the gathered page stream."""
+    c = _SMOKE
+    j, lanes = pool_lanes(c["kvh"], c["d"])
+    s = c["n_pages"] * c["page"]
+    kv = table[idx].reshape(c["b"], j, c["n_pages"], 2, c["page"], lanes)
+    kv = kv.reshape(c["b"], j, c["n_pages"], 2, c["page"], c["kvh"] // j,
+                    c["d"])
+    kv = kv.transpose(0, 1, 5, 3, 2, 4, 6)      # [b, j, p, 2, npg, page, d]
+    kv = kv.reshape(c["b"], c["kvh"], 2, s, c["d"])
+    return kv[:, :, 0], kv[:, :, 1]
 
 
 def _paged_ref(idx, table, lengths, q):
-    """Masked-softmax oracle over the gathered row stream."""
+    """Masked-softmax oracle over the gathered page stream, in the graph's
+    lane-packed output layout."""
     c = _SMOKE
-    b, kvh, g_pad, d = q.shape
-    s = c["n_pages"] * c["page"]
-    kv = table[idx].reshape(b, kvh, c["n_pages"], 2, c["page"], d)
-    k = kv[:, :, :, 0].reshape(b, kvh, s, d)
-    v = kv[:, :, :, 1].reshape(b, kvh, s, d)
-    s_ = jnp.einsum("bhgd,bhsd->bhgs", q.astype(jnp.float32),
-                    k.astype(jnp.float32)) * (1.0 / (d ** 0.5))
-    cols = jnp.arange(s)
+    p = c["kvh"] // pool_lanes(c["kvh"], c["d"])[0]
+    k, v = _paged_heads(idx, table)
+    qh = unpack_blockdiag(q, p)                  # [b, KVH, g_pad, d]
+    s_ = jnp.einsum("bhgd,bhsd->bhgs", qh.astype(jnp.float32),
+                    k.astype(jnp.float32)) * (1.0 / (c["d"] ** 0.5))
+    cols = jnp.arange(k.shape[2])
     s_ = jnp.where(cols[None, None, None] < lengths[:, None, None, None],
                    s_, -1e30)
-    p = jax.nn.softmax(s_, axis=-1)
-    out = jnp.einsum("bhgs,bhsd->bhgd", p, v.astype(jnp.float32))
-    return out.astype(q.dtype)
+    pr = jax.nn.softmax(s_, axis=-1)
+    out = jnp.einsum("bhgs,bhsd->bhgd", pr, v.astype(jnp.float32))
+    return pack_blockdiag(out.astype(q.dtype), p)
 
 
 def _paged_unfused(idx, table, lengths, q):
     """Gather then decode-attention as two separate repro.ops calls — the
-    gathered [n_rows, d] page stream round-trips HBM (the BENCH_graph
-    staged baseline). block_kv is pinned to the page size so the
-    comparison isolates the lowering, not the tiling."""
+    gathered page stream round-trips HBM (the BENCH_graph staged
+    baseline). block_kv is pinned to the page size so the comparison
+    isolates the lowering, not the tiling."""
     import repro
 
     c = _SMOKE
-    b, kvh, g_pad, d = q.shape
-    s = c["n_pages"] * c["page"]
-    rows = repro.ops.gather(table, idx)
-    kv = rows.reshape(b, kvh, c["n_pages"], 2, c["page"], d)
-    k = kv[:, :, :, 0].reshape(b, kvh, s, d)
-    v = kv[:, :, :, 1].reshape(b, kvh, s, d)
+    p = c["kvh"] // pool_lanes(c["kvh"], c["d"])[0]
+    n_tab, rows, lanes = table.shape
+    pages = repro.ops.gather(table.reshape(n_tab, rows * lanes), idx)
+    k, v = _paged_heads(jnp.arange(idx.shape[0]),
+                        pages.reshape(-1, rows, lanes))
+    qh = unpack_blockdiag(q, p)
+    b, kvh, g_pad, d = qh.shape
     out = repro.ops.decode_attention(
-        q.reshape(b, kvh * g_pad, d), k, v, lengths, block_kv=c["page"])
-    return out.reshape(b, kvh, g_pad, d)
+        qh.reshape(b, kvh * g_pad, d), k, v, lengths, block_kv=c["page"])
+    return pack_blockdiag(out.reshape(b, kvh, g_pad, d), p)
 
 
 def _paged_sweep_inputs(key, site):
@@ -482,9 +551,10 @@ def _paged_sweep_inputs(key, site):
     d, nb = int(site["d"]), int(site["n_blocks"])
     kv_dt = jnp.dtype(site.get("dtype", "float32"))
     q_dt = jnp.dtype(site.get("q_dtype", "float32"))
+    j, lanes = pool_lanes(kvh, d)
     q = 0.3 * jax.random.normal(key, (b, h, d), q_dt)
     pool = jax.random.normal(jax.random.fold_in(key, 1),
-                             (nb, 2, page, kvh, d), kv_dt)
+                             (nb, j, 2, page, lanes), kv_dt)
     bt = (jax.random.permutation(jax.random.fold_in(key, 2),
                                  max(nb, b * n_pages))[:b * n_pages]
           % nb).reshape(b, n_pages).astype(jnp.int32)

@@ -32,7 +32,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
-from repro.runtime.collectives import axis_size
 
 
 @dataclasses.dataclass(frozen=True)
@@ -49,7 +48,7 @@ class StageHandoff:
     axis_name: str
 
     def n_stages(self) -> int:
-        return axis_size(self.axis_name)
+        return jax.lax.axis_size(self.axis_name)
 
     def stage(self):
         return jax.lax.axis_index(self.axis_name)

@@ -14,14 +14,11 @@ single-device call sites. The bridge is deliberately thin:
   with the mesh-tagged policy installed as the session default inside the
   body. The body sees *local shard shapes*, so the planner automatically
   derives per-shard local workloads — the kernel running on 1/Nth of the
-  batch plans 1/Nth of the word schedule, not the global one;
-* :func:`shard_map_compat` papers over the ``jax.shard_map`` /
-  ``jax.experimental.shard_map`` relocation (jax < 0.5), exactly like the
-  distributed tests do, so every runtime module shares one shim.
+  batch plans 1/Nth of the word schedule, not the global one.
 
 Example — a registry kernel under an 8-way data mesh::
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))        # repro.launch.mesh
     with sharding.use_sharding(mesh):
         f = shard_streams(repro.ops.matmul,
                           in_specs=(P("data"), P(None, None)),
@@ -40,18 +37,6 @@ from repro.core.meshspec import MeshSpec
 from repro.core.program import PipePolicy, current_policy
 from repro.core.program import policy as policy_ctx
 from repro.runtime import sharding as shlib
-
-
-def shard_map_compat(f: Callable[..., Any], mesh, in_specs, out_specs,
-                     check: bool = False) -> Callable[..., Any]:
-    """``jax.shard_map`` across jax versions (< 0.5 keeps it in
-    jax.experimental with the replication-check kwarg named check_rep)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check)
 
 
 def mesh_policy(policy: Optional[PipePolicy] = None,
@@ -111,4 +96,5 @@ def shard_streams(fn: Callable[..., Any], *, in_specs, out_specs,
 
     with obs.span("shard_streams", mesh=pol.mesh.token,
                   devices=pol.mesh.device_count):
-        return shard_map_compat(body, mesh, in_specs, out_specs, check=check)
+        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=check)

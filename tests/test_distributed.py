@@ -20,16 +20,9 @@ def run_sub(body: str, n_dev: int = 8, timeout: int = 560) -> str:
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={n_dev}"
         import jax, jax.numpy as jnp, numpy as np
+        from jax import shard_map
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-        if hasattr(jax, "shard_map"):
-            shard_map = jax.shard_map
-        else:
-            # jax < 0.5: shard_map lives in jax.experimental and the
-            # replication-check kwarg is named check_rep, not check_vma
-            from jax.experimental.shard_map import shard_map as _shard_map
-            def shard_map(f, mesh, in_specs, out_specs, check_vma=True):
-                return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                                  out_specs=out_specs, check_rep=check_vma)
+        from repro.launch.mesh import make_mesh
     """) + textwrap.dedent(body)
     env = {**os.environ, "PYTHONPATH": SRC, "JAX_PLATFORMS": "cpu"}
     r = subprocess.run([sys.executable, "-c", prog], env=env,
@@ -60,7 +53,7 @@ def test_sharded_train_step_matches_single_device():
         p1, _, m1 = jax.jit(ts)(params, opt_init(params), batch)
 
         # sharded over (data=4, model=2)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         with shlib.use_sharding(mesh):
             p2, _, m2 = jax.jit(ts)(params, opt_init(params), batch)
         d = max(float(jnp.max(jnp.abs(a - b)))
@@ -77,7 +70,7 @@ def test_sharded_train_step_matches_single_device():
 def test_compressed_allreduce_error_bound():
     out = run_sub("""
         from repro.optim.compression import compressed_allreduce, quantize
-        mesh = jax.make_mesh((8,), ("d",))
+        mesh = make_mesh((8,), ("d",))
         x = jax.random.normal(jax.random.key(0), (8, 64, 128)) * 3.0
         f = shard_map(lambda s: compressed_allreduce(s, "d"),
                       mesh=mesh, in_specs=P("d"), out_specs=P("d"))
@@ -95,7 +88,7 @@ def test_compressed_allreduce_error_bound():
 def test_allgather_matmul_overlap_equals_plain():
     out = run_sub("""
         from repro.runtime.collectives import allgather_matmul, matmul_reducescatter
-        mesh = jax.make_mesh((8,), ("d",))
+        mesh = make_mesh((8,), ("d",))
         x = jax.random.normal(jax.random.key(0), (64, 32))
         w = jax.random.normal(jax.random.key(1), (32, 16))
         f = shard_map(lambda xs, w: allgather_matmul(xs, w, "d"),
@@ -123,7 +116,7 @@ def test_pipeline_parallel_matches_sequential():
     out = run_sub("""
         from repro.runtime.pipeline_parallel import pipeline_apply
         n_stage, m, mb, d = 4, 8, 4, 16
-        mesh = jax.make_mesh((n_stage,), ("pod",))
+        mesh = make_mesh((n_stage,), ("pod",))
         ws = jax.random.normal(jax.random.key(0), (n_stage, d, d)) / (d ** 0.5)
         x = jax.random.normal(jax.random.key(1), (m, mb, d))
 

@@ -97,9 +97,10 @@ def test_mixed_depth_pipes():
 
 
 def test_gather_ring_scratch_shapes():
-    """The gather emitter owns one semaphore per (slot, row)."""
+    """The gather emitter issues one DMA per row of a word; the rows of a
+    slot share one semaphore (each wait consumes one row's bytes)."""
     ring = GatherRingPipe(Pipe(tile=(8, 128), dtype=jnp.float32, depth=3))
-    assert ring.n_dmas == 8
+    assert ring.n_dmas == 8 and ring.n_sems == 1
     buf, sems = ring.scratch_shapes
     assert buf.shape == (3, 8, 128)
 

@@ -322,3 +322,68 @@ def test_policy_is_frozen_and_replaceable():
         pol.mode = "baseline"
     assert pol.replace(mode="baseline").mode == "baseline"
     assert pol.mode == "ff"
+
+
+# ---------------------------------------------------------------------------
+# Backend-derived defaults: interpret mode, hardware model, row tiling
+# ---------------------------------------------------------------------------
+
+def test_policy_derives_interpret_from_backend():
+    on_tpu = jax.default_backend() == "tpu"
+    assert PipePolicy().interpret is (not on_tpu)
+    assert PipePolicy(interpret=True).interpret is True
+    assert PipePolicy(interpret=False).interpret is False
+    # the resolved flag is what the plan key carries
+    from repro.core import autotune
+    sig = autotune._policy_constraints(PipePolicy())
+    assert f"|interp{int(not on_tpu)}" in sig
+
+
+def test_policy_hw_from_device_kind():
+    from repro.core.pipeline_model import TPU_V5E, device_hardware
+
+    class Dev:
+        def __init__(self, platform, kind):
+            self.platform, self.device_kind = platform, kind
+
+    assert device_hardware(Dev("tpu", "TPU v5 lite")) is TPU_V5E
+    assert device_hardware(Dev("cpu", "cpu")) is TPU_V5E
+    with pytest.raises(ValueError, match="TPU v9"):
+        device_hardware(Dev("tpu", "TPU v9"))
+    # an explicit hw wins, and the default on the CPU plans for TPU v5e
+    assert PipePolicy(hw=ARRIA_CX).hw is ARRIA_CX
+    assert PipePolicy().hw is device_hardware(jax.devices()[0])
+
+
+@pytest.mark.parametrize("dtype,rows,allowed", [
+    (jnp.float32, 16, (1, 2)),
+    (jnp.bfloat16, 16, (1,)),
+    (jnp.bfloat16, 64, (1, 2, 4)),
+    (jnp.int8, 64, (1, 2)),
+])
+def test_planner_never_splits_below_row_tiling(dtype, rows, allowed):
+    """A stream split must leave each DMA whole native row tiles (8 rows
+    for 32-bit, 16 for bf16, 32 for int8): the TPU compiler refuses less."""
+    from repro.core.pipe import clamp_streams
+    from repro.core.pipeline_model import Workload
+    from repro.core.planner import plan_pipe
+    w = Workload(n_words=64, word_bytes=float(rows * 128 * 4),
+                 flops_per_word=0.0)
+    plan = plan_pipe(w, (rows, 128), dtype, stream_options=(1, 2, 4))
+    assert plan.pipe.streams in allowed
+    assert clamp_streams(rows, 4, dtype) == max(allowed)
+
+
+def test_compile_cache_location(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; without it the cache goes
+    to the fixed in-checkout path on a TPU, and stays off elsewhere."""
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert compile_cache.CHECKOUT_CACHE_DIR.name == ".jax_cache"
+    if jax.default_backend() != "tpu":
+        assert compile_cache.enable_compile_cache() is None
+        assert jax.config.jax_compilation_cache_dir == before

@@ -29,8 +29,8 @@ from repro.core.program import PipePolicy
 from repro.launch import serve as serve_lib
 from repro.launch import steps as steps_lib
 from repro.runtime.paged_kv import (BlockAllocator, OutOfBlocks,
-                                    PagedKVCache, gather_indices,
-                                    paged_decode_attention)
+                                    PagedKVCache, gather_indices, pack_pool,
+                                    paged_decode_attention, unpack_pool)
 
 KEY = jax.random.key(11)
 ARCH = "qwen1_5_0p5b"
@@ -89,15 +89,29 @@ def test_allocator_no_external_fragmentation():
 
 
 def test_gather_indices_layout():
-    page, kvh, nb = 4, 3, 6
+    j, nb = 3, 6
     bt = jnp.array([[5, 2], [0, nb]], jnp.int32)   # second row: sentinel
-    idx = np.asarray(gather_indices(bt, page=page, kv_heads=kvh,
-                                    n_blocks=nb))
-    idx = idx.reshape(2, kvh, 2, 2, page)          # [B, KVH, npg, 2, page]
-    for b, h, pg, which, off in np.ndindex(2, kvh, 2, 2, page):
+    idx = np.asarray(gather_indices(bt, lane_words=j, n_blocks=nb))
+    idx = idx.reshape(2, j, 2)                     # [B, J, npg]
+    for b, w, pg in np.ndindex(2, j, 2):
         blk = min(int(bt[b, pg]), nb - 1)          # sentinel clips
-        expect = ((blk * 2 + which) * page + off) * kvh + h
-        assert idx[b, h, pg, which, off] == expect
+        assert idx[b, w, pg] == blk * j + w
+
+
+def test_pool_pages_are_whole_lane_words():
+    """hd-64 heads pair up in 128-lane words: one gathered page block holds
+    exactly its two heads' K rows then V rows, and the layout round-trips."""
+    nb, page, kvh, d = 3, 4, 4, 64
+    heads = jax.random.normal(KEY, (nb, 2, page, kvh, d), jnp.float32)
+    pool = pack_pool(heads)
+    assert pool.shape == (nb, 2, 2, page, 128)
+    np.testing.assert_array_equal(unpack_pool(pool, kvh), heads)
+    pages = pool.reshape(nb * 2, 2 * page, 128)
+    blk, w = 2, 1
+    want = jnp.concatenate(
+        [heads[blk, which, :, 2 * w:2 * w + 2].reshape(page, 128)
+         for which in (0, 1)])
+    np.testing.assert_array_equal(pages[blk * 2 + w], want)
 
 
 # ---------------------------------------------------------------------------
@@ -123,13 +137,14 @@ def test_paged_kernel_bitwise_vs_contiguous():
     b, h, kvh, d = 2, 8, 2, 64
     nb, page, npg = 12, 32, 5
     s = npg * page
-    pool = jax.random.normal(KEY, (nb, 2, page, kvh, d), jnp.float32)
+    heads = jax.random.normal(KEY, (nb, 2, page, kvh, d), jnp.float32)
+    pool = pack_pool(heads)
     perm = np.random.default_rng(3).permutation(nb)[:b * npg]
     bt = jnp.asarray(perm.reshape(b, npg), jnp.int32)
     lens = jnp.array([97, s], jnp.int32)
     q = jax.random.normal(jax.random.fold_in(KEY, 1), (b, h, d),
                           jnp.float32)
-    kv = pool[bt]
+    kv = heads[bt]
     k = kv[:, :, 0].reshape(b, s, kvh, d).transpose(0, 2, 1, 3)
     v = kv[:, :, 1].reshape(b, s, kvh, d).transpose(0, 2, 1, 3)
     pol = PipePolicy(mode="ff", depth=2, streams=1, interpret=True)

@@ -29,6 +29,7 @@ def run_sub(body: str, n_dev: int = 8, timeout: int = 560) -> str:
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={n_dev}"
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        from repro.launch.mesh import make_mesh
     """) + textwrap.dedent(body)
     env = {**os.environ, "PYTHONPATH": SRC, "JAX_PLATFORMS": "cpu"}
     r = subprocess.run([sys.executable, "-c", prog], env=env,
@@ -72,7 +73,7 @@ def test_shard_map_plans_local_workload_with_mesh_key():
         from repro.runtime import sharding as shlib
         from repro.runtime.streams import shard_streams
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         m_global, n, k = 8 * 192, 160, 136
         a = jax.random.normal(jax.random.key(0), (m_global, k), jnp.float32)
         b = jax.random.normal(jax.random.key(1), (k, n), jnp.float32)
@@ -114,16 +115,16 @@ def test_collectives_policy_routes_stream_matmul():
         from repro.runtime import sharding as shlib
         from repro.runtime.collectives import allgather_matmul, \\
             matmul_reducescatter
-        from repro.runtime.streams import shard_map_compat
 
-        mesh = jax.make_mesh((8,), ("d",))
+        mesh = make_mesh((8,), ("d",))
         pol = PipePolicy(interpret=True)
         x = jax.random.normal(jax.random.key(0), (64, 32))
         w = jax.random.normal(jax.random.key(1), (32, 16))
         with shlib.use_sharding(mesh):
-            f = shard_map_compat(
+            f = jax.shard_map(
                 lambda xs, ws: allgather_matmul(xs, ws, "d", policy=pol),
-                mesh, (P("d", None), P(None, None)), P(None, None))
+                mesh=mesh, in_specs=(P("d", None), P(None, None)),
+                out_specs=P(None, None), check_vma=False)
             got = f(x, w)
         np.testing.assert_allclose(np.asarray(got), np.asarray(x @ w),
                                    rtol=1e-4, atol=1e-4)
@@ -133,9 +134,10 @@ def test_collectives_policy_routes_stream_matmul():
         x2 = jax.random.normal(jax.random.key(2), (64, 128))
         w2 = jax.random.normal(jax.random.key(3), (128, 16))
         with shlib.use_sharding(mesh):
-            g = shard_map_compat(
+            g = jax.shard_map(
                 lambda xs, ws: matmul_reducescatter(xs, ws, "d", policy=pol),
-                mesh, (P(None, "d"), P("d", None)), P("d", None))
+                mesh=mesh, in_specs=(P(None, "d"), P("d", None)),
+                out_specs=P("d", None), check_vma=False)
             got2 = g(x2, w2)
         np.testing.assert_allclose(np.asarray(got2), np.asarray(x2 @ w2),
                                    rtol=1e-4, atol=1e-4)
@@ -151,10 +153,9 @@ def test_pipeline_apply_with_policy_matches_sequential():
         from repro.core import PipePolicy
         from repro.runtime.pipeline_parallel import pipeline_apply
         from repro.runtime import sharding as shlib
-        from repro.runtime.streams import shard_map_compat
 
         n_stage, m, mb, d = 4, 8, 4, 16
-        mesh = jax.make_mesh((n_stage,), ("pod",))
+        mesh = make_mesh((n_stage,), ("pod",))
         ws = jax.random.normal(jax.random.key(0), (n_stage, d, d)) / (d ** 0.5)
         x = jax.random.normal(jax.random.key(1), (m, mb, d))
 
@@ -163,10 +164,11 @@ def test_pipeline_apply_with_policy_matches_sequential():
 
         pol = PipePolicy(interpret=True)
         with shlib.use_sharding(mesh):
-            f = shard_map_compat(
+            f = jax.shard_map(
                 lambda w, x: pipeline_apply(stage, w[0], x, "pod",
                                             policy=pol),
-                mesh, (P("pod"), P(None)), P("pod"))
+                mesh=mesh, in_specs=(P("pod"), P(None)),
+                out_specs=P("pod"), check_vma=False)
             got = f(ws, x)
 
         want = x
