@@ -3,7 +3,7 @@
 
 With no arguments (one chip), this serves ``qwen1_5_0p5b`` at its published
 widths (24 layers, d_model 1024, 16 heads of hd 64, d_ff 2816, vocab
-151936, bf16, random weights from ``--seed``) through
+151936, bf16, random weights from ``SEED``) through
 ``repro.launch.serve.serve_bench`` — paged continuous batching with the
 compiled ``ff`` stream kernels — and checks that
 
@@ -17,7 +17,8 @@ compiled ``ff`` stream kernels — and checks that
 ``--four-chips`` runs only the multi-chip path: one ``make_train_step``
 step of ``qwen1_5_0p5b`` (widths unchanged, depth cut to 4 layers so the
 one-device reference fits one chip) on the ``(data 2, model 2)`` mesh,
-compared with the same step on one device.
+compared with the same step on one device: loss, gradient and parameter
+update, the last two leaf by leaf.
 
 The script fails — exit code not 0, no result line — when JAX finds no
 TPU; it never falls back to the CPU. Every time it prints was taken on the
@@ -41,6 +42,16 @@ import time
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ARCH = "qwen1_5_0p5b"
+SEED = 0
+
+# the serve run: 8 requests on 4 slots, prompts up to 256 tokens, 16-32
+# new tokens each, 16-token pages
+REQUESTS, SLOTS, PROMPT_LEN, MAX_NEW, PAGE = 8, 4, 256, 32, 16
+# prompt lengths of the logits check's four rows: mixed, one at the serve
+# cap, one that ends mid-page
+LOGITS_LENS = (37, PROMPT_LEN, 5, 130)
+# the four-chip train step: 4 of the 24 layers, batch of 8 x 128 tokens
+TRAIN_LAYERS, TRAIN_BATCH = 4, (8, 128)
 
 # ff vs xla decode logits: both paths read the same bf16 KV cache and q and
 # accumulate in f32; they differ in summation order and in where values
@@ -49,19 +60,26 @@ ARCH = "qwen1_5_0p5b"
 # bf16 step is no yardstick. The yardstick is measured instead: the same
 # step through the xla path in float32 (same params, same cache). bf16
 # rounding alone moves the xla path that far from it; two bf16 paths that
-# are both right differ by at most twice that. A wrong head or lane
-# mapping, a wrong mask, or attention in a narrower type misses it.
+# are both right differ by at most twice that. Two hd-64 heads swapped in
+# the paged path read 1.22 against a floor of 0.017 (CPU, smoke widths).
 LOGITS_FLOOR_FACTOR = 2.0
 
 # sharded vs one-device train step: the same bf16 step with the matmul
 # reductions split over the model axis, so f32 partial sums are reordered
-# and a few bf16 roundings flip. Loss: absolute, ~0.1% of log(vocab).
-# Parameters: AdamW's first step moves each weight by about lr * sign(g),
-# so a flipped sign of a near-zero gradient moves that weight the other
-# way; the summed update may disagree by at most this share of the total
-# movement.
+# and some bf16 roundings flip. Loss: absolute, ~0.1% of log(vocab).
+# Gradient: AdamW's first moment after one step is 0.1 x the clipped
+# gradient, so the worst leaf's |m_mesh - m_one| / |m_one|, and the
+# relative grad-norm gap, read the sharded gradient itself. Update: the
+# worst leaf's |g (d_mesh - d_one)| / |g d_one|, d = new minus initial
+# params, weighted by the gradient g: AdamW's first step moves each weight
+# by about lr * sign(g), so where g is within rounding of zero its sign,
+# and so its step, is a coin toss; weighting by |g| keeps those weights
+# from counting. A step that leaves the params unchanged reads 1.0. On 4
+# CPU host devices at smoke widths a sound step read 0.037 (gradient) and
+# 0.020 (update); a mesh step on half the batch read 1.14 and 0.85.
 LOSS_ATOL = 1e-2
-UPDATE_RTOL = 1e-2
+GRAD_RTOL = 0.2
+UPDATE_RTOL = 0.2
 
 
 def _tpu():
@@ -78,16 +96,16 @@ def _say(msg: str) -> None:
     print(msg, flush=True)
 
 
-def serve_phase(args, kind: str):
+def serve_phase(kind: str):
     """Serve through the entry point; check every request's token budget."""
     from repro.launch import serve
     ap = argparse.ArgumentParser()
     serve.add_serve_args(ap)
     sargs = ap.parse_args([
         "--arch", ARCH, "--impl", "ff", "--policy-mode", "ff",
-        "--requests", str(args.requests), "--slots", str(args.slots),
-        "--prompt-len", str(args.prompt_len), "--max-new", str(args.max_new),
-        "--page", str(args.page), "--rate", "0", "--seed", str(args.seed)])
+        "--requests", str(REQUESTS), "--slots", str(SLOTS),
+        "--prompt-len", str(PROMPT_LEN), "--max-new", str(MAX_NEW),
+        "--page", str(PAGE), "--rate", "0", "--seed", str(SEED)])
     t0 = time.perf_counter()
     result = serve.serve_bench(sargs)
     wall = time.perf_counter() - t0
@@ -113,7 +131,7 @@ def serve_phase(args, kind: str):
     return result
 
 
-def logits_phase(args, kind: str) -> None:
+def logits_phase(kind: str) -> None:
     """One paged decode step through the ff kernels vs the xla path, on the
     same prefilled paged state and the same params."""
     import jax
@@ -135,13 +153,13 @@ def logits_phase(args, kind: str) -> None:
     policy = PipePolicy(mode="ff")
     if policy.interpret and jax.default_backend() == "tpu":
         raise AssertionError("the ff kernels would run interpreted")
-    rng = np.random.default_rng(args.seed)
-    lens = np.array([37, args.prompt_len, 5, 130], np.int32)
-    page = args.page
+    rng = np.random.default_rng(SEED)
+    lens = np.array(LOGITS_LENS, np.int32)
+    page = PAGE
     n_pages = -(-int(lens.max() + 1) // page)
     mesh = make_host_mesh(devices=jax.devices()[:1])
     with shlib.use_sharding(mesh, overrides=dict(cfg.rule_overrides or {})):
-        params = model.init(jax.random.key(args.seed))
+        params = model.init(jax.random.key(SEED))
         prefill = jax.jit(steps_lib.make_prefill_step(model, policy=policy))
         kv = PagedKVCache(
             n_layers=cfg.n_layers, n_blocks=len(lens) * n_pages, page=page,
@@ -193,10 +211,33 @@ def logits_phase(args, kind: str) -> None:
                              f"the bf16 floor {floor}")
 
 
-def four_chip_phase(args, kind: str) -> None:
+def _worst_leaf_gap(ref, got, *, base=None, weight=None):
+    """Max over leaves of |w (got - ref)| / |w ref| (L2 norms, float32 on
+    the host), with ``base`` subtracted from both and ``w`` the leaf of
+    ``weight`` (1 without); and that leaf's path."""
+    import jax
+    import numpy as np
+
+    zeros = jax.tree.map(lambda x: 0, ref)
+    worst, where = 0.0, None
+    for (path, r), g, b, w in zip(
+            jax.tree_util.tree_leaves_with_path(ref), jax.tree.leaves(got),
+            jax.tree.leaves(zeros if base is None else base),
+            jax.tree.leaves(zeros if weight is None else weight)):
+        b = np.asarray(b, np.float32)
+        w = 1.0 if weight is None else np.abs(np.asarray(w, np.float32))
+        r = np.asarray(r, np.float32) - b
+        d = float(np.linalg.norm(w * (np.asarray(g, np.float32) - b - r)))
+        n = float(np.linalg.norm(w * r))
+        gap = d / n if n else (0.0 if d == 0 else float("inf"))
+        if not gap <= worst:            # a NaN gap is the worst
+            worst, where = gap, jax.tree_util.keystr(path)
+    return worst, where
+
+
+def four_chip_phase(kind: str) -> None:
     """One train step on the (data 2, model 2) mesh vs one device."""
     import jax
-    import jax.numpy as jnp
     import numpy as np
 
     from repro.configs.base import get_config
@@ -210,16 +251,15 @@ def four_chip_phase(args, kind: str) -> None:
     if len(devs) != 4:
         raise AssertionError(f"--four-chips needs 4 devices, found "
                              f"{len(devs)}")
-    cfg = get_config(ARCH).replace(n_layers=args.train_layers)
+    cfg = get_config(ARCH).replace(n_layers=TRAIN_LAYERS)
     model = build_model(cfg)
-    params = model.init(jax.random.key(args.seed))
+    params = model.init(jax.random.key(SEED))
     opt_cfg = adamw.AdamWConfig(lr_peak=1e-3, warmup_steps=1, total_steps=10)
-    opt_init, _ = steps_lib.opt_init_and_update(cfg.optimizer, opt_cfg)
-    k1, k2 = jax.random.split(jax.random.key(args.seed + 1))
-    shape = (args.train_batch, args.train_seq)
-    batch = {"tokens": jax.random.randint(k1, shape, 0, cfg.vocab),
-             "labels": jax.random.randint(k2, shape, 0, cfg.vocab)}
-    step = steps_lib.make_train_step(model, optimizer=cfg.optimizer,
+    opt_init, _ = steps_lib.opt_init_and_update("adamw", opt_cfg)
+    k1, k2 = jax.random.split(jax.random.key(SEED + 1))
+    batch = {"tokens": jax.random.randint(k1, TRAIN_BATCH, 0, cfg.vocab),
+             "labels": jax.random.randint(k2, TRAIN_BATCH, 0, cfg.vocab)}
+    step = steps_lib.make_train_step(model, optimizer="adamw",
                                      opt_cfg=opt_cfg)
 
     def run(mesh):
@@ -230,36 +270,34 @@ def four_chip_phase(args, kind: str) -> None:
                 jax.jit(step)(params, opt_init(params), batch))
             return out, time.perf_counter() - t0
 
-    (p1, _, m1), t1 = run(make_host_mesh(devices=devs[:1]))
-    (p2, _, m2), t2 = run(make_host_mesh(devices=devs[:4]))
-
-    def update_sum(new):
-        return sum(float(jnp.sum((a.astype(jnp.float32)
-                                  - b.astype(jnp.float32)), dtype=jnp.float32))
-                   for a, b in zip(jax.tree.leaves(new),
-                                   jax.tree.leaves(params)))
-
-    moved = sum(float(jnp.sum(jnp.abs(a.astype(jnp.float32)
-                                      - b.astype(jnp.float32))))
-                for a, b in zip(jax.tree.leaves(p1), jax.tree.leaves(params)))
-    c1, c2 = update_sum(p1), update_sum(p2)
+    (p1, s1, m1), t1 = run(make_host_mesh(devices=devs[:1]))
+    (p2, s2, m2), t2 = run(make_host_mesh(devices=devs[:4]))
     l1, l2 = float(m1["loss"]), float(m2["loss"])
     g1, g2 = float(m1["grad_norm"]), float(m2["grad_norm"])
+    norm_gap = abs(g2 - g1) / g1
+    grad_gap, grad_leaf = _worst_leaf_gap(s1["m"], s2["m"])
+    upd_gap, upd_leaf = _worst_leaf_gap(p1, p2, base=params, weight=s1["m"])
     _say(f"train step ({cfg.n_layers} of {get_config(ARCH).n_layers} "
-         f"layers, batch {shape}): one "
-         f"device {t1:.1f} s, (data 2, model 2) {t2:.1f} s, compiles "
-         f"included, on {kind}")
-    _say(f"loss one device {l1!r}, mesh {l2!r}; grad norm {g1!r} vs {g2!r}")
-    _say(f"summed parameter update one device {c1!r}, mesh {c2!r}; total "
-         f"movement {moved!r}")
-    for v in (l1, l2, g1, g2, c1, c2):
+         f"layers, batch {TRAIN_BATCH}): one device {t1:.1f} s, (data 2, "
+         f"model 2) {t2:.1f} s, compiles included, on {kind}")
+    _say(f"loss one device {l1!r}, mesh {l2!r} (limit |d| {LOSS_ATOL:g}); "
+         f"grad norm {g1!r} vs {g2!r}, relative gap {norm_gap!r} (limit "
+         f"{GRAD_RTOL:g})")
+    _say(f"worst leaf, mesh vs one device: gradient {grad_gap!r} at "
+         f"{grad_leaf} (limit {GRAD_RTOL:g}); parameter update {upd_gap!r} "
+         f"at {upd_leaf} (limit {UPDATE_RTOL:g}); no update reads 1.0")
+    for v in (l1, l2, g1, g2):
         if not np.isfinite(v):
             raise AssertionError(f"non-finite train metric {v}")
     if not abs(l1 - l2) <= LOSS_ATOL:
         raise AssertionError(f"loss differs: {l1} vs {l2}")
-    if not abs(c1 - c2) <= UPDATE_RTOL * moved:
-        raise AssertionError(f"parameter update checksum differs: {c1} vs "
-                             f"{c2} (total movement {moved})")
+    if not (norm_gap <= GRAD_RTOL and grad_gap <= GRAD_RTOL):
+        raise AssertionError(f"sharded gradient differs: grad norm gap "
+                             f"{norm_gap}, worst leaf {grad_gap} at "
+                             f"{grad_leaf}")
+    if not upd_gap <= UPDATE_RTOL:
+        raise AssertionError(f"sharded parameter update differs: worst leaf "
+                             f"{upd_gap} at {upd_leaf}")
 
 
 def main(argv=None) -> int:
@@ -267,15 +305,6 @@ def main(argv=None) -> int:
     ap.add_argument("--four-chips", action="store_true",
                     help="run only the (data 2, model 2) train step vs one "
                          "device, on a 4-chip host")
-    ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--requests", type=int, default=8)
-    ap.add_argument("--slots", type=int, default=4)
-    ap.add_argument("--prompt-len", type=int, default=256)
-    ap.add_argument("--max-new", type=int, default=32)
-    ap.add_argument("--page", type=int, default=16)
-    ap.add_argument("--train-layers", type=int, default=4)
-    ap.add_argument("--train-batch", type=int, default=8)
-    ap.add_argument("--train-seq", type=int, default=128)
     args = ap.parse_args(argv)
 
     dev = _tpu()
@@ -291,10 +320,10 @@ def main(argv=None) -> int:
     with autotune.tuning_config(
             cache_path=os.path.join(HERE, ".cache", "plans.json")):
         if args.four_chips:
-            four_chip_phase(args, kind)
+            four_chip_phase(kind)
         else:
-            serve_phase(args, kind)
-            logits_phase(args, kind)
+            serve_phase(kind)
+            logits_phase(kind)
     stats = dev.memory_stats() or {}
     if "peak_bytes_in_use" in stats:
         _say(f"peak_bytes_in_use {stats['peak_bytes_in_use']} on {kind}")

@@ -44,7 +44,8 @@ import dataclasses
 import functools
 import threading
 import warnings
-from typing import Any, Callable, Dict, Mapping, Optional, Tuple, Union
+from typing import Any, Callable, Dict, List, Mapping, Optional, Tuple, \
+    Union
 
 import jax
 import jax.numpy as jnp
@@ -138,7 +139,10 @@ def default_interpret() -> bool:
 
 class _PolicyStack(threading.local):
     def __init__(self):
-        self.stack = [PipePolicy()]
+        # the session default is built on first use, not at import:
+        # building it reads the backend, which initializes (and on a TPU
+        # host claims) the devices
+        self.stack: List[PipePolicy] = []
 
 
 _policies = _PolicyStack()
@@ -146,6 +150,8 @@ _policies = _PolicyStack()
 
 def current_policy() -> PipePolicy:
     """The session's active policy (innermost :func:`policy` context)."""
+    if not _policies.stack:
+        _policies.stack.append(PipePolicy())
     return _policies.stack[-1]
 
 

@@ -469,7 +469,11 @@ def serve_bench(args) -> Dict[str, object]:
     policy = PipePolicy(mode=args.policy_mode)
     from repro.models import build_model
     model = build_model(cfg)
-    mesh = make_host_mesh()
+    # GSPMD cannot partition a compiled (Mosaic) kernel, so with compiled
+    # kernels serving runs on one device even on a multi-chip host;
+    # interpreted kernels are plain HLO and shard over the host mesh
+    mesh = make_host_mesh(devices=None if policy.interpret
+                          else jax.devices()[:1])
 
     requests = make_requests(
         args.requests, prompt_len=args.prompt_len, max_new=args.max_new,

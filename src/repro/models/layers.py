@@ -220,31 +220,6 @@ def _session_kernel_policy():
     return pol
 
 
-def _on_mesh(fn, *args, batched: Tuple[bool, ...]):
-    """Call a compiled-kernel op under the ambient mesh.
-
-    GSPMD cannot partition a Mosaic kernel, so under a multi-device
-    :func:`~repro.runtime.sharding.use_sharding` context the call runs in
-    ``shard_map`` (:func:`~repro.runtime.streams.shard_streams`): the
-    ``batched`` operands (and the output) split their leading dim over the
-    mesh axes the "batch" rule names when it divides evenly; every other
-    operand, and every other mesh axis, is replicated, so those devices
-    compute the same result. Without a mesh the call is direct.
-    """
-    from repro.runtime import sharding as shlib
-    ctx = shlib.current()
-    if ctx is None or ctx.mesh.size == 1:
-        return fn(*args)
-    from jax.sharding import PartitionSpec as P
-
-    from repro.runtime.streams import shard_streams
-    split = shlib.divisible("batch", args[0].shape[0], ctx)
-    bspec = shlib.spec_for(("batch",), ctx) if split else P()
-    return shard_streams(
-        fn, in_specs=tuple(bspec if b else P() for b in batched),
-        out_specs=bspec, ctx=ctx)(*args)
-
-
 def _session_scan_policy(cfg_impl: str):
     """Scan-kernel policy: the model config pins the default impl, but an
     explicit session mode override (anything but the "ff" session default)
@@ -268,12 +243,9 @@ def attention_op(q, k, v, *, causal: bool, impl: str = "xla",
     kh = k.transpose(0, 2, 1, 3).reshape(b * kvh, k.shape[1], d)
     vh = v.transpose(0, 2, 1, 3).reshape(b * kvh, v.shape[1], d)
     block_q = min(128, max(8, s))
-    pol = _session_kernel_policy()
-    out = _on_mesh(
-        lambda q_, k_, v_: ff_attn(q_, k_, v_, kv_groups=h // kvh,
-                                   causal=causal, block_q=block_q,
-                                   block_kv=128, policy=pol),
-        qh, kh, vh, batched=(True, True, True))
+    out = ff_attn(qh, kh, vh, kv_groups=h // kvh, causal=causal,
+                  block_q=block_q, block_kv=128,
+                  policy=_session_kernel_policy())
     return out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
 
 
@@ -304,10 +276,8 @@ def decode_attention_op(q, k, v, lengths, *, impl: str = "xla",
     if pad:
         kh = jnp.pad(kh, ((0, 0), (0, 0), (0, pad), (0, 0)))
         vh = jnp.pad(vh, ((0, 0), (0, 0), (0, pad), (0, 0)))
-    pol = _session_kernel_policy()
-    return _on_mesh(
-        lambda *a: ff_dec(*a, block_kv=block_kv, policy=pol),
-        q, kh, vh, lengths, batched=(True, True, True, True))
+    return ff_dec(q, kh, vh, lengths, block_kv=block_kv,
+                  policy=_session_kernel_policy())
 
 
 def paged_decode_attention_op(q, kv_pool, block_tables, lengths, *,
@@ -327,11 +297,8 @@ def paged_decode_attention_op(q, kv_pool, block_tables, lengths, *,
                                  j * lanes // q.shape[-1])
         out = attention_xla(q[:, None], k, v, causal=False, lengths=lengths)
         return out[:, 0]
-    pol = _session_kernel_policy()
-    return _on_mesh(
-        lambda *a: paged_kv.paged_decode_attention(*a, policy=pol),
-        q, kv_pool, block_tables, lengths,
-        batched=(True, False, True, True))
+    return paged_kv.paged_decode_attention(q, kv_pool, block_tables, lengths,
+                                           policy=_session_kernel_policy())
 
 
 # ---------------------------------------------------------------------------

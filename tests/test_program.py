@@ -339,6 +339,21 @@ def test_policy_derives_interpret_from_backend():
     assert f"|interp{int(not on_tpu)}" in sig
 
 
+def test_import_leaves_backends_uninitialized():
+    """Importing the package must not claim a device: on a TPU host only
+    one process may hold the chip, and a parent that merely imports repro
+    before starting a child would take it."""
+    import subprocess
+    import sys
+    code = ("import repro, repro.core.program, repro.launch.serve, "
+            "repro.launch.train, repro.models, repro.kernels\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge.backends_are_initialized()\n"
+            "repro.core.program.current_policy()\n"
+            "assert xla_bridge.backends_are_initialized()\n")
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=120)
+
+
 def test_policy_hw_from_device_kind():
     from repro.core.pipeline_model import TPU_V5E, device_hardware
 
