@@ -11,6 +11,8 @@ compiled ``ff`` stream kernels — and checks that
 * one paged decode step's logits through the ``ff`` kernels agree with the
   ``impl="xla"`` reference path on the same state and params (bf16
   tolerance, reason below);
+* the paged decode attention graph alone agrees with its f32 oracle on the
+  prefilled pool, within the bf16 rounding of its output (reason below);
 * and reports the lockstep-vs-paged probe's logits difference (0.0 means
   bitwise identical; a nonzero value is printed, not hidden).
 
@@ -62,7 +64,19 @@ TRAIN_LAYERS, TRAIN_BATCH = 4, (8, 128)
 # rounding alone moves the xla path that far from it; two bf16 paths that
 # are both right differ by at most twice that. Two hd-64 heads swapped in
 # the paged path read 1.22 against a floor of 0.017 (CPU, smoke widths).
+# A paged kernel that accumulates in bf16 is not caught here (it read 1.37 x
+# the floor, CPU, 24 layers of d_model 256); the kernel check below is.
 LOGITS_FLOOR_FACTOR = 2.0
+
+# paged decode attention graph alone vs the f32 oracle, on the prefilled
+# pool (L2 over the output). A sound bf16 kernel rounds twice: its output
+# to bf16, and the softmax weights to bf16 for the PV matmul. Each is
+# measured on the oracle (its output rounded; its weights rounded), and the
+# floor combines the two as independent errors, sqrt(a^2 + b^2). A sound
+# kernel read 0.99-1.02 x that floor, one accumulating in bf16 across
+# pages 1.48-1.62 (CPU: smoke widths on the prefilled pool, and 16 KV
+# heads of hd 64 on three random pools).
+KERNEL_FLOOR_FACTOR = 1.2
 
 # sharded vs one-device train step: the same bf16 step with the matmul
 # reductions split over the model axis, so f32 partial sums are reordered
@@ -209,6 +223,50 @@ def logits_phase(kind: str) -> None:
         raise AssertionError(f"ff decode logits differ from the xla path "
                              f"by {err}, more than {LOGITS_FLOOR_FACTOR} x "
                              f"the bf16 floor {floor}")
+    kernel_phase(kind, cfg, policy, kv, lens, rng)
+
+
+def kernel_phase(kind, cfg, policy, kv, lens, rng) -> None:
+    """The paged decode attention graph on layer 0 of the prefilled pool,
+    with a random bf16 q, against the f32 oracle."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.runtime import paged_kv
+
+    q = jnp.asarray(rng.standard_normal((len(lens), cfg.n_heads, cfg.hd)),
+                    cfg.cdtype)
+    pool, tables = kv.cache_view()["kv_pool"][0], kv.device_tables()[0]
+    lengths = jnp.asarray(lens)
+    got = paged_kv.paged_decode_attention(q, pool, tables, lengths,
+                                          policy=policy)
+    with jax.default_matmul_precision("highest"):
+        ref, ref_p = (np.asarray(paged_kv.paged_decode_attention_ref(
+            q.astype(jnp.float32), pool, tables, lengths, p_dtype=p_dt))
+            for p_dt in (None, cfg.cdtype))
+    got = np.asarray(got, np.float32)
+    if got.shape != ref.shape or not np.isfinite(got).all():
+        raise AssertionError(f"paged attention: shape {got.shape} (oracle "
+                             f"{ref.shape}), finite "
+                             f"{bool(np.isfinite(got).all())}")
+    err = float(np.linalg.norm(got - ref))
+    out_floor = float(np.linalg.norm(
+        np.asarray(jnp.asarray(ref, cfg.cdtype), np.float32) - ref))
+    p_floor = float(np.linalg.norm(ref_p - ref))
+    floor = float(np.hypot(out_floor, p_floor))
+    _say(f"paged decode attention graph vs f32 oracle (layer 0, "
+         f"{cfg.n_kv_heads} KV heads of hd {cfg.hd}, lengths "
+         f"{list(map(int, lens))}): |d| = {err:.4g}, {err / floor:.4g} x "
+         f"the bf16 floor {floor:.4g} (output rounding {out_floor:.4g}, "
+         f"softmax-weight rounding {p_floor:.4g}; |oracle| "
+         f"{float(np.linalg.norm(ref)):.4g}); limit "
+         f"{KERNEL_FLOOR_FACTOR:g} x floor, on {kind}")
+    if not err <= KERNEL_FLOOR_FACTOR * floor:
+        raise AssertionError(f"paged decode attention differs from the f32 "
+                             f"oracle by {err}, more than "
+                             f"{KERNEL_FLOOR_FACTOR} x the bf16 floor "
+                             f"{floor}")
 
 
 def _worst_leaf_gap(ref, got, *, base=None, weight=None):

@@ -116,11 +116,13 @@ def gather_indices(block_tables, *, lane_words: int,
 # ---------------------------------------------------------------------------
 
 
-def paged_decode_attention_ref(q, kv_pool, block_tables, lengths):
+def paged_decode_attention_ref(q, kv_pool, block_tables, lengths, *,
+                               p_dtype=None):
     """XLA oracle: dereference the block table densely, then masked softmax.
     q: [B, H, hd]; kv_pool: [nb, J, 2, page, lanes]; block_tables:
     [B, n_pages]; lengths: [B]. Returns [B, H, hd] (zeros for length-0
-    rows)."""
+    rows). ``p_dtype`` rounds the softmax weights to that dtype before the
+    PV product, as the kernel does for its matmul (None: kept in f32)."""
     b, h, d = q.shape
     _, j, _, _, lanes = kv_pool.shape
     kvh = j * lanes // d
@@ -134,7 +136,12 @@ def paged_decode_attention_ref(q, kv_pool, block_tables, lengths):
     cols = jnp.arange(k.shape[2])
     s_ = jnp.where(cols[None, None, None] < lengths[:, None, None, None],
                    s_, -1e30)
-    p = jax.nn.softmax(s_, axis=-1)
+    if p_dtype is None:
+        p = jax.nn.softmax(s_, axis=-1)
+    else:       # as the kernel rounds them: unnormalized, the max at 1
+        e = jnp.exp(s_ - jnp.max(s_, axis=-1, keepdims=True))
+        p = e.astype(p_dtype).astype(jnp.float32) / jnp.sum(
+            e, axis=-1, keepdims=True)
     out = jnp.einsum("bhgs,bhsd->bhgd", p, v.astype(jnp.float32))
     out = jnp.where(lengths[:, None, None, None] > 0, out, 0.0)
     return out.reshape(b, h, d).astype(q.dtype)

@@ -30,7 +30,8 @@ from repro.launch import serve as serve_lib
 from repro.launch import steps as steps_lib
 from repro.runtime.paged_kv import (BlockAllocator, OutOfBlocks,
                                     PagedKVCache, gather_indices, pack_pool,
-                                    paged_decode_attention, unpack_pool)
+                                    paged_decode_attention,
+                                    paged_decode_attention_ref, unpack_pool)
 
 KEY = jax.random.key(11)
 ARCH = "qwen1_5_0p5b"
@@ -126,6 +127,32 @@ def test_paged_graph_fused_matches_oracle():
     assert err <= spec.tol, err
     assert any(e.mode == "fused" for e in compiled.plan.edges), \
         [(e.edge.label, e.rationale) for e in compiled.plan.edges]
+
+
+def test_paged_kernel_bf16_within_its_two_roundings():
+    """In bf16 the graph's error against the f32 oracle is its two
+    roundings (output, softmax weights for the PV matmul) and no more —
+    the yardstick chip_smoke.py holds the compiled kernel to."""
+    b, kvh, d, n_pages = 2, 4, 64, 4
+    nb = b * n_pages
+    k1, k2 = jax.random.split(KEY)
+    pool = pack_pool(jax.random.normal(k1, (nb, 2, PAGE, kvh, d),
+                                       jnp.bfloat16))
+    q = jax.random.normal(k2, (b, kvh, d), jnp.bfloat16)
+    bt = jnp.arange(nb, dtype=jnp.int32)[::-1].reshape(b, n_pages)
+    lens = jnp.array([13, 30], jnp.int32)
+    got = np.asarray(paged_decode_attention(q, pool, bt, lens,
+                                            policy=PipePolicy(mode="ff")),
+                     np.float32)
+    with jax.default_matmul_precision("highest"):
+        ref, ref_p = (np.asarray(paged_decode_attention_ref(
+            q.astype(jnp.float32), pool, bt, lens, p_dtype=p_dt))
+            for p_dt in (None, jnp.bfloat16))
+    out_floor = np.linalg.norm(
+        np.asarray(jnp.asarray(ref, jnp.bfloat16), np.float32) - ref)
+    p_floor = np.linalg.norm(ref_p - ref)
+    assert 0 < p_floor
+    assert np.linalg.norm(got - ref) <= 1.2 * np.hypot(out_floor, p_floor)
 
 
 def test_paged_kernel_bitwise_vs_contiguous():
