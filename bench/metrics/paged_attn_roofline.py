@@ -1,0 +1,23 @@
+"""Kernels: the paged decode attention graph's least possible time over
+its device time, in %, for the decode steps inside the traced window.
+Least time per step is the larger of its operations at the peak bf16
+rate and its live-page bytes at the peak HBM rate
+(``costs/paged_attn.py``); device time is the kernels of the decode step
+program in the trace."""
+
+import harness
+
+
+def read(ctx):
+    t = ctx.trace
+    if t is None or not t.kernel_s.get("decode_step"):
+        return None
+    paged = harness.cost("paged_attn")
+    first, last = t.steps
+    bound = 0.0
+    for s in ctx.run.steps[first:last + 1]:
+        f, b = paged.step(ctx.spec, (s.lengths + 1) * s.mask,
+                          ctx.run.page)
+        bound += max(f / ctx.peaks["bf16_flops_per_s"],
+                     b / ctx.peaks["hbm_bytes_per_s"])
+    return 100.0 * bound / t.kernel_s["decode_step"]
