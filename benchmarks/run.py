@@ -59,16 +59,12 @@ recovery seconds + plan-stat breakdowns) and exits non-zero if any
 scenario fails — the CI resilience gate. ``--smoke`` shrinks step counts
 and is consumed, like ``--serve``.
 
-``--telemetry`` runs the bandwidth-utilization suite (``repro.obs``):
-every registry kernel and every registered graph is timed with live
-tracing on (spans -> ``BENCH_trace.jsonl``), and the modeled byte counts
-are joined with the measured wall into achieved GB/s + roofline
-utilization per kernel and per graph edge (``BENCH_telemetry.json``).
-Three gates make it a CI check on the telemetry stack itself: every
-utilization must land in (0, 1], the span layer must cost < 3% wall
-overhead (interleaved disabled-vs-enabled timing), and the serve
-schedulers' live latency histograms must match the post-hoc bench
-percentiles within 10%. ``--smoke`` is consumed, like ``--serve``.
+``--telemetry`` gates the telemetry stack itself (``repro.obs``), with
+live tracing on (spans -> ``BENCH_trace.jsonl``): the span layer must
+cost < 3% wall overhead on an instrumented kernel workload (interleaved
+disabled-vs-enabled timing), and the serve schedulers' live latency
+histograms must match the post-hoc bench percentiles within 10%. Writes
+``BENCH_telemetry.json``. ``--smoke`` is consumed, like ``--serve``.
 
 ``--out-dir`` routes every bare artifact filename above (the
 ``--*-json`` defaults, ``--plans-db-out``, ``--trace-jsonl``) into one
@@ -750,25 +746,19 @@ def chaos_bench(json_path: str = "BENCH_chaos.json",
 def telemetry_bench(json_path: str = "BENCH_telemetry.json",
                     trace_path: str = "BENCH_trace.jsonl",
                     smoke: bool = True, iters: int = 5) -> None:
-    """Bandwidth-utilization telemetry: join modeled byte counts with
-    measured wall time into achieved GB/s + roofline utilization per
-    kernel and per graph edge, under live tracing (spans appended to
-    ``trace_path`` as JSONL — plan-source tags included). Gates the
-    telemetry stack itself three ways: every utilization must land in
-    (0, 1]; the span layer must cost < 3% wall overhead on an
-    instrumented workload (interleaved disabled-vs-enabled timing); and
-    the serve scheduler's live latency histogram must agree with the
-    post-hoc bench percentiles within 10%. Writes ``BENCH_telemetry
-    .json``; any gate failure exits non-zero. ``--smoke`` is consumed,
-    like ``--serve``."""
+    """Gates the telemetry stack under live tracing (spans appended to
+    ``trace_path`` as JSONL — plan-source tags included) two ways: the
+    span layer must cost < 3% wall overhead on an instrumented workload
+    (interleaved disabled-vs-enabled timing), and the serve scheduler's
+    live latency histogram must agree with the post-hoc bench percentiles
+    within 10%. Writes ``BENCH_telemetry.json``; any gate failure exits
+    non-zero. ``--smoke`` is consumed, like ``--serve``."""
     import jax
-    import numpy as np   # noqa: F401 — jax platform init order
+    import jax.numpy as jnp
 
     from repro import obs
     from repro.core import TPU_V5E, PipePolicy, planned_pipe
-    from repro.core.planner import last_plan
-    from repro.kernels.registry import (all_graphs, all_kernels,
-                                        run_graph_smoke)
+    from repro.kernels.registry import all_kernels
     from repro.launch import serve as serve_lib
 
     hw = TPU_V5E
@@ -778,88 +768,20 @@ def telemetry_bench(json_path: str = "BENCH_telemetry.json",
     prev_obs = obs.enable(trace_path or None)
     obs.metrics_clear("serve_token_latency_seconds")
     try:
-        print(f"# telemetry: achieved GB/s vs roofline "
-              f"({hw.hbm_bw / 1e9:.0f} GB/s), spans -> "
-              f"{trace_path or '<memory ring>'}")
+        print(f"# telemetry: spans -> {trace_path or '<memory ring>'}")
         policy = PipePolicy(mode="ff")
+        spec0 = all_kernels()[0]
+        args, kw = spec0.make_inputs(jax.random.key(0))
 
-        def check_util(label, util):
-            if not (0.0 < util["utilization"] <= 1.0):
-                failures.append(f"{label} utilization "
-                                f"{util['utilization']} outside (0, 1]")
+        def fn0():
+            return spec0.op(*args, **kw, policy=policy)
 
-        # 1. per-kernel: the workload the planner sized the pipe for, at
-        #    the smoke shapes actually executed, over the measured wall
-        kernels = {}
-        first_op = None
-        for spec in all_kernels():
-            try:
-                args, kw = spec.make_inputs(jax.random.key(0))
-                fn = (lambda a=args, k=kw, s=spec:
-                      s.op(*a, **k, policy=policy))
-                jax.block_until_ready(fn())   # compile + plan
-                plan = last_plan(spec.name)
-                wall_ms = _interleaved_ms([("op", fn)], warmup=1,
-                                          iters=iters)["op"]
-                util = obs.kernel_utilization(plan.workload, hw,
-                                              wall_ms / 1e3)
-            except Exception:   # noqa: BLE001 — report all kernels
-                traceback.print_exc()
-                failures.append(spec.name)
-                kernels[spec.name] = {"ok": False}
-                print(f"telemetry/{spec.name},nan,FAIL")
-                continue
-            check_util(spec.name, util)
-            if first_op is None:
-                first_op = (spec, fn)
-            util["plan"] = {"depth": plan.pipe.depth,
-                            "streams": plan.pipe.streams}
-            util["wall_ms"] = round(wall_ms, 3)
-            kernels[spec.name] = util
-            print(f"telemetry/{spec.name},{wall_ms * 1e3:.0f},"
-                  f"achieved={util['achieved_gb_s']:.3f}GB/s_"
-                  f"util={util['utilization']:.2e}")
+        jax.block_until_ready(fn0())       # compile + plan
 
-        # 2. per-graph: the compiled fused graph's estimate carries
-        #    post-fusion per-stage traffic; the measured wall is
-        #    attributed by modeled-time share, then joined per edge
-        graphs = {}
-        for spec in all_graphs():
-            try:
-                args = spec.make_inputs(jax.random.key(0))
-                _, _, _, fused = run_graph_smoke(spec)
-                wall_ms = _interleaved_ms(
-                    [("fused", lambda: fused(*args))],
-                    warmup=1, iters=iters)["fused"]
-                util = obs.graph_utilization(fused.plan.estimate, hw,
-                                             wall_ms / 1e3)
-            except Exception:   # noqa: BLE001 — report all graphs
-                traceback.print_exc()
-                failures.append(spec.name)
-                graphs[spec.name] = {"ok": False}
-                print(f"telemetry/{spec.name},nan,FAIL")
-                continue
-            check_util(spec.name, util["graph"])
-            for e in util["edges"]:
-                check_util(f"{spec.name}:{e['edge']}", e)
-            util["graph"]["wall_ms"] = round(wall_ms, 3)
-            graphs[spec.name] = util
-            edges = ",".join(f"{e['edge']}({e['mode']})"
-                             for e in util["edges"])
-            print(f"telemetry/{spec.name},{wall_ms * 1e3:.0f},"
-                  f"achieved={util['graph']['achieved_gb_s']:.3f}GB/s_"
-                  f"edges={edges}")
-
-        # 3. overhead gate: the same instrumented workload (a cache-hit
-        #    plan resolution — its span fires every call — plus real
-        #    kernel work), timed interleaved with tracing off vs on. The
-        #    span layer must stay under 3%.
-        if first_op is None:
-            print(f"\nFAILED telemetry: no kernel compiled "
-                  f"({failures})", file=sys.stderr)
-            raise SystemExit(1)
-        import jax.numpy as jnp
-        spec0, fn0 = first_op
+        # 1. overhead gate: an instrumented workload (a cache-hit plan
+        #    resolution — its span fires every call — plus real kernel
+        #    work), timed interleaved with tracing off vs on. The span
+        #    layer must stay under 3%.
         kw0 = dict(spec0.bench_kwargs)
         w0, tile0 = spec0.workload(**kw0)
         dtype0 = kw0.get("dtype", jnp.float32)
@@ -929,7 +851,7 @@ def telemetry_bench(json_path: str = "BENCH_telemetry.json",
         print(f"telemetry/overhead,{wall['enabled'] * 1e3:.0f},"
               f"frac={overhead:+.4f}_{'ok' if overhead_ok else 'FAIL'}")
 
-        # 4. serve parity: the live histogram observed exactly the
+        # 2. serve parity: the live histogram observed exactly the
         #    quantities _summarize computes post hoc, so live p50/p99
         #    must agree with the bench JSON within the histogram's
         #    bucket resolution (<< the 10% gate)
@@ -964,7 +886,7 @@ def telemetry_bench(json_path: str = "BENCH_telemetry.json",
     finally:
         obs.restore(prev_obs)
 
-    # 5. trace digest: prove the JSONL sink saw the run — span counts
+    # 3. trace digest: prove the JSONL sink saw the run — span counts
     #    and the plan-source tags the acceptance bar asks for
     trace = {"path": trace_path or None, "records": 0, "spans": {},
              "plan_sources": {}}
@@ -987,9 +909,6 @@ def telemetry_bench(json_path: str = "BENCH_telemetry.json",
     if json_path:
         payload = {
             "suite": "telemetry",
-            "hw": {"roofline_gb_s": hw.hbm_bw / 1e9},
-            "kernels": kernels,
-            "graphs": graphs,
             "overhead": {
                 "disabled_ms": round(wall["disabled"], 3),
                 "enabled_ms": round(wall["enabled"], 3),
@@ -1106,13 +1025,10 @@ def main() -> None:
                         help="path for the chaos JSON report "
                              "('' disables; default %(default)s)")
     parser.add_argument("--telemetry", action="store_true",
-                        help="run the bandwidth-utilization telemetry "
-                             "suite (achieved GB/s + roofline fraction "
-                             "per kernel and per graph edge under live "
-                             "tracing) and gate the telemetry stack: "
-                             "span overhead < 3%%, serve live-vs-post-"
-                             "hoc p50/p99 within 10%%; --smoke is "
-                             "consumed, like --serve")
+                        help="gate the telemetry stack under live "
+                             "tracing: span overhead < 3%%, serve "
+                             "live-vs-post-hoc p50/p99 within 10%%; "
+                             "--smoke is consumed, like --serve")
     parser.add_argument("--telemetry-json", default="BENCH_telemetry.json",
                         help="path for the telemetry JSON report "
                              "('' disables; default %(default)s)")

@@ -305,40 +305,48 @@ def run_continuous(model, params, cfg, requests: List[Request], *,
     def active_mask():
         return np.array([r is not None for r in slot_req])
 
+    # host-side spans: serve_schedule and serve_bookkeep hold the
+    # scheduler's own work; serve_admit (admit_launch, admit_wait) and
+    # serve_decode_step (decode_inputs, decode_launch, decode_wait) split
+    # the host's dispatch from its wait on the device
     while pending or active_mask().any():
-        # admit arrived requests into free slots while blocks allow
-        while pending and pending[0].arrival <= clock:
-            free = [i for i, r in enumerate(slot_req) if r is None]
-            if not free:
-                break
-            r = pending[0]
-            need = -(-(len(r.prompt) + r.max_new) // page)
-            if need > kv.allocator.n_free:
-                break                       # wait for a retirement
-            pending.popleft()
-            slot = free[0]
-            plen = len(r.prompt)
-            pb = _bucket(plen)
-            toks = np.zeros((1, pb), np.int32)
-            toks[0, :plen] = r.prompt
-            t0 = time.perf_counter()
-            with obs.span("serve_admit", scheduler="paged", rid=r.rid,
-                          slot=slot, prompt_len=plen):
-                _, pc = prefill(params, {"tokens": jnp.asarray(toks)})
-                kv.admit(slot, pc["k"][:, 0], pc["v"][:, 0], plen,
-                         plen + r.max_new)
-                jax.block_until_ready(kv.pool)
-            dt = time.perf_counter() - t0
-            clock += dt
-            prefill_s += dt
-            slot_req[slot] = r
-            cur[slot] = int(r.prompt[-1])
-            produced[slot] = 0
-            # first decode step re-feeds the last prompt token at
-            # position plen-1 (idempotent cache write, exact logits)
-            kv.lengths[slot] = plen - 1
+        with obs.span("serve_schedule"):
+            # admit arrived requests into free slots while blocks allow
+            while pending and pending[0].arrival <= clock:
+                free = [i for i, r in enumerate(slot_req) if r is None]
+                if not free:
+                    break
+                r = pending[0]
+                need = -(-(len(r.prompt) + r.max_new) // page)
+                if need > kv.allocator.n_free:
+                    break                       # wait for a retirement
+                pending.popleft()
+                slot = free[0]
+                plen = len(r.prompt)
+                pb = _bucket(plen)
+                toks = np.zeros((1, pb), np.int32)
+                toks[0, :plen] = r.prompt
+                t0 = time.perf_counter()
+                with obs.span("serve_admit", scheduler="paged", rid=r.rid,
+                              slot=slot, prompt_len=plen):
+                    with obs.span("admit_launch"):
+                        _, pc = prefill(params,
+                                        {"tokens": jnp.asarray(toks)})
+                        kv.admit(slot, pc["k"][:, 0], pc["v"][:, 0], plen,
+                                 plen + r.max_new)
+                    with obs.span("admit_wait"):
+                        jax.block_until_ready(kv.pool)
+                dt = time.perf_counter() - t0
+                clock += dt
+                prefill_s += dt
+                slot_req[slot] = r
+                cur[slot] = int(r.prompt[-1])
+                produced[slot] = 0
+                # first decode step re-feeds the last prompt token at
+                # position plen-1 (idempotent cache write, exact logits)
+                kv.lengths[slot] = plen - 1
+            act = active_mask()
 
-        act = active_mask()
         if not act.any():
             if pending:
                 clock = max(clock, pending[0].arrival)
@@ -346,38 +354,47 @@ def run_continuous(model, params, cfg, requests: List[Request], *,
             break
 
         t0 = time.perf_counter()
-        with obs.span("serve_decode_step", scheduler="paged"):
-            nxt, _, new_caches = decode(
-                params, {"token": jnp.asarray(cur),
-                         "lengths": jnp.asarray(kv.lengths)},
-                kv.cache_view())
-            nxt_np = np.asarray(nxt)
+        with obs.span("serve_decode_step", scheduler="paged") as step:
+            with obs.span("decode_inputs"):
+                batch = {"token": jnp.asarray(cur),
+                         "lengths": jnp.asarray(kv.lengths)}
+                caches = kv.cache_view()
+            with obs.span("decode_launch"):
+                nxt, _, new_caches = decode(params, batch, caches)
+            # let the old pool go once update_pool replaces it, as it did
+            # when the inputs were the call's temporaries
+            del batch, caches
+            with obs.span("decode_wait"):
+                nxt_np = np.asarray(nxt)
+            if telemetry:
+                step.set(**obs.counters())
         dt = time.perf_counter() - t0
         clock += dt
         decode_s += dt
         steps += 1
-        kv.update_pool(new_caches["kv_pool"])
-        kv.append(act.astype(np.int32))
-        for slot in np.nonzero(act)[0]:
-            r = slot_req[slot]
-            tok = int(nxt_np[slot])
-            emits.setdefault(r.rid, []).append(clock)
+        with obs.span("serve_bookkeep"):
+            kv.update_pool(new_caches["kv_pool"])
+            kv.append(act.astype(np.int32))
+            for slot in np.nonzero(act)[0]:
+                r = slot_req[slot]
+                tok = int(nxt_np[slot])
+                emits.setdefault(r.rid, []).append(clock)
+                if telemetry:
+                    hist.observe(clock - prev_emit.get(r.rid, r.arrival))
+                    prev_emit[r.rid] = clock
+                produced[slot] += 1
+                if tok == eos_id or produced[slot] >= r.max_new:
+                    with obs.span("serve_retire", scheduler="paged",
+                                  rid=r.rid, slot=int(slot)):
+                        kv.retire(slot)     # blocks recycle immediately
+                    slot_req[slot] = None
+                else:
+                    cur[slot] = tok
+            u = kv.utilization()
+            utils.append(u["util_vs_allocated"])
+            utils_pool.append(u["util_vs_pool"])
             if telemetry:
-                hist.observe(clock - prev_emit.get(r.rid, r.arrival))
-                prev_emit[r.rid] = clock
-            produced[slot] += 1
-            if tok == eos_id or produced[slot] >= r.max_new:
-                with obs.span("serve_retire", scheduler="paged",
-                              rid=r.rid, slot=int(slot)):
-                    kv.retire(slot)         # blocks recycle immediately
-                slot_req[slot] = None
-            else:
-                cur[slot] = tok
-        u = kv.utilization()
-        utils.append(u["util_vs_allocated"])
-        utils_pool.append(u["util_vs_pool"])
-        if telemetry:
-            kv_gauge.set(u["util_vs_allocated"])
+                kv_gauge.set(u["util_vs_allocated"])
     out = _summarize(emits, requests, utils, prefill_s, decode_s, steps)
     out["kv_util_pool"] = (float(np.mean(utils_pool))
                            if utils_pool else None)

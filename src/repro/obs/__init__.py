@@ -1,16 +1,18 @@
-"""Unified telemetry: tracing spans, live metrics, bandwidth accounting.
+"""Unified telemetry: tracing spans, process counters, live metrics.
 
 Three layers, one import (``from repro import obs``):
 
 * :mod:`repro.obs.tracing` — ``obs.span("compile_graph", ...)`` context
   managers with thread-local nesting and a JSONL sink
-  (``REPRO_TRACE=/path`` or ``tuning_config(trace_path=...)``);
+  (``REPRO_TRACE=/path`` or ``tuning_config(trace_path=...)``), mirrored
+  onto the profiler's host plane as ``TraceAnnotation`` events while
+  a profile records;
+* :mod:`repro.obs.recorders` — GC pauses and JAX compilations, counted
+  while tracing is enabled (``obs.counters()``; compilations also as
+  ``jax_compile`` spans);
 * :mod:`repro.obs.metrics` — process-global counters / gauges /
   exponential-bucket histograms behind ``obs.metrics_snapshot()`` and a
-  Prometheus-style ``obs.render_text()`` exporter;
-* :mod:`repro.obs.bandwidth` — achieved-GB/s and roofline-utilization
-  joins of modeled bytes with measured wall time, per kernel and per
-  graph edge (``benchmarks/run.py --telemetry``).
+  Prometheus-style ``obs.render_text()`` exporter.
 
 stdlib-only on purpose: ``repro.core`` imports ``repro.obs``, never the
 reverse, so instrumentation can sit in the lowest layers. Everything is
@@ -27,10 +29,12 @@ from repro.obs.tracing import (   # noqa: F401
     drain,
     enable,
     enabled,
+    record,
     restore,
     span,
     trace_path,
 )
+from repro.obs.recorders import counters, gc_by_generation   # noqa: F401
 from repro.obs.metrics import (   # noqa: F401
     Counter,
     Gauge,
@@ -43,15 +47,11 @@ from repro.obs.metrics import (   # noqa: F401
     parse_text,
     render_text,
 )
-from repro.obs.bandwidth import (   # noqa: F401
-    graph_utilization,
-    kernel_utilization,
-)
 
 __all__ = [
     "NOOP_SPAN", "Span", "TRACE_ENV", "current_span", "disable", "drain",
-    "enable", "enabled", "restore", "span", "trace_path",
+    "enable", "enabled", "record", "restore", "span", "trace_path",
+    "counters", "gc_by_generation",
     "Counter", "Gauge", "Histogram", "counter", "gauge", "histogram",
     "metrics_clear", "metrics_snapshot", "parse_text", "render_text",
-    "graph_utilization", "kernel_utilization",
 ]

@@ -15,6 +15,14 @@ close to the configured sink:
 * :func:`enable` with no path keeps records in a bounded in-memory ring
   (:func:`drain` reads and clears it — the test/bench hook).
 
+While enabled, and where JAX was imported before :func:`enable`, each
+span also opens a ``jax.profiler.TraceAnnotation`` of its name and initial
+attributes, so a profile taken meanwhile shows every program span on its
+host plane, on the same time base as the device's operations. The module
+itself imports only the standard library. :func:`enable` also installs the
+process-wide GC and compilation recorders of :mod:`repro.obs.recorders`;
+:func:`disable` removes them.
+
 Record schema (one JSON object per line)::
 
     {"name": "resolve_call", "id": 7, "parent": 3, "ts": <epoch s>,
@@ -39,6 +47,7 @@ import collections
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 import warnings
@@ -46,7 +55,10 @@ from typing import Any, Dict, List, Optional, Tuple
 
 TRACE_ENV = "REPRO_TRACE"
 
-_BUFFER_MAX = 16384     # in-memory ring bound (records), no-path mode
+# in-memory ring bound (records), no-path mode: room for a server's
+# set-up, whose compilations emit thousands of jax_compile spans before
+# its first drain
+_BUFFER_MAX = 65536
 _FLUSH_EVERY = 64       # pending records that wake the writer early
 _WRITER_POLL_S = 0.5    # writer wakes at least this often for small tails
 
@@ -56,6 +68,8 @@ _file = None
 _lock = threading.Lock()
 _ids = itertools.count(1)
 _buffer: "collections.deque[dict]" = collections.deque(maxlen=_BUFFER_MAX)
+# jax.profiler.TraceAnnotation while enabled with JAX loaded, else None
+_annotation = None
 
 
 class _Local(threading.local):
@@ -80,12 +94,17 @@ def enable(path: Optional[str] = None) -> Tuple[bool, Optional[str]]:
     """Turn tracing on. ``path`` appends JSONL records there; ``None``
     collects into the in-memory ring (:func:`drain`). Returns the previous
     ``(enabled, path)`` state for :func:`restore`."""
-    global _enabled, _path
+    global _enabled, _path, _annotation
     prev = (_enabled, _path)
     if path != _path:
         _shutdown_writer()           # drain + close the old sink first
     with _lock:
         _path = path
+    if "jax" in sys.modules:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    from repro.obs import recorders
+    recorders.install(record)
     _enabled = True
     return prev
 
@@ -93,9 +112,12 @@ def enable(path: Optional[str] = None) -> Tuple[bool, Optional[str]]:
 def disable() -> Tuple[bool, Optional[str]]:
     """Turn tracing off, drain pending records, and close the sink.
     Returns the previous state."""
-    global _enabled, _path
+    global _enabled, _path, _annotation
     prev = (_enabled, _path)
     _enabled = False
+    _annotation = None
+    from repro.obs import recorders
+    recorders.remove()
     _shutdown_writer()
     with _lock:
         _path = None
@@ -265,7 +287,7 @@ class Span:
     resolution). Closing under an exception records ``status="error"`` and
     the exception type, then re-raises (``__exit__`` returns False)."""
 
-    __slots__ = ("name", "attrs", "id", "parent", "t0")
+    __slots__ = ("name", "attrs", "id", "parent", "t0", "ann")
 
     def __init__(self, name: str, attrs: Dict[str, Any]):
         self.name = name
@@ -273,6 +295,7 @@ class Span:
         self.id = next(_ids)
         self.parent: Optional[int] = None
         self.t0 = 0.0
+        self.ann = None
 
     def set(self, **attrs) -> "Span":
         self.attrs.update(attrs)
@@ -282,11 +305,17 @@ class Span:
         stack = _local.stack
         self.parent = stack[-1].id if stack else None
         stack.append(self)
+        ann = _annotation
+        if ann is not None and ann.is_enabled():   # a profile is recording
+            self.ann = ann(self.name, **self.attrs)
+            self.ann.__enter__()
         self.t0 = time.monotonic()
         return self
 
     def __exit__(self, etype, exc, tb) -> bool:
         dur = time.monotonic() - self.t0
+        if self.ann is not None:
+            self.ann.__exit__(etype, exc, tb)
         stack = _local.stack
         # unwind any child frames a non-context-manager misuse left open,
         # so one leak cannot mis-parent every later span on this thread
@@ -308,6 +337,24 @@ class Span:
             rec["attrs"] = self.attrs
         _emit(rec)
         return False
+
+
+def record(name: str, start: float, end: float, **attrs) -> None:
+    """Emit a span that has already closed, from ``start`` to ``end``
+    (wall-clock seconds), as a child of this thread's innermost open span
+    (a compilation phase timed by JAX, say). No-op when disabled."""
+    if not _enabled:
+        return
+    stack = _local.stack
+    rec: Dict[str, Any] = {
+        "name": name, "id": next(_ids),
+        "parent": stack[-1].id if stack else None,
+        "ts": end, "dur_s": end - start,
+        "thread": threading.get_ident(), "status": "ok",
+    }
+    if attrs:
+        rec["attrs"] = attrs
+    _emit(rec)
 
 
 def span(name: str, **attrs):

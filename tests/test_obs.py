@@ -1,16 +1,16 @@
-"""Observability stack: tracing spans, metrics registry, bandwidth
-accounting, and the live-telemetry wiring through autotune and serve."""
+"""Observability stack: tracing spans, their profiler bridge, the GC and
+compile recorders, the metrics registry, and the live-telemetry wiring
+through autotune and serve."""
 
 import json
 import os
 import time
-import types
 import warnings
 
 import pytest
 
 from repro import obs
-from repro.core import TPU_V5E, Workload, autotune
+from repro.core import autotune
 
 
 @pytest.fixture
@@ -169,93 +169,166 @@ def test_histogram_quantiles_track_percentiles():
 
 
 # ---------------------------------------------------------------------------
-# Bandwidth accounting
+# Profiler bridge and process counters (GC, compilations)
 # ---------------------------------------------------------------------------
 
-W = Workload(n_words=1024, word_bytes=65536.0, flops_per_word=1e5,
-             store_bytes_per_word=4096.0)
+def _host_events(trace_dir, names):
+    import glob
+
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                out += [(e.start_ns, e.start_ns + e.duration_ns, e.name)
+                        for e in line.events if e.name in names]
+    return sorted(out)
 
 
-def test_kernel_utilization_in_unit_interval():
-    total_bytes = 1024 * (65536.0 + 4096.0)
-    # measured exactly at the roofline -> utilization 1.0
-    at_roof = obs.kernel_utilization(W, TPU_V5E,
-                                     total_bytes / TPU_V5E.hbm_bw)
-    assert at_roof["utilization"] == pytest.approx(1.0)
-    # 10x slower than the roofline -> 0.1
-    slow = obs.kernel_utilization(W, TPU_V5E,
-                                  10 * total_bytes / TPU_V5E.hbm_bw)
-    assert slow["utilization"] == pytest.approx(0.1)
-    assert slow["hbm_bytes"] == total_bytes
-    assert slow["achieved_gb_s"] == pytest.approx(
-        TPU_V5E.hbm_bw / 10 / 1e9)
-    assert 0.0 < slow["utilization"] <= 1.0
-    # a byte model claiming more than the roofline clamps, keeps the raw
-    fast = obs.kernel_utilization(W, TPU_V5E,
-                                  0.5 * total_bytes / TPU_V5E.hbm_bw)
-    assert fast["utilization"] == 1.0
-    assert fast["utilization_raw"] == pytest.approx(2.0)
+def test_spans_land_on_the_profiler_host_plane(obs_memory, tmp_path):
+    import jax
+    import jax.numpy as jnp
+
+    # the bridge binds at enable() once JAX is loaded
+    obs.restore(obs.enable())
+    x = jnp.ones((8, 8))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.span("outer_span", k=1):
+            with obs.span("inner_a"):
+                jax.block_until_ready(x @ x)
+            with obs.span("inner_b"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    ev = _host_events(str(tmp_path), {"outer_span", "inner_a", "inner_b"})
+    assert [e[2] for e in ev] == ["outer_span", "inner_a", "inner_b"]
+    (o0, o1, _), (a0, a1, _), (b0, b1, _) = ev
+    assert o0 <= a0 <= a1 <= b0 <= b1 <= o1     # nested, in order
+    # the ring record keeps its schema
+    recs = {r["name"]: r for r in obs.drain()}
+    assert set(recs["outer_span"]) == {"name", "id", "parent", "ts",
+                                       "dur_s", "thread", "status",
+                                       "attrs"}
+    assert recs["inner_a"]["parent"] == recs["outer_span"]["id"]
 
 
-def _stage(bw, total_s):
-    return types.SimpleNamespace(achieved_bw=bw, total_s=total_s)
+def test_fresh_jit_yields_compile_spans_until_disabled():
+    import jax
+
+    def triple_plus_one(x):
+        return x * 3 + 1
+
+    def quadruple(x):
+        return x * 4
+
+    prev = obs.enable()
+    try:
+        obs.drain()
+        before = obs.counters()["compiles"]
+        with obs.span("outer") as outer:
+            jax.block_until_ready(jax.jit(triple_plus_one)(1.0))
+        recs = [r for r in obs.drain() if r["name"] == "jax_compile"]
+    finally:
+        obs.disable()
+    mine = [r for r in recs if "triple_plus_one" in r["attrs"]["fun"]]
+    assert {r["attrs"]["phase"] for r in mine} >= {"trace", "lower",
+                                                   "compile"}
+    assert all(r["parent"] == outer.id and r["dur_s"] >= 0 for r in mine)
+    assert obs.counters()["compiles"] - before == sum(
+        r["attrs"]["phase"] != "cache_load" for r in recs)
+    try:
+        jax.block_until_ready(jax.jit(quadruple)(1.0))
+        after = obs.counters()["compiles"]
+        assert obs.drain() == []
+        assert after == obs.counters()["compiles"]
+    finally:
+        obs.restore(prev)
 
 
-def _edge(label, mode):
-    return types.SimpleNamespace(edge=label, mode=mode,
-                                 hbm_bytes_saved=111, rationale="test")
+def test_gc_inside_spans_counts_without_deadlock():
+    import gc
+    import threading
+
+    old = gc.get_threshold()
+    before = obs.counters()
+    prev = obs.enable()
+
+    def work():
+        with obs.span("holder"):
+            for i in range(20_000):
+                with obs.span("churn", i=i):
+                    [{} for _ in range(3)]
+            gc.collect()
+        obs.drain()
+
+    try:
+        gc.set_threshold(1)          # a collection on nearly every alloc
+        t = threading.Thread(target=work, daemon=True)
+        t.start()
+        t.join(timeout=120)
+        alive = t.is_alive()
+    finally:
+        gc.set_threshold(*old)
+        obs.restore(prev)
+    assert not alive, "a GC pause inside a span deadlocked the tracer"
+    after = obs.counters()
+    assert after["gc_n"] > before["gc_n"]
+    assert after["gc_s"] > before["gc_s"]
+    n, s = zip(*obs.gc_by_generation())
+    assert sum(n) == after["gc_n"] and n[2] >= 1
 
 
-def test_graph_utilization_attributes_wall_by_model_share():
-    est = types.SimpleNamespace(
-        total_s=3e-3,
-        hbm_bytes_saved=111,
-        per_stage=[("a", _stage(100e9, 1e-3)), ("b", _stage(100e9, 2e-3))],
-        edges=[_edge("a->b", "fused")],
-    )
-    rep = obs.graph_utilization(est, TPU_V5E, measured_s=6e-3)
-    # measured wall split 1:2 by modeled share
-    assert rep["stages"]["a"]["attributed_s"] == pytest.approx(2e-3)
-    assert rep["stages"]["b"]["attributed_s"] == pytest.approx(4e-3)
-    # bytes recovered from modeled bw * modeled time
-    assert rep["stages"]["a"]["hbm_bytes"] == pytest.approx(100e9 * 1e-3)
-    (edge,) = rep["edges"]
-    assert edge["edge"] == "a->b" and edge["mode"] == "fused"
-    assert edge["hbm_bytes"] == pytest.approx(100e9 * 3e-3)
-    assert edge["attributed_s"] == pytest.approx(6e-3)
-    assert edge["hbm_bytes_saved"] == 111
-    # 2x slower than modeled -> utilization = modeled_bw/2 / roofline
-    want = (100e9 / 2) / TPU_V5E.hbm_bw
-    assert edge["utilization"] == pytest.approx(want)
-    assert 0.0 < edge["utilization"] <= 1.0
-    assert rep["graph"]["measured_s"] == 6e-3
-    assert rep["graph"]["modeled_s"] == 3e-3
+def test_disabled_installs_no_recorder(obs_off):
+    import gc
+
+    import jax  # noqa: F401 — the bridge binds only when JAX is loaded
+    from jax._src import monitoring    # the listener lists themselves
+
+    from repro.obs import recorders, tracing
+
+    def installed():
+        return (recorders._on_gc in gc.callbacks,
+                recorders._on_time_span
+                in monitoring.get_event_time_span_listeners(),
+                recorders._on_duration
+                in monitoring.get_event_duration_listeners(),
+                tracing._annotation is not None)
+
+    assert installed() == (False,) * 4
+    assert obs.span("x") is obs.NOOP_SPAN
+    prev = obs.enable()
+    assert installed() == (True,) * 4
+    obs.restore(prev)
+    assert installed() == (False,) * 4
 
 
-def test_graph_utilization_multi_consumer_edge_not_double_counted():
-    """A producer feeding two edges (decode_layer's oproj -> gateup and
-    oproj -> down residual) must contribute its bytes/wall once across the
-    graph: edge rows split the shared stage so their sum equals the total."""
-    est = types.SimpleNamespace(
-        total_s=4e-3,
-        hbm_bytes_saved=222,
-        per_stage=[("p", _stage(100e9, 2e-3)),
-                   ("c1", _stage(100e9, 1e-3)),
-                   ("c2", _stage(100e9, 1e-3))],
-        edges=[_edge("p->c1", "fused"), _edge("p->c2", "fused")],
-    )
-    rep = obs.graph_utilization(est, TPU_V5E, measured_s=4e-3)
-    total_bytes = 100e9 * 4e-3
-    assert rep["graph"]["hbm_bytes"] == pytest.approx(total_bytes)
-    edge_bytes = sum(e["hbm_bytes"] for e in rep["edges"])
-    edge_s = sum(e["attributed_s"] for e in rep["edges"])
-    # shared producer p split across its two edges, not counted twice
-    assert edge_bytes == pytest.approx(total_bytes)
-    assert edge_s == pytest.approx(4e-3)
-    for e in rep["edges"]:
-        # each edge: half of p (1e-3 worth) + its own consumer (1e-3 worth)
-        assert e["hbm_bytes"] == pytest.approx(100e9 * 2e-3)
-        assert e["attributed_s"] == pytest.approx(2e-3)
+def test_record_emits_a_closed_span_under_the_open_one(obs_memory):
+    with obs.span("parent") as p:
+        obs.record("after_the_fact", 10.0, 10.5, phase="x")
+    recs = {r["name"]: r for r in obs.drain()}
+    r = recs["after_the_fact"]
+    assert r["parent"] == p.id and r["ts"] == 10.5 and r["dur_s"] == 0.5
+    # ids follow the order in which spans opened
+    assert r["attrs"] == {"phase": "x"} and r["id"] > recs["parent"]["id"]
+
+
+def test_obs_imports_only_the_standard_library():
+    import subprocess
+    import sys
+    code = ("import sys; from repro import obs; obs.enable(); "
+            "obs.span('a').__enter__(); "
+            "print(sorted(m for m in ('jax', 'numpy', 'jaxlib') "
+            "if m in sys.modules))")
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    env = dict(os.environ, PYTHONPATH=src)
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "[]"
 
 
 # ---------------------------------------------------------------------------

@@ -339,6 +339,62 @@ def test_serve_schedulers_token_parity():
     assert ls["tokens"] == pg["tokens"] == 12
 
 
+def test_continuous_spans_split_each_step():
+    """With tracing on, every ``serve_decode_step`` holds exactly one
+    ``decode_inputs``, ``decode_launch`` and ``decode_wait`` (in that
+    order, inside its interval) and carries the GC and compile totals;
+    every ``serve_admit`` holds one ``admit_launch`` and ``admit_wait``
+    and keeps its ``prompt_len``; the step and admission spans stay one
+    per step and per request, siblings of the scheduler's own spans."""
+    from repro import obs
+    model, cfg, params, policy = _xla_setup()
+    reqs = _mk_requests([3, 5, 2], [6, 9, 4], cfg.vocab)
+    prev = obs.enable()
+    obs.drain()
+    try:
+        m = serve_lib.run_continuous(model, params, cfg, reqs, n_slots=2,
+                                     page=PAGE, eos_id=None, policy=policy)
+        recs = obs.drain()
+    finally:
+        obs.restore(prev)
+    by_id = {r["id"]: r for r in recs}
+    kids = {}
+    for r in recs:
+        kids.setdefault(r["parent"], []).append(r)
+
+    def span_of(r):
+        return r["ts"] - r["dur_s"], r["ts"]
+
+    steps = [r for r in recs if r["name"] == "serve_decode_step"]
+    admits = [r for r in recs if r["name"] == "serve_admit"]
+    assert len(steps) == m["decode_steps"]
+    assert sorted(r["attrs"]["prompt_len"] for r in admits) == [4, 6, 9]
+    for parent, names in ((steps, ["decode_inputs", "decode_launch",
+                                   "decode_wait"]),
+                          (admits, ["admit_launch", "admit_wait"])):
+        for p in parent:
+            mine = sorted((c for c in kids.get(p["id"], [])
+                           if c["name"] != "jax_compile"),
+                          key=lambda c: c["id"])
+            assert [c["name"] for c in mine] == names
+            a, b = span_of(p)
+            for c in mine:
+                ca, cb = span_of(c)
+                assert a - 1e-3 <= ca <= cb <= b + 1e-3
+    for p in admits:
+        assert by_id[p["parent"]]["name"] == "serve_schedule"
+    for p in steps:
+        assert p["parent"] is None
+        assert {"gc_s", "gc_n", "compiles"} <= set(p["attrs"])
+    totals = [p["attrs"]["compiles"] for p in steps]
+    assert totals == sorted(totals)
+    for name in ("decode_inputs", "decode_launch", "decode_wait"):
+        assert sum(r["name"] == name for r in recs) == len(steps)
+    assert sum(r["name"] == "serve_bookkeep" for r in recs) == len(steps)
+    assert all(by_id[r["parent"]]["name"] == "serve_bookkeep"
+               for r in recs if r["name"] == "serve_retire")
+
+
 # ---------------------------------------------------------------------------
 # pad_cache_to (satellite)
 # ---------------------------------------------------------------------------
