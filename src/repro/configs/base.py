@@ -75,9 +75,10 @@ class ArchConfig:
     decode_block_kv: Optional[int] = None     # pin the ff decode-attention
                                               # KV tile (None = heuristic);
                                               # serving pins it to the paged
-                                              # cache's page size so the
-                                              # contiguous path is bitwise-
-                                              # equal to the paged path
+                                              # graph's grid word of pages
+                                              # so the contiguous path is
+                                              # bitwise-equal to the paged
+                                              # path
     layer_graph: bool = False                 # route dense-cache decode steps
                                               # through the whole-layer
                                               # decode_layer StreamGraph (one

@@ -145,8 +145,8 @@ def build_program(b: int, kvh: int, g_pad: int, s: int, d: int, *,
     )
 
 
-def build_paged_program(b: int, kvh: int, g_pad: int, n_pages: int,
-                        page: int, d: int, *, dtype=jnp.float32,
+def build_paged_program(b: int, kvh: int, g_pad: int, n_words: int,
+                        ppw: int, page: int, d: int, *, dtype=jnp.float32,
                         kv_dtype=None, out_dtype=None,
                         depth: int = 2, streams: int = 1,
                         scale: Optional[float] = None,
@@ -154,26 +154,30 @@ def build_paged_program(b: int, kvh: int, g_pad: int, n_pages: int,
     """Paged-KV decode attention: the consumer half of the
     ``paged_decode_attention`` StreamGraph.
 
-    The KV operand is the *gathered* row stream ``[B*KVH*n_pages*2*page, d]``
-    produced by an ``ff_gather`` node walking the block table — each word is
-    one page's K rows followed by its V rows (a merged ``(2*page, d)`` tile),
-    so the producer's 8-row DMA bundles line up word-for-word with this
-    stream and the edge fuses into a single ``pallas_call``. The online
-    softmax is *identical* to :func:`build_program` at ``block_kv == page``
-    (same tile order, same f32 accumulation), which is what makes the paged
-    path bitwise-equal to the contiguous cache path.
+    The KV operand is the *gathered* page stream ``[B*KVH*n_words*ppw,
+    2*page, d]`` produced by an ``ff_gather`` node walking the block table
+    — each page is its K rows followed by its V rows, and each word is
+    ``ppw`` consecutive pages of one (row, lane word) (a ``(ppw, 2*page,
+    d)`` tile), so the producer's ``ppw``-page DMA bundles line up
+    word-for-word with this stream and the edge fuses into a single
+    ``pallas_call``. A word's K pages form one ``(ppw*page, d)`` block:
+    one score matmul and one PV matmul per word. The online softmax is
+    *identical* to :func:`build_program` at ``block_kv == ppw*page`` (same
+    tile order, same f32 accumulation), which is what makes the paged path
+    bitwise-equal to the contiguous cache path.
     """
     scale = 1.0 / (d ** 0.5) if scale is None else scale
     out_dtype = out_dtype or dtype
-    kv_spec = Pipe(tile=(2 * page, d), dtype=kv_dtype or dtype, depth=depth,
-                   streams=streams)
+    block_kv = ppw * page
+    kv_spec = Pipe(tile=(ppw, 2 * page, d), dtype=kv_dtype or dtype,
+                   depth=depth, streams=streams)
 
     def kv_slicer(ctx, word):
-        return ctx.ref("kv").at[pl.ds(word * 2 * page, 2 * page), :]
+        return ctx.ref("kv").at[pl.ds(word * ppw, ppw)]
 
     def consumer(ctx):
-        kj = ctx.g % n_pages
-        b_idx = (ctx.g // n_pages) // kvh
+        kj = ctx.g % n_words
+        b_idx = (ctx.g // n_words) // kvh
         length = ctx.ref("lengths")[b_idx]
         m_sc, l_sc = ctx.scratch("m"), ctx.scratch("l")
         acc = ctx.scratch("acc")
@@ -184,22 +188,24 @@ def build_paged_program(b: int, kvh: int, g_pad: int, n_pages: int,
             l_sc[...] = jnp.zeros_like(l_sc)
             acc[...] = jnp.zeros_like(acc)
 
-        kv_start = kj * page
+        kv_start = kj * block_kv
 
+        # a word wholly past the row's length does no compute
         @pl.when(kv_start < length)
         def _():
             q = ctx.ref("q")[0, 0]                     # [g_pad, d]
-            kv = ctx.word("kv")[...]                   # [2*page, d]
-            k = kv[:page]
-            v = kv[page:]
+            kv = ctx.word("kv")                        # [ppw, 2*page, d]
+            k = kv[:, :page, :].reshape(block_kv, d)
+            v = kv[:, page:, :].reshape(block_kv, d)
             s_ = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale   # [g_pad, page]
+                preferred_element_type=jnp.float32) * scale  # [g_pad, bkv]
             cols = kv_start + jax.lax.broadcasted_iota(
-                jnp.int32, (g_pad, page), 1)
-            # rows past `length` (zero padding or stale recycled-block
-            # contents) mask to -inf, so their exp underflows to exactly
-            # 0.0 — recycled garbage cannot perturb even the last bit
+                jnp.int32, (g_pad, block_kv), 1)
+            # rows past `length` (zero padding, stale recycled-block
+            # contents or the sentinel pages that pad a row to whole
+            # words) mask to -inf, so their exp underflows to exactly
+            # 0.0 — garbage cannot perturb even the last bit
             s_ = jnp.where(cols < length, s_, _NEG_INF)
             m_prev = m_sc[:, :1]
             m_new = jnp.maximum(m_prev, jnp.max(s_, axis=1, keepdims=True))
@@ -212,22 +218,23 @@ def build_paged_program(b: int, kvh: int, g_pad: int, n_pages: int,
                 p.astype(v.dtype), v, preferred_element_type=jnp.float32)
             m_sc[...] = jnp.broadcast_to(m_new, m_sc.shape)
 
-        @pl.when(kj == n_pages - 1)
+        @pl.when(kj == n_words - 1)
         def _():
             _finalize(ctx, acc, l_sc, lane_heads, out_dtype)
 
-    q_index_map = lambda g, lens: ((g // n_pages) // kvh,
-                                   (g // n_pages) % kvh, 0, 0)
+    q_index_map = lambda g, lens: ((g // n_words) // kvh,
+                                   (g // n_words) % kvh, 0, 0)
     return StreamProgram(
         name="ff_paged_decode_attention",
-        n_words=b * kvh * n_pages,
+        n_words=b * kvh * n_words,
         inputs=(
             ScalarIn("lengths"),
             BlockIn("q", (1, 1, g_pad, d), q_index_map, dtype=dtype),
-            # word w reads row block w of the gathered [n_words*2*page, d]
-            # stream — the identity schedule an ff_gather producer writes,
-            # so check_fusion legalizes the edge with wpb=1
-            Stream("kv", kv_spec, kv_slicer, index=lambda w: (w, 0)),
+            # word w reads page block w of the gathered
+            # [n_words*ppw, 2*page, d] stream — the identity schedule an
+            # ff_gather producer writes, so check_fusion legalizes the
+            # edge with wpb=1
+            Stream("kv", kv_spec, kv_slicer, index=lambda w: (w, 0, 0)),
         ),
         consumer=consumer,
         out_shape=(b, kvh, g_pad, d),

@@ -50,22 +50,24 @@ def decode_attention_workload(b: int, h: int, kvh: int, s: int, d: int,
 
 
 def paged_decode_attention_workload(b: int, h: int, kvh: int, n_pages: int,
-                                    page: int, d: int, *, dtype=jnp.bfloat16
+                                    page: int, d: int, *, ppw: int = 1,
+                                    dtype=jnp.bfloat16
                                     ) -> Tuple[Workload, Tuple[int, int]]:
-    """One word per (b, lane word, page): a merged K+V page tile of ``p``
-    lane-packed heads gathered through the block table. Same math as
-    :func:`decode_attention_workload` at ``block_kv == page``, but the
-    stream arrives via an irregular gather."""
+    """One word per (b, lane word, group of ``ppw`` pages): ``ppw`` merged
+    K+V page tiles of ``p`` lane-packed heads gathered through the block
+    table, each row padded to ``ceil(n_pages / ppw)`` words. Same math as
+    :func:`decode_attention_workload` at ``block_kv == ppw * page``, but
+    the stream arrives via an irregular gather."""
     itemsize = jnp.dtype(dtype).itemsize
     group = max(h // kvh, 1)
     p = lane_pack(kvh, d)
     w = Workload(
-        n_words=b * (kvh // p) * n_pages,
-        word_bytes=float(2 * page * p * d * itemsize),
-        flops_per_word=4.0 * p * group * page * d,
+        n_words=b * (kvh // p) * cdiv(n_pages, ppw),
+        word_bytes=float(2 * ppw * page * p * d * itemsize),
+        flops_per_word=4.0 * p * group * ppw * page * d,
         regular=True,
     )
-    return w, (2 * page, p * d)
+    return w, (2 * ppw * page, p * d)
 
 
 # KV-cache tile candidates for mode="autotune" (the cache stream's word

@@ -22,9 +22,10 @@ Two schedulers over the same Poisson request trace:
 Both replay the same trace on a virtual clock advanced by measured step
 wall-times (discrete-event replay: no sleeping, real compute costs), and
 both decode greedily with identical math — with ``--impl ff`` the dense
-path's KV tile is pinned to the page size (``cfg.decode_block_kv``), so
-paged decode is *bitwise-identical* to the contiguous path and the two
-schedulers emit token-for-token equal sequences.
+path's KV tile is pinned to the paged graph's grid word
+(``cfg.decode_block_kv``, :func:`paged_block_kv`), so paged decode is
+*bitwise-identical* to the contiguous path and the two schedulers emit
+token-for-token equal sequences.
 
 Example (CPU smoke):
   PYTHONPATH=src python -m repro.launch.serve --arch qwen1_5_0p5b --smoke \
@@ -49,7 +50,8 @@ from repro.launch import steps as steps_lib
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.runtime import sharding as shlib
-from repro.runtime.paged_kv import OutOfBlocks, PagedKVCache
+from repro.runtime.paged_kv import OutOfBlocks, PagedKVCache, \
+    pages_per_word, pool_lanes
 
 
 def pad_cache_to(cache, s_from: int, s_max: int, seq_dims):
@@ -256,8 +258,7 @@ def run_continuous(model, params, cfg, requests: List[Request], *,
     into free slots, retire per step, recycle blocks."""
     prefill = jax.jit(steps_lib.make_prefill_step(model, policy=policy))
     decode = jax.jit(steps_lib.make_decode_step(model, policy=policy))
-    n_pages_max = max(-(-(len(r.prompt) + r.max_new) // page)
-                      for r in requests)
+    n_pages_max = _pages_max(requests, page)
     if pool_blocks is None:
         pool_blocks = n_slots * n_pages_max
     # a single empty-pool admission must always fit, else admission stalls
@@ -403,6 +404,20 @@ def run_continuous(model, params, cfg, requests: List[Request], *,
     return out
 
 
+def _pages_max(requests: List[Request], page: int) -> int:
+    """Pages of the largest reservation: the paged pool's table width."""
+    return max(-(-(len(r.prompt) + r.max_new) // page) for r in requests)
+
+
+def paged_block_kv(cfg, n_pages: int, page: int) -> int:
+    """The dense ff decode tile that walks the KV in the paged graph's
+    grid words at ``n_pages`` pages per row (``ppw * page`` tokens,
+    :func:`~repro.runtime.paged_kv.pages_per_word`): same tile order,
+    so the two paths are bitwise-equal."""
+    lanes = pool_lanes(cfg.n_kv_heads, cfg.hd)[1]
+    return pages_per_word(n_pages, page, lanes, cfg.cdtype) * page
+
+
 # ---------------------------------------------------------------------------
 # Bitwise parity probe (paged vs. contiguous decode on identical state)
 # ---------------------------------------------------------------------------
@@ -414,9 +429,10 @@ def decode_parity_probe(model, params, cfg, policy, *, page: int,
     through (a) the dense right-padded cache and (b) the paged pool, and
     return the max abs logits difference (0.0 = bitwise identical).
 
-    Requires the model's dense ff path to be pinned to the page tile
-    (``cfg.decode_block_kv == page``) for ff impls; xla impls match because
-    both views present the same ``[B, n_pages*page]`` KV extent.
+    For ff impls the dense path runs a copy of the model with its decode
+    tile pinned to the paged graph's word at the probe's table width
+    (:func:`paged_block_kv`); xla impls match because both views present
+    the same ``[B, n_pages*page]`` KV extent.
     """
     rng = np.random.default_rng(seed)
     b = 2
@@ -428,8 +444,15 @@ def decode_parity_probe(model, params, cfg, policy, *, page: int,
     n_pages = -(-(p_max + n_steps) // page)
     s_max = n_pages * page
 
+    dense_model = model
+    if cfg.attn_impl == "ff":
+        from repro.models import build_model
+        dense_model = build_model(cfg.replace(
+            decode_block_kv=paged_block_kv(cfg, n_pages, page)))
     prefill = jax.jit(steps_lib.make_prefill_step(model, policy=policy))
     decode = jax.jit(steps_lib.make_decode_step(model, policy=policy))
+    decode_dense = jax.jit(steps_lib.make_decode_step(dense_model,
+                                                      policy=policy))
 
     _, dense = prefill(params, {"tokens": jnp.asarray(toks)})
     dense_cache = pad_cache_to(dense, p_max, s_max, 2)
@@ -448,7 +471,7 @@ def decode_parity_probe(model, params, cfg, policy, *, page: int,
     kv.lengths[:] = lens - 1
     max_diff = 0.0
     for _ in range(n_steps):
-        nd, logits_d, dense_cache = decode(
+        nd, logits_d, dense_cache = decode_dense(
             params, {"token": cur_d, "lengths": len_d}, dense_cache)
         np_, logits_p, new_caches = decode(
             params, {"token": cur_p, "lengths": jnp.asarray(kv.lengths)},
@@ -473,10 +496,14 @@ def serve_bench(args) -> Dict[str, object]:
         raise SystemExit("serve driver targets decoder-only archs")
     if args.impl != "cfg":
         cfg = cfg.replace(attn_impl=args.impl)
+    requests = make_requests(
+        args.requests, prompt_len=args.prompt_len, max_new=args.max_new,
+        rate=args.rate, vocab=cfg.vocab, seed=args.seed)
     if cfg.attn_impl == "ff":
-        # pin the dense path's KV tile to the page so lockstep decode is
-        # bitwise-identical to the paged stream graph
-        cfg = cfg.replace(decode_block_kv=args.page)
+        # pin the dense path's KV tile to the paged graph's grid word so
+        # lockstep decode is bitwise-identical to the paged stream graph
+        cfg = cfg.replace(decode_block_kv=paged_block_kv(
+            cfg, _pages_max(requests, args.page), args.page))
     if getattr(args, "layer_graph", False):
         # route dense-cache decode steps through the whole-layer
         # decode_layer StreamGraph (one planned multi-kernel program per
@@ -491,10 +518,6 @@ def serve_bench(args) -> Dict[str, object]:
     # interpreted kernels are plain HLO and shard over the host mesh
     mesh = make_host_mesh(devices=None if policy.interpret
                           else jax.devices()[:1])
-
-    requests = make_requests(
-        args.requests, prompt_len=args.prompt_len, max_new=args.max_new,
-        rate=args.rate, vocab=cfg.vocab, seed=args.seed)
 
     # plan-service hooks: --plan-db points the autotune lookup chain at a
     # release PlanDB (pre-warmed here so the first resolution is a dict
@@ -591,8 +614,9 @@ def add_serve_args(ap: argparse.ArgumentParser) -> None:
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--page", type=int, default=16,
-                    help="KV block (page) size in tokens; also pins the ff "
-                         "dense path's block_kv for bitwise parity")
+                    help="KV block (page) size in tokens; the ff dense "
+                         "path's block_kv is pinned to the paged graph's "
+                         "grid word of pages for bitwise parity")
     ap.add_argument("--slots", type=int, default=4,
                     help="decode slots (batch rows) for both schedulers")
     ap.add_argument("--rate", type=float, default=10.0,

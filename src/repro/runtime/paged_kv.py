@@ -17,35 +17,43 @@ the serving cache around fixed-size KV blocks (pages):
     page)`` blocks; retirement recycles them, so KV memory scales with the
     *live* token count instead of ``B * S_max``.
   * :func:`gather_indices` — flattens a block table into the page-index
-    stream an ``ff_gather`` producer walks: word ``w = (b*J + j)*n_pages +
-    kj`` fetches page ``kj`` of lane word ``j`` for request ``b``.
+    stream an ``ff_gather`` producer walks, each row's table padded with
+    sentinels to whole words of ``ppw`` pages: entry ``(b*J + j)*P + kj``
+    (``P`` the padded pages per row) fetches page ``kj`` of lane word
+    ``j`` for request ``b``.
   * ``paged_decode_attention`` StreamGraph — the registered two-node graph
     (block-table page gather producer → online-softmax decode-attention
-    consumer). The gather moves one ``(2*page, lanes)`` page per word, its
-    out blocks line up word-for-word with the consumer's kv pipe, and
-    ``check_fusion`` legalizes the edge with wpb=1: the gathered pages
-    stream through a VMEM ring and never round-trip HBM. Tuned jointly via
+    consumer). Each grid word moves ``ppw`` ``(2*page, lanes)`` pages of
+    one (row, lane word) in one bundle of DMAs (:func:`pages_per_word`
+    picks ``ppw`` from the call's shape), its out blocks line up
+    word-for-word with the consumer's kv pipe, and ``check_fusion``
+    legalizes the edge with wpb=1: the gathered pages stream through a
+    VMEM ring and never round-trip HBM. Tuned jointly via
     :func:`repro.core.autotune.resolve_graph`.
 
 The consumer's softmax math is identical to the contiguous
-``ff_decode_attention`` kernel at ``block_kv == page`` (same lane packing,
-same tile order, same f32 accumulation), so paged decode is
+``ff_decode_attention`` kernel at ``block_kv == ppw * page`` (same lane
+packing, same tile order, same f32 accumulation), so paged decode is
 *bitwise-equal* to the contiguous-cache path — rows past ``length`` (zero
-fill or stale recycled block contents) mask to ``-1e30`` and their ``exp``
-underflows to exactly 0.0. ``tests/test_serving_paged.py`` asserts this.
+fill, stale recycled block contents or sentinel padding) mask to ``-1e30``
+and their ``exp`` underflows to exactly 0.0.
+``tests/test_serving_paged.py`` asserts this.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import autotune
 from repro.core.emitter import lane_pack, pack_blockdiag, unpack_blockdiag
+from repro.core.pipe import DEFAULT_VMEM_BUDGET_BYTES
 from repro.core.program import current_policy
 
 
@@ -90,24 +98,71 @@ def dense_kv(kv_pool, block_tables, kv_heads: int):
 
 
 # ---------------------------------------------------------------------------
+# Grid words: pages per word
+# ---------------------------------------------------------------------------
+
+# K and V bytes one grid word of the paged graph aims to move: 512 tokens
+# of a 128-lane bf16 lane word. A grid step has a fixed cost and its
+# bundle of page DMAs is all that is in flight, so one page per word
+# (8 KiB) leaves the kernel bound by its step count, far below HBM rate.
+_WORD_BYTES = 256 * 1024
+# ring slots a word may occupy in the fused kernel: the deepest producer
+# ring the planner picks (``planner.plan_pipe``'s depth cap) plus the
+# fused edge's two-slot intermediate ring, within one node's half of the
+# planner's VMEM budget
+_RING_SLOTS = 17 + 2
+
+
+def pages_per_word(n_pages: int, page: int, lanes: int, dtype) -> int:
+    """``ppw``: KV pages per grid word of the ``paged_decode_attention``
+    graph for rows of ``n_pages`` pages of ``page`` tokens in ``lanes``-wide
+    lane words of ``dtype``.
+
+    Words hold at most ``cap`` pages, about :data:`_WORD_BYTES` of K and
+    V; a row's pages spread evenly over ``ceil(n_pages / cap)`` words (under
+    one page of padding a word). ``ppw * page`` then rounds up to a
+    multiple of 128 tokens (whole lanes of the score block) where the row
+    still needs as many words. The row's grid is ``ceil(n_pages / ppw)``
+    words, its last padded with sentinel pages.
+    """
+    assert n_pages >= 1, n_pages
+    page_bytes = 2 * page * lanes * jnp.dtype(dtype).itemsize
+    word_bytes = min(_WORD_BYTES,
+                     DEFAULT_VMEM_BUDGET_BYTES // 2 // _RING_SLOTS)
+    cap = max(1, word_bytes // page_bytes)
+    n_words = -(-n_pages // cap)
+    ppw = -(-n_pages // n_words)
+    step = 128 // math.gcd(page, 128)
+    up = -(-ppw // step) * step
+    if up <= cap and -(-n_pages // up) == n_words:
+        ppw = up
+    return ppw
+
+
+# ---------------------------------------------------------------------------
 # Block-table -> gather page indexing
 # ---------------------------------------------------------------------------
 
 
-def gather_indices(block_tables, *, lane_words: int,
-                   n_blocks: int) -> jnp.ndarray:
+def gather_indices(block_tables, *, lane_words: int, n_blocks: int,
+                   ppw: int = 1) -> jnp.ndarray:
     """Page indices into the pool viewed as ``[nb*J, 2*page, lanes]`` for
     one decode step.
 
     ``block_tables``: [B, n_pages] int32 (entries >= ``n_blocks`` are
     sentinels for unallocated pages; they clip to a real block and the
-    consumer's length mask discards whatever they fetch). Returns the
-    [B*J*n_pages] index stream in ``ff_gather`` word order: word
-    ``(b*J + j)*n_pages + kj`` reads lane word ``j`` of page ``kj``.
+    consumer's length mask discards whatever they fetch). Each row is
+    padded with sentinels to ``P = ceil(n_pages / ppw) * ppw`` pages.
+    Returns the [B*J*P] index stream in ``ff_gather`` order: entry
+    ``(b*J + j)*P + kj`` reads lane word ``j`` of page ``kj``, and each
+    run of ``ppw`` entries is one grid word.
     """
-    bt = jnp.clip(jnp.asarray(block_tables, jnp.int32), 0, n_blocks - 1)
+    bt = jnp.asarray(block_tables, jnp.int32)
+    bt = jnp.pad(bt, ((0, 0), (0, -bt.shape[1] % ppw)),
+                 constant_values=n_blocks)
+    bt = jnp.clip(bt, 0, n_blocks - 1)
     j = jnp.arange(lane_words, dtype=jnp.int32)
-    idx = bt[:, None, :] * lane_words + j[None, :, None]   # [B, J, n_pages]
+    idx = bt[:, None, :] * lane_words + j[None, :, None]   # [B, J, P]
     return idx.reshape(-1).astype(jnp.int32)
 
 
@@ -155,17 +210,18 @@ def paged_decode_attention_ref(q, kv_pool, block_tables, lengths, *,
 def build_paged_decode_graph(*, b: int, kvh: int, g_pad: int, n_pages: int,
                              page: int, d: int, dtype=jnp.float32,
                              kv_dtype=None, depth: int = 2,
-                             streams: int = 1):
+                             streams: int = 1, ppw: Optional[int] = None):
     """Declare the paged-decode StreamGraph at one shape point (``kvh``
     KV heads of ``d`` lanes, ``g_pad`` padded query heads each): an
     ``ff_gather`` producer walking the block-table page stream feeding the
-    paged online-softmax consumer through a fusable ``(2*page, lanes)``
-    edge.
+    paged online-softmax consumer through a fusable ``(ppw, 2*page,
+    lanes)`` edge.
 
     Both nodes run on lane-packed heads (:func:`pool_lanes`): the gather
-    moves one whole ``(2*page, lanes)`` page block per word, so its out
-    blocks coincide with the consumer's kv words — the geometry
-    ``check_fusion`` needs for wpb=1.
+    moves ``ppw`` whole ``(2*page, lanes)`` page blocks per word (default
+    :func:`pages_per_word`), so its out blocks coincide with the
+    consumer's kv words — the geometry ``check_fusion`` needs for wpb=1.
+    The grid runs ``b * J * ceil(n_pages / ppw)`` words.
     """
     from repro.core.graph import GraphEdge, GraphNode, StreamGraph
     from repro.kernels.ff_decode_attention.kernel import build_paged_program
@@ -177,17 +233,20 @@ def build_paged_decode_graph(*, b: int, kvh: int, g_pad: int, n_pages: int,
     kv_dtype = kv_dtype or dtype
     j, lanes = pool_lanes(kvh, d)
     p = kvh // j
-    n = b * j * n_pages
-    gather = gather_prog(n, lanes, block_rows=2 * page, blocks_per_word=1,
+    if ppw is None:
+        ppw = pages_per_word(n_pages, page, lanes, kv_dtype)
+    n_words = -(-n_pages // ppw)
+    n = b * j * n_words * ppw
+    gather = gather_prog(n, lanes, block_rows=2 * page, blocks_per_word=ppw,
                          dtype=kv_dtype, depth=depth)
-    attn = build_paged_program(b, j, p * g_pad, n_pages, page, lanes,
+    attn = build_paged_program(b, j, p * g_pad, n_words, ppw, page, lanes,
                                dtype=dtype, kv_dtype=kv_dtype, depth=depth,
                                streams=streams, scale=1.0 / (d ** 0.5),
                                lane_heads=p)
     w_g, t_g = gather_workload(n, lanes, block_rows=2 * page,
-                               blocks_per_word=1, dtype=kv_dtype)
+                               blocks_per_word=ppw, dtype=kv_dtype)
     w_a, t_a = paged_decode_attention_workload(
-        b, kvh * g_pad, kvh, n_pages, page, d, dtype=kv_dtype)
+        b, kvh * g_pad, kvh, n_pages, page, d, ppw=ppw, dtype=kv_dtype)
     return StreamGraph(
         name="paged_decode_attention",
         nodes=(
@@ -195,18 +254,22 @@ def build_paged_decode_graph(*, b: int, kvh: int, g_pad: int, n_pages: int,
             GraphNode("attn", attn, workload=w_a, plan_tile=t_a),
         ),
         edges=(
-            GraphEdge("gather", "attn", "kv", reshape=(n * 2 * page, lanes)),
+            GraphEdge("gather", "attn", "kv", reshape=(n, 2 * page, lanes)),
         ),
     )
 
 
 def paged_decode_attention(q, kv_pool, block_tables, lengths, *,
-                           policy=None) -> jnp.ndarray:
+                           policy=None, ppw: Optional[int] = None
+                           ) -> jnp.ndarray:
     """Decode attention for one new token through the block table.
 
     q: [B, H, hd]; kv_pool: [n_blocks, J, 2, page, lanes] (one layer's
     pool); block_tables: [B, n_pages] int32; lengths: [B] int32 (0 =
-    inactive slot). Returns [B, H, hd].
+    inactive slot). Returns [B, H, hd]. ``ppw`` (pages per grid word)
+    defaults to :func:`pages_per_word` at this shape; a plan sweep that
+    replays a recorded call site at a bucketed ``n_pages`` passes the
+    recorded value, so it measures the grid the site ran.
     """
     policy = current_policy() if policy is None else policy
     b, h, d = q.shape
@@ -218,18 +281,20 @@ def paged_decode_attention(q, kv_pool, block_tables, lengths, *,
     lens = lengths.astype(jnp.int32)
     if policy.mode == "ref":
         return paged_decode_attention_ref(q, kv_pool, block_tables, lens)
+    if ppw is None:
+        ppw = pages_per_word(n_pages, page, lanes, kv_pool.dtype)
     g_pad = -(-group // 8) * 8
     qg = q.reshape(b, kvh, group, d)
     qg = jnp.pad(qg, ((0, 0), (0, 0), (0, g_pad - group), (0, 0)))
     qp = pack_blockdiag(qg, kvh // j)
-    idx = gather_indices(block_tables, lane_words=j, n_blocks=nb)
+    idx = gather_indices(block_tables, lane_words=j, n_blocks=nb, ppw=ppw)
     table = kv_pool.reshape(nb * j, 2 * page, lanes)
 
     def build(depth=2, streams=1):
         return build_paged_decode_graph(
             b=b, kvh=kvh, g_pad=g_pad, n_pages=n_pages, page=page, d=d,
             dtype=qp.dtype, kv_dtype=kv_pool.dtype, depth=depth,
-            streams=streams)
+            streams=streams, ppw=ppw)
 
     from repro.core import graph as graphlib
     g0 = build()
@@ -249,16 +314,21 @@ def paged_decode_attention(q, kv_pool, block_tables, lengths, *,
         runner=None if autotune.has_tracers(q, kv_pool, block_tables, lens)
         else runner,
         site={"b": b, "h": h, "kvh": kvh, "n_pages": n_pages, "page": page,
-              "d": d, "n_blocks": nb, "q_dtype": str(q.dtype)},
+              "d": d, "n_blocks": nb, "q_dtype": str(q.dtype), "ppw": ppw},
         site_dynamic=("b", "n_pages", "n_blocks"))
     # compiled fresh per call: the graph closure may capture trace-scoped
     # constants, so it must never be reused across jit traces (the outer
     # jitted decode step already amortizes the rebuild)
     mode = "ff" if policy.mode == "autotune" else policy.mode
-    cg = graphlib.compile_graph(
-        build(depth=choice.depth, streams=choice.streams),
-        policy=policy.replace(mode=mode, depth=choice.depth,
-                              streams=choice.streams))
+    n_words = -(-n_pages // ppw)
+    # the grid this call runs, in the trace of the build
+    with obs.span("paged_attn_grid", grid_words=b * j * n_words, ppw=ppw,
+                  words_per_row=n_words, rows=b, lane_words=j,
+                  n_pages=n_pages, page=page):
+        cg = graphlib.compile_graph(
+            build(depth=choice.depth, streams=choice.streams),
+            policy=policy.replace(mode=mode, depth=choice.depth,
+                                  streams=choice.streams))
     out = unpack_blockdiag(cg(idx, table, lens, qp), kvh // j)
     return out[:, :, :group, :].reshape(b, h, d)
 
@@ -468,8 +538,15 @@ class PagedKVCache:
 # b=2 kv_heads=2 g_pad=8 n_pages=4 page=16 d=64 over a 12-block pool (the
 # two hd-64 heads share one 128-lane word); block tables drawn from a
 # permutation so the gather is genuinely non-contiguous, lengths mixed (one
-# partial page, one full table)
+# partial page, one full table). In f32 a word holds 8 pages: each row is
+# one word, its last 4 pages sentinel padding.
 _SMOKE = dict(b=2, kvh=2, g_pad=8, n_pages=4, page=16, d=64, nb=12)
+
+
+def _smoke_ppw() -> int:
+    c = _SMOKE
+    return pages_per_word(c["n_pages"], c["page"],
+                          pool_lanes(c["kvh"], c["d"])[1], jnp.float32)
 
 
 def _paged_build(*, depth: int = 2, streams: int = 1):
@@ -491,7 +568,8 @@ def _paged_inputs(key):
     perm = jax.random.permutation(
         jax.random.fold_in(key, 1), c["nb"])[:c["b"] * c["n_pages"]]
     bt = perm.reshape(c["b"], c["n_pages"]).astype(jnp.int32)
-    idx = gather_indices(bt, lane_words=j, n_blocks=c["nb"])
+    idx = gather_indices(bt, lane_words=j, n_blocks=c["nb"],
+                         ppw=_smoke_ppw())
     lens = jnp.array([37, c["n_pages"] * c["page"]], jnp.int32)
     q = 0.3 * jax.random.normal(jax.random.fold_in(key, 2),
                                 (c["b"], c["kvh"], c["g_pad"], c["d"]),
@@ -500,13 +578,14 @@ def _paged_inputs(key):
 
 
 def _paged_heads(idx, table):
-    """Per-head dense K/V [b, KVH, S, d] of the gathered page stream."""
+    """Per-head dense K/V [b, KVH, S, d] of the gathered page stream (S
+    counts the sentinel pages that pad each row to whole words)."""
     c = _SMOKE
-    j, lanes = pool_lanes(c["kvh"], c["d"])
-    s = c["n_pages"] * c["page"]
-    kv = table[idx].reshape(c["b"], j, c["n_pages"], 2, c["page"], lanes)
-    kv = kv.reshape(c["b"], j, c["n_pages"], 2, c["page"], c["kvh"] // j,
-                    c["d"])
+    j = pool_lanes(c["kvh"], c["d"])[0]
+    npg = idx.shape[0] // (c["b"] * j)
+    s = npg * c["page"]
+    kv = table[idx].reshape(c["b"], j, npg, 2, c["page"], c["kvh"] // j,
+                            c["d"])
     kv = kv.transpose(0, 1, 5, 3, 2, 4, 6)      # [b, j, p, 2, npg, page, d]
     kv = kv.reshape(c["b"], c["kvh"], 2, s, c["d"])
     return kv[:, :, 0], kv[:, :, 1]
@@ -532,8 +611,8 @@ def _paged_ref(idx, table, lengths, q):
 def _paged_unfused(idx, table, lengths, q):
     """Gather then decode-attention as two separate repro.ops calls — the
     gathered page stream round-trips HBM (the BENCH_graph staged
-    baseline). block_kv is pinned to the page size so the comparison
-    isolates the lowering, not the tiling."""
+    baseline). block_kv is pinned to the graph's word (``ppw`` pages) so
+    the comparison isolates the lowering, not the tiling."""
     import repro
 
     c = _SMOKE
@@ -545,14 +624,17 @@ def _paged_unfused(idx, table, lengths, q):
     qh = unpack_blockdiag(q, p)
     b, kvh, g_pad, d = qh.shape
     out = repro.ops.decode_attention(
-        qh.reshape(b, kvh * g_pad, d), k, v, lengths, block_kv=c["page"])
+        qh.reshape(b, kvh * g_pad, d), k, v, lengths,
+        block_kv=_smoke_ppw() * c["page"])
     return pack_blockdiag(out.reshape(b, kvh, g_pad, d), p)
 
 
 def _paged_sweep_inputs(key, site):
     """Rebuild paged_decode_attention operands at a recorded call-site
     shape (plan sweep). ``dtype`` is the resolve dtype (the KV pool's);
-    ``q_dtype`` rides along in the recorded site dict."""
+    ``q_dtype`` and the pages per word the site ran (``ppw``, not
+    bucketed) ride along in the recorded site dict, so the replay at the
+    bucketed ``n_pages`` keeps the site's word size."""
     b, h, kvh = int(site["b"]), int(site["h"]), int(site["kvh"])
     n_pages, page = int(site["n_pages"]), int(site["page"])
     d, nb = int(site["d"]), int(site["n_blocks"])
@@ -566,7 +648,8 @@ def _paged_sweep_inputs(key, site):
                                  max(nb, b * n_pages))[:b * n_pages]
           % nb).reshape(b, n_pages).astype(jnp.int32)
     lens = jnp.full((b,), n_pages * page, jnp.int32)
-    return (q, pool, bt, lens), {}
+    kw = {"ppw": int(site["ppw"])} if "ppw" in site else {}
+    return (q, pool, bt, lens), kw
 
 
 def _register_paged_graph():

@@ -10,7 +10,9 @@ Layers covered:
     (block, k/v, offset, head) coordinates.
   * the registered ``paged_decode_attention`` StreamGraph vs. its XLA
     oracle (fused edge) and kernel-level bitwise parity vs. the contiguous
-    ``ff_decode_attention`` at ``block_kv == page``.
+    ``ff_decode_attention`` at ``block_kv == ppw * page`` (``ppw`` pages
+    per grid word, :func:`~repro.runtime.paged_kv.pages_per_word`); the
+    graph's grid at the benchmark cells' shapes.
   * model-level decode: dense cache vs. paged pool, xla and ff impls,
     bitwise logits equality over multiple steps (mixed lengths).
   * scheduler semantics: lockstep terminates in exactly
@@ -29,9 +31,11 @@ from repro.core.program import PipePolicy
 from repro.launch import serve as serve_lib
 from repro.launch import steps as steps_lib
 from repro.runtime.paged_kv import (BlockAllocator, OutOfBlocks,
-                                    PagedKVCache, gather_indices, pack_pool,
-                                    paged_decode_attention,
-                                    paged_decode_attention_ref, unpack_pool)
+                                    PagedKVCache, build_paged_decode_graph,
+                                    gather_indices, pack_pool,
+                                    pages_per_word, paged_decode_attention,
+                                    paged_decode_attention_ref, pool_lanes,
+                                    unpack_pool)
 
 KEY = jax.random.key(11)
 ARCH = "qwen1_5_0p5b"
@@ -97,6 +101,12 @@ def test_gather_indices_layout():
     for b, w, pg in np.ndindex(2, j, 2):
         blk = min(int(bt[b, pg]), nb - 1)          # sentinel clips
         assert idx[b, w, pg] == blk * j + w
+    # three pages per word: each row pads to 3 with clipped sentinels
+    idx = np.asarray(gather_indices(bt, lane_words=j, n_blocks=nb, ppw=3))
+    idx = idx.reshape(2, j, 3)
+    for b, w, pg in np.ndindex(2, j, 3):
+        blk = min(int(bt[b, pg]), nb - 1) if pg < 2 else nb - 1
+        assert idx[b, w, pg] == blk * j + w
 
 
 def test_pool_pages_are_whole_lane_words():
@@ -155,30 +165,131 @@ def test_paged_kernel_bf16_within_its_two_roundings():
     assert np.linalg.norm(got - ref) <= 1.2 * np.hypot(out_floor, p_floor)
 
 
-def test_paged_kernel_bitwise_vs_contiguous():
+@pytest.mark.parametrize("npg,lens", [
+    (4, (97, 128)),       # one word per row: ppw == n_pages
+    (13, (200, 416)),     # n_pages not a multiple of ppw: sentinel pages
+    (5, (0, 160)),        # a length-0 row next to a full one
+], ids=["one_word_per_row", "padded_sentinel_pages", "empty_row"])
+def test_paged_kernel_bitwise_vs_contiguous(npg, lens):
     """Same pool dereferenced two ways: through the block-table stream
-    graph and as a dense cache at block_kv == page. Identical tile order +
-    identical f32 online softmax => bitwise-equal outputs, even with
-    garbage in the masked tail (exp underflows to exactly 0)."""
+    graph and as a dense cache at block_kv == ppw * page. Identical tile
+    order + identical f32 online softmax => bitwise-equal outputs, even
+    with garbage in the masked tail and the sentinel pages that pad a row
+    to whole words (exp underflows to exactly 0)."""
     import repro
-    b, h, kvh, d = 2, 8, 2, 64
-    nb, page, npg = 12, 32, 5
+    b, h, kvh, d, page = 2, 8, 2, 64, 32
+    nb = b * npg + 2
+    ppw = pages_per_word(npg, page, pool_lanes(kvh, d)[1], jnp.float32)
+    n_words = -(-npg // ppw)
+    if npg == 4:
+        assert ppw == npg
+    if npg == 13:
+        assert npg % ppw and n_words > 1
     s = npg * page
     heads = jax.random.normal(KEY, (nb, 2, page, kvh, d), jnp.float32)
     pool = pack_pool(heads)
     perm = np.random.default_rng(3).permutation(nb)[:b * npg]
     bt = jnp.asarray(perm.reshape(b, npg), jnp.int32)
-    lens = jnp.array([97, s], jnp.int32)
+    lens = jnp.array(lens, jnp.int32)
     q = jax.random.normal(jax.random.fold_in(KEY, 1), (b, h, d),
                           jnp.float32)
     kv = heads[bt]
     k = kv[:, :, 0].reshape(b, s, kvh, d).transpose(0, 2, 1, 3)
     v = kv[:, :, 1].reshape(b, s, kvh, d).transpose(0, 2, 1, 3)
+    pad = ((0, 0), (0, 0), (0, (n_words * ppw - npg) * page), (0, 0))
+    k, v = jnp.pad(k, pad), jnp.pad(v, pad)
     pol = PipePolicy(mode="ff", depth=2, streams=1, interpret=True)
-    cont = repro.ops.decode_attention(q, k, v, lens, block_kv=page,
+    cont = repro.ops.decode_attention(q, k, v, lens, block_kv=ppw * page,
                                       policy=pol)
     paged = paged_decode_attention(q, pool, bt, lens, policy=pol)
     assert np.array_equal(np.asarray(cont), np.asarray(paged))
+
+
+# the benchmark cells' decode shapes: (rows, heads, KV heads, head dim,
+# pages reserved per row) -> pages per word; 16-token bf16 pages
+_CELL_SHAPES = {
+    "qwen_short_chat": ((32, 16, 16, 64, 48), 24),
+    "qwen_long_decode": ((10, 16, 16, 64, 208), 32),
+    "starcoder2_code_complete": ((16, 48, 4, 128, 248), 32),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_CELL_SHAPES))
+def test_paged_graph_grid_fuses_at_cell_shapes(cell):
+    """At each cell's shape the built graph fuses into one kernel (wpb 1)
+    and runs B x J x ceil(n_pages / ppw) grid words of ppw pages each, not
+    one word per page; the plan-cache signature tells it from a graph of
+    one page per word."""
+    from repro.core.graph import check_fusion, graph_signature
+    (b, h, kvh, hd, n_pages), want_ppw = _CELL_SHAPES[cell]
+    page = 16
+    j, lanes = pool_lanes(kvh, hd)
+    ppw = pages_per_word(n_pages, page, lanes, jnp.bfloat16)
+    assert ppw == want_ppw
+    g_pad = -(-(h // kvh) // 8) * 8
+    kw = dict(b=b, kvh=kvh, g_pad=g_pad, n_pages=n_pages, page=page, d=hd,
+              dtype=jnp.bfloat16)
+    graph = build_paged_decode_graph(**kw)
+    gather, attn = graph.node("gather").program, graph.node("attn").program
+    rep = check_fusion(gather, attn, graph.edges[0])
+    assert rep.ok and rep.wpb == 1, rep.reason
+    assert attn.n_words == gather.n_words == b * j * -(-n_pages // ppw)
+    assert tuple(gather.out_block) == (ppw, 2 * page, lanes)
+    assert graph_signature(graph) != graph_signature(
+        build_paged_decode_graph(**kw, ppw=1))
+
+
+def test_paged_attn_grid_span():
+    """Building the graph records the grid it runs in the trace: a
+    ``paged_attn_grid`` span with the call's grid words and ppw."""
+    from repro import obs
+    b, kvh, d, npg, page = 2, 2, 64, 3, 16
+    pool = pack_pool(jax.random.normal(KEY, (b * npg, 2, page, kvh, d),
+                                       jnp.float32))
+    q = jax.random.normal(jax.random.fold_in(KEY, 1), (b, kvh, d),
+                          jnp.float32)
+    bt = jnp.arange(b * npg, dtype=jnp.int32).reshape(b, npg)
+    prev = obs.enable()
+    obs.drain()
+    try:
+        paged_decode_attention(q, pool, bt, jnp.array([20, 48], jnp.int32),
+                               policy=PipePolicy(mode="ff"))
+        recs = obs.drain()
+    finally:
+        obs.restore(prev)
+    grid = [r for r in recs if r["name"] == "paged_attn_grid"]
+    assert len(grid) == 1
+    ppw = pages_per_word(npg, page, pool_lanes(kvh, d)[1], jnp.float32)
+    j = pool_lanes(kvh, d)[0]
+    assert grid[0]["attrs"]["ppw"] == ppw
+    assert grid[0]["attrs"]["grid_words"] == b * j * -(-npg // ppw)
+    assert any(r["name"] == "compile_graph"
+               and r["parent"] == grid[0]["id"] for r in recs)
+
+
+def test_paged_sweep_replays_the_recorded_ppw():
+    """A plan sweep replays a recorded call at its bucketed n_pages with
+    the pages per word the call ran, not the rule's pick at the bucket."""
+    from repro.plans import record_traffic
+    from repro.runtime import paged_kv
+    b, kvh, d, npg, page = 1, 1, 64, 40, 16       # one 64-lane word
+    pool = pack_pool(jax.random.normal(KEY, (npg, 2, page, kvh, d),
+                                       jnp.float32))
+    q = jax.random.normal(jax.random.fold_in(KEY, 1), (b, 2, d),
+                          jnp.float32)
+    bt = jnp.arange(npg, dtype=jnp.int32)[None]
+    with record_traffic() as prof:
+        paged_decode_attention(q, pool, bt, jnp.array([600], jnp.int32),
+                               policy=PipePolicy(mode="ff"))
+    (entry,) = [e for e in prof.entries.values()
+                if e.op == "graph:paged_decode_attention"]
+    ppw = pages_per_word(npg, page, 64, jnp.float32)
+    bucket = entry.site["n_pages"]
+    assert bucket > npg
+    assert pages_per_word(bucket, page, 64, jnp.float32) != ppw
+    (_, pool_b, bt_b, _), kw = paged_kv._paged_sweep_inputs(
+        KEY, dict(entry.site, dtype=entry.dtype))
+    assert bt_b.shape == (b, bucket) and kw == {"ppw": ppw}
 
 
 # ---------------------------------------------------------------------------
@@ -186,10 +297,15 @@ def test_paged_kernel_bitwise_vs_contiguous():
 # ---------------------------------------------------------------------------
 
 
-def _model_for(impl):
+def _model_for(impl, n_pages=None):
+    """The model at ``impl``; with ``n_pages``, an ff model's dense decode
+    tile is the paged graph's word at that many pages (``ppw * PAGE``),
+    the tile order that makes the two paths bitwise-equal
+    (``decode_parity_probe`` pins its dense path itself)."""
     cfg = smoke_config(ARCH).replace(remat="none", attn_impl=impl)
-    if impl == "ff":
-        cfg = cfg.replace(decode_block_kv=PAGE)
+    if impl == "ff" and n_pages is not None:
+        cfg = cfg.replace(decode_block_kv=serve_lib.paged_block_kv(
+            cfg, n_pages, PAGE))
     from repro.models import build_model
     return build_model(cfg), cfg
 
@@ -250,7 +366,8 @@ def test_token_parity_paged_contiguous_oracle():
     prompts = [rng.integers(1, cfg0.vocab, size=n).astype(np.int32)
                for n in (5, 12, 9)]
     steps = 6
-    model_ff, cfg_ff = _model_for("ff")
+    n_pages = -(-(max(map(len, prompts)) + steps) // PAGE)   # as _greedy
+    model_ff, cfg_ff = _model_for("ff", n_pages)
     model_x, cfg_x = _model_for("xla")
     params = model_ff.init(KEY)   # identical params for both impls
     t_paged = _greedy(model_ff, cfg_ff, params, prompts, steps, paged=True)

@@ -83,17 +83,27 @@ def test_prefill_attention_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
-def test_paged_decode_graph_compiles(one_chip):
+# (heads, KV heads, head dim, pages per row): Qwen1.5-0.5B (MHA, two hd-64
+# heads per 128-lane word) and StarCoder2-15B (GQA 12:1, hd 128) at the
+# table width of its benchmark cell
+_PAGED_SHAPES = {"qwen": (H, KVH, HD, S_CACHE // PAGE),
+                 "starcoder2": (48, 4, 128, 248)}
+
+
+@pytest.mark.parametrize("model", sorted(_PAGED_SHAPES))
+def test_paged_decode_graph_compiles(one_chip, model):
+    """Mosaic takes the graph's (ppw, 2*page, lanes) word and the
+    consumer's (ppw*page, lanes) view of its K and V pages."""
     from repro.runtime.paged_kv import paged_decode_attention, pool_lanes
-    n_pages = S_CACHE // PAGE
+    h, kvh, hd, n_pages = _PAGED_SHAPES[model]
     nb = B * n_pages
-    j, lanes = pool_lanes(KVH, HD)
+    j, lanes = pool_lanes(kvh, hd)
     assert lanes == 128
 
     def f(q, pool, bt, lens):
         return paged_decode_attention(q, pool, bt, lens, policy=compiled())
 
-    text = _compile_text(f, one_chip, ((B, H, HD), BF16),
+    text = _compile_text(f, one_chip, ((B, h, hd), BF16),
                          ((nb, j, 2, PAGE, lanes), BF16),
                          ((B, n_pages), jnp.int32), ((B,), jnp.int32))
     assert "tpu_custom_call" in text
