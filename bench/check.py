@@ -2,8 +2,9 @@
 
 Once the window has closed and the program's state is freed, a sample of
 the finished requests, drawn from the seed and always holding the longest
-one, is run through the reference (``reference.py``, float32, highest
-precision) over its prompt and the tokens it was served. At each served
+one, is run through the plain reference of the cell's architecture
+(``archs/<model_type>.py`` ``logits_at``, float32, highest precision)
+over its prompt and the tokens it was served. At each served
 position the reference's best logit minus its logit for the served token
 is that token's gap; the number compared is the widest gap. A greedy
 server at the configuration's precision serves the reference's best token
@@ -19,8 +20,7 @@ from typing import Dict, List
 
 import numpy as np
 
-import reference
-import weights as weights_lib
+from reference import Q_BLOCK
 
 SAMPLE_SALT = 0x5EED
 
@@ -45,7 +45,7 @@ def sample(tl, check_tokens: int, seed: int) -> List[int]:
 
 def padded_len(traffic: Dict) -> int:
     t = traffic["prompt_len"]["max"] + traffic["output_len"]["max"]
-    return -(-t // reference.Q_BLOCK) * reference.Q_BLOCK
+    return -(-t // Q_BLOCK) * Q_BLOCK
 
 
 def _inputs(prompt, served, t_pad: int, k_pad: int):
@@ -57,23 +57,24 @@ def _inputs(prompt, served, t_pad: int, k_pad: int):
     return seq, rows.astype(np.int32)
 
 
-def gaps(spec, seed: int, traffic: Dict, items, *, control: bool = False):
+def gaps(cell, seed: int, items, *, control: bool = False):
     """Widest gap over ``items`` (``(prompt, served_tokens)`` pairs) and
     the number of tokens compared. With ``control`` the token judged at
     each position is the fp8 reference's first choice instead of the
     served one."""
     import jax.numpy as jnp
-    w = weights_lib.canonical(spec, seed)
-    t_pad = padded_len(traffic)
-    k_pad = traffic["output_len"]["max"]
+    arch, spec = cell.arch, cell.spec
+    w = arch.canonical(spec, seed)
+    t_pad = padded_len(cell.traffic)
+    k_pad = cell.traffic["output_len"]["max"]
     worst, n = 0.0, 0
     for prompt, served in items:
         seq, rows = _inputs(prompt, served, t_pad, k_pad)
-        ref = reference.logits_at(spec, w, jnp.asarray(seq),
-                                  jnp.asarray(rows), "f32")[:len(served)]
+        ref = arch.logits_at(spec, w, jnp.asarray(seq), jnp.asarray(rows),
+                             "f32")[:len(served)]
         if control:
-            ctl = reference.logits_at(spec, w, jnp.asarray(seq),
-                                      jnp.asarray(rows), "fp8")
+            ctl = arch.logits_at(spec, w, jnp.asarray(seq),
+                                 jnp.asarray(rows), "fp8")
             pick = jnp.argmax(ctl[:len(served)], axis=-1)
         else:
             pick = jnp.asarray(np.asarray(served, np.int32))
@@ -91,7 +92,7 @@ def compare(cell, tl, run, seed: int) -> Dict[str, Dict]:
     fed = sum(q.fed_mismatch for q in tl.reqs.values())
     rids = sample(tl, cell.traffic["check_tokens"], seed)
     by_rid = {r.rid: r for r in run.requests}
-    gap, n = gaps(cell.spec, seed, cell.traffic,
+    gap, n = gaps(cell, seed,
                   [(by_rid[r].prompt, tl.reqs[r].tokens) for r in rids])
     limit = cell.config.get("limit_logit_gap")
     return {

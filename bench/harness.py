@@ -1,10 +1,12 @@
 """Finds everything a cell needs by the names in ``BENCHMARK.json``.
 
 A cell names a configuration (``configs[].file``) and a traffic mix
-(``traffic/<mix>.json``); each metric is ``metrics/<name>.py`` and each
-kernel's operation and byte count ``costs/<kernel>.py``; peaks are keyed
-by ``device_kind`` in ``peaks.json``. Adding a cell, a configuration, a
-mix or a metric adds files and entries and edits none.
+(``traffic/<mix>.json``); the configuration's ``model_type`` names its
+architecture, ``archs/<model_type>.py``; each metric is
+``metrics/<name>.py`` and each kernel's operation and byte count
+``costs/<kernel>.py``; peaks are keyed by ``device_kind`` in
+``peaks.json``. Adding a cell, a configuration, an architecture, a mix or
+a metric adds files and entries and edits none.
 """
 
 from __future__ import annotations
@@ -13,26 +15,46 @@ import dataclasses
 import importlib.util
 import json
 import os
-from typing import Dict, List
-
-from model_spec import ModelSpec, program_config, spec_from_file
+from types import ModuleType
+from typing import Any, Dict, List
 
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
+
+# what every archs/<model_type>.py exports (archs/__init__.py)
+ARCH_CONTRACT = ("spec", "program_config", "canonical", "program_params",
+                 "logits_at", "matmul_weights", "attn_flops_per_pair")
+
+
+def arch(model_type: str) -> ModuleType:
+    """``archs/<model_type>.py``: everything that depends on the
+    architecture."""
+    path = os.path.join(BENCH_DIR, "archs", f"{model_type}.py")
+    if (not model_type.isidentifier() or model_type.startswith("_")
+            or not os.path.isfile(path)):
+        raise ValueError(f"no architecture module bench/archs/"
+                         f"{model_type}.py for model_type {model_type!r}")
+    return importlib.import_module(f"archs.{model_type}")
 
 
 @dataclasses.dataclass
 class Cell:
+    """A cell; its architecture module and sizes follow from ``config``."""
     name: str
     chips: int
     config: Dict
     traffic: Dict
-    spec: ModelSpec
     end_to_end: List[Dict]
     per_layer: List[Dict]
+    arch: ModuleType = dataclasses.field(init=False)
+    spec: Any = dataclasses.field(init=False)
+
+    def __post_init__(self):
+        self.arch = arch(self.config["model_type"])
+        self.spec = self.arch.spec(self.config)
 
     def program_config(self):
-        return program_config(self.config, self.spec,
-                              page=self.traffic["page"])
+        return self.arch.program_config(self.config, self.spec,
+                                        page=self.traffic["page"])
 
     def metrics(self, trace: bool) -> List[Dict]:
         return self.per_layer if trace else self.end_to_end
@@ -60,7 +82,6 @@ def load_cell(root: str, bench: Dict, name: str) -> Cell:
                            w["traffic"] + ".json")) as f:
         traffic = json.load(f)
     return Cell(name, int(w["chips"]), config, traffic,
-                spec_from_file(config),
                 [m for m in bench["end_to_end"] if _applies(m, name)],
                 [m for m in bench["per_layer"] if _applies(m, name)])
 

@@ -6,8 +6,8 @@
 For each seed, in one process: a short window of the cell's own traffic
 and load through the timed path, then on the requests a run would compare
 (``check.sample``) the widest gap of the served tokens and the widest gap
-of the fp8 control's first choices (``reference.py``). One JSON line per
-seed. The benchmark's own runs never run the control.
+of the fp8 control's first choices (the architecture's ``logits_at``).
+One JSON line per seed. The benchmark's own runs never run the control.
 """
 
 import argparse
@@ -46,9 +46,8 @@ def main(argv=None) -> int:
         by = {r.rid: r for r in run.requests}
         rids = check.sample(tl, cell.traffic["check_tokens"], seed)
         items = [(by[r].prompt, tl.reqs[r].tokens) for r in rids]
-        prog, n = check.gaps(cell.spec, seed, cell.traffic, items)
-        ctl, _ = check.gaps(cell.spec, seed, cell.traffic, items,
-                            control=True)
+        prog, n = check.gaps(cell, seed, items)
+        ctl, _ = check.gaps(cell, seed, items, control=True)
         print(json.dumps({"workload": cell.name, "seed": seed,
                           "program_gap": prog, "control_gap": ctl,
                           "tokens": n, "requests": len(rids),

@@ -7,7 +7,8 @@ import harness
 
 def read(ctx):
     model = harness.cost("model")
-    flops = sum(model.decode_step(ctx.spec, (s.lengths + 1) * s.mask)
+    flops = sum(model.decode_step(ctx.arch, ctx.spec,
+                                   (s.lengths + 1) * s.mask)
                 for s in ctx.run.steps[ctx.tl.steps])
     t = sum(b - a for a, b in ctx.tl.decode_spans[ctx.tl.steps])
     if not t:

@@ -11,5 +11,5 @@ def read(ctx):
     t = sum(b - a for a, b, _ in adm)
     if not t:
         return None
-    flops = sum(model.prefill(ctx.spec, n) for _, _, n in adm)
+    flops = sum(model.prefill(ctx.arch, ctx.spec, n) for _, _, n in adm)
     return 100.0 * flops / (t * ctx.peaks["bf16_flops_per_s"])

@@ -62,7 +62,8 @@ class Ctx:
     """What a metric's ``read(ctx)`` may use."""
 
     def __init__(self, cell, run, tl, trace, peaks, setup_s):
-        self.cell, self.spec, self.traffic = cell, cell.spec, cell.traffic
+        self.cell, self.traffic = cell, cell.traffic
+        self.arch, self.spec = cell.arch, cell.spec
         self.run, self.tl, self.trace = run, tl, trace
         self.peaks, self.setup_s = peaks, setup_s
 

@@ -18,7 +18,10 @@ harness hooks it in three places, each named in PERF.md:
 * ``TapCache.__init__``, ``admit`` and ``retire`` note, on the harness's
   own reading of the host's wall clock, when the window's cache was made
   (the warm-up's cache comes first and is superseded), which slot each
-  admission took and which slot each retirement freed.
+  admission took and which slot each retirement freed. ``admit`` passes
+  on whatever the cache's own ``admit`` takes (a slot first) and reads
+  the admitted length from ``lengths[slot]`` after it, so a cache that
+  admits another kind of state is tapped unchanged.
 
 Every end-to-end time is the harness's own clock reading in these hooks.
 The program's ``obs`` spans (``serve_admit``, ``serve_decode_step``) are
@@ -37,7 +40,6 @@ import numpy as np
 
 import generator
 import trace_reduce
-import weights as weights_lib
 
 
 @dataclasses.dataclass
@@ -126,9 +128,9 @@ def _tap_classes(base_cache, rec: Recorder):
             super().__init__(*args, **kwargs)
             rec.on_new_cache()
 
-        def admit(self, slot, k_seq, v_seq, length, reserve_tokens):
-            super().admit(slot, k_seq, v_seq, length, reserve_tokens)
-            rec.on_admit(slot, length)
+        def admit(self, slot, *args, **kwargs):
+            super().admit(slot, *args, **kwargs)
+            rec.on_admit(slot, self.lengths[slot])
 
         def retire(self, slot):
             super().retire(slot)
@@ -210,7 +212,7 @@ def run(cell, *, seed: int, seconds: float, trace_dir: Optional[str],
     try:
         with shlib.use_sharding(mesh, overrides=dict(cfg.rule_overrides
                                                      or {})):
-            params = weights_lib.program_params(cell.spec, seed)
+            params = cell.arch.program_params(cell.spec, seed)
             _check_tree(params, model.abstract_params())
             jax.block_until_ready(params)
             with obs.span("bench_window") as anchor:

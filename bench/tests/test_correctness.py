@@ -14,7 +14,7 @@ import check
 import run as runmod
 import serve_driver
 import timeline
-from tiny import SECONDS, TINY_TRAFFIC, tiny_cell
+from tiny import SECONDS, tiny_cell
 
 SEED = 2**31 + 11           # wider than 32 bits, as a seed may be
 
@@ -81,8 +81,8 @@ def test_fp8_control_fails_the_limit():
     tl = timeline.build(r)
     by = {q.rid: q for q in r.requests}
     items = [(by[i].prompt, tl.reqs[i].tokens) for i in tl.reqs]
-    sound, _ = check.gaps(cell.spec, SEED, TINY_TRAFFIC, items)
-    ctl, n = check.gaps(cell.spec, SEED, TINY_TRAFFIC, items, control=True)
+    sound, _ = check.gaps(cell, SEED, items)
+    ctl, n = check.gaps(cell, SEED, items, control=True)
     limit = cell.config["limit_logit_gap"]
     assert n >= 30
     assert sound <= limit < ctl, (sound, limit, ctl)
@@ -90,11 +90,10 @@ def test_fp8_control_fails_the_limit():
 
 @pytest.mark.parametrize("bad_seed", [0, 2**40 + 3])
 def test_weights_follow_the_seed(bad_seed):
-    import weights
     cell = tiny_cell()
-    a = weights.canonical(cell.spec, bad_seed)["wq"]
-    b = weights.canonical(cell.spec, bad_seed)["wq"]
-    c = weights.canonical(cell.spec, bad_seed + 1)["wq"]
+    a = cell.arch.canonical(cell.spec, bad_seed)["wq"]
+    b = cell.arch.canonical(cell.spec, bad_seed)["wq"]
+    c = cell.arch.canonical(cell.spec, bad_seed + 1)["wq"]
     assert jnp.array_equal(a, b) and not jnp.array_equal(a, c)
 
 
@@ -105,24 +104,23 @@ def test_tied_embedding_is_the_lm_head():
 
     import numpy as np
 
-    import reference
-    import weights
+    from reference import Q_BLOCK
     from repro.models import build_model
     from tiny import TINY_CONFIG
     cell = tiny_cell()
+    arch = cell.arch
     tied = dataclasses.replace(cell.spec, tie_embeddings=True)
     conf = dict(TINY_CONFIG, tie_word_embeddings=True)
-    from model_spec import program_config, spec_from_file
-    assert spec_from_file(conf) == tied
-    cfg = program_config(conf, tied, page=16)
+    assert arch.spec(conf) == tied
+    cfg = arch.program_config(conf, tied, page=16)
     assert cfg.tie_embeddings
-    params = weights.program_params(tied, SEED)
+    params = arch.program_params(tied, SEED)
     serve_driver._check_tree(params, build_model(cfg).abstract_params())
-    w = weights.canonical(tied, SEED)
+    w = arch.canonical(tied, SEED)
     assert "unembed" not in w and "unembed" not in params
     untied = dict(w, unembed=w["embed"])
-    toks = jnp.arange(reference.Q_BLOCK, dtype=jnp.int32) % tied.vocab
+    toks = jnp.arange(Q_BLOCK, dtype=jnp.int32) % tied.vocab
     rows = jnp.array([3, 100], jnp.int32)
-    a = reference.logits_at(tied, w, toks, rows)
-    b = reference.logits_at(cell.spec, untied, toks, rows)
+    a = arch.logits_at(tied, w, toks, rows)
+    b = arch.logits_at(cell.spec, untied, toks, rows)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -4,12 +4,11 @@ count made by hand at a small shape."""
 import pytest
 
 import harness
-from model_spec import ModelSpec
+from archs._dense import Spec
 
-SPEC = ModelSpec(n_layers=2, d_model=8, n_heads=4, n_kv_heads=2,
-                 head_dim=4, d_ff=16, vocab=10, norm="rmsnorm",
-                 norm_eps=1e-6, act="swiglu", qkv_bias=True,
-                 rope_theta=1e4)
+SPEC = Spec(n_layers=2, d_model=8, n_heads=4, n_kv_heads=2, head_dim=4,
+            d_ff=16, vocab=10, norm="rmsnorm", norm_eps=1e-6, act="swiglu",
+            qkv_bias=True, rope_theta=1e4)
 
 
 def test_paged_attn_counts_only_live_pages():
@@ -43,23 +42,25 @@ def test_prefill_attn_causal_count():
 
 
 def test_model_flops_by_hand():
-    model = harness.cost("model")
+    model, qwen2 = harness.cost("model"), harness.arch("qwen2")
     # per layer: q 8*16, k 8*8, v 8*8, o 16*8, mlp 3*8*16
     per_layer = 128 + 64 + 64 + 128 + 384
-    assert model.matmul_weights_per_layer(SPEC) == per_layer
+    assert qwen2.matmul_weights(SPEC) == (2 * per_layer, 8 * 10)
+    # q k^T and p v: 2 * 4 heads * 4 lanes, two operations each, 2 layers
+    assert qwen2.attn_flops_per_pair(SPEC) == 2 * 64
     tok = 2 * (2 * per_layer + 8 * 10)
-    assert model.token_flops(SPEC) == tok
+    assert model.token_flops(qwen2, SPEC) == tok
     # two live rows (contexts 3 and 5), one idle: attention 4*4*4*n/layer
-    assert model.decode_step(SPEC, [3, 0, 5]) == \
+    assert model.decode_step(qwen2, SPEC, [3, 0, 5]) == \
         2 * tok + 2 * (64 * 3 + 64 * 5)
     # a prefill of 3 tokens: layers, causal attention, one head row
-    assert model.prefill(SPEC, 3) == \
+    assert model.prefill(qwen2, SPEC, 3) == \
         2 * 2 * per_layer * 3 + 2 * (2 * 16 * 3 * 4) + 2 * 8 * 10
 
 
 @pytest.mark.parametrize("act,mlp", [("swiglu", 3), ("gelu", 2)])
 def test_mlp_weights_by_activation(act, mlp):
     import dataclasses
-    model = harness.cost("model")
     s = dataclasses.replace(SPEC, act=act)
-    assert model.matmul_weights_per_layer(s) == 384 + mlp * 8 * 16
+    layers, _ = harness.arch("qwen2").matmul_weights(s)
+    assert layers == 2 * (384 + mlp * 8 * 16)

@@ -1,8 +1,8 @@
 """A cell small enough for the CPU: Qwen1.5's layer math at toy widths
-(the Pallas kernels run in the interpreter there)."""
+(the Pallas kernels run in the interpreter there), and a StarCoder2-shaped
+configuration at the same widths."""
 
 import harness
-from model_spec import spec_from_file
 
 # widths as small as the kernels' 128-lane words allow (two heads of 64
 # per word); the limit sits between the sound runs' widest gaps on the CPU
@@ -24,10 +24,21 @@ TINY_TRAFFIC = {
 }
 SECONDS = 6
 
+# LayerNorm, tanh-GELU with biases, GQA 4:1; read by its architecture
+# module, never served
+TINY_STARCODER2_CONFIG = {
+    "name": "tiny_starcoder2", "repro_arch": "starcoder2_15b",
+    "model_type": "starcoder2", "hidden_act": "gelu_pytorch_tanh",
+    "norm_type": "layer_norm", "norm_epsilon": 1e-5, "use_bias": True,
+    "hidden_size": 256, "intermediate_size": 512,
+    "num_attention_heads": 4, "num_key_value_heads": 1,
+    "num_hidden_layers": 2, "rope_theta": 1e5, "vocab_size": 512,
+    "tie_word_embeddings": False,
+}
+
 
 def tiny_cell(end_to_end=(), per_layer=()):
     return harness.Cell(
         "tiny.cell", 1, TINY_CONFIG, TINY_TRAFFIC,
-        spec_from_file(TINY_CONFIG),
         [{"name": n, "unit": "1"} for n in end_to_end],
         [{"name": n, "unit": "1"} for n in per_layer])
