@@ -38,13 +38,26 @@ class ArchConfig:
     n_shared_experts: int = 0
     top_k: int = 0
     moe_d_ff: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25             # 0: no capacity, drop no token
+                                              # (sorted, grouped experts)
+    norm_topk_prob: bool = True               # renormalise the top-k weights
+    routed_scaling_factor: float = 1.0        # scales the routed experts' sum
+    n_experts_held: int = 0                   # experts this chip holds (0:
+    expert_offset: int = 0                    # all), from expert_offset on
+    first_k_dense: int = 0                    # leading dense-FFN layers
 
     # MLA (deepseek)
     kv_lora_rank: int = 0
     qk_rope_dim: int = 64
     qk_nope_dim: int = 128
     v_head_dim: int = 128
+    # YaRN RoPE scaling (deepseek ``rope_scaling``); factor 1 = plain RoPE
+    rope_factor: float = 1.0
+    rope_original_max_pos: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
 
     # SSM / hybrid
     ssm_state: int = 0
@@ -100,6 +113,10 @@ class ArchConfig:
     @property
     def hd(self) -> int:
         return self.head_dim or self.d_model // self.n_heads
+
+    @property
+    def experts_held(self) -> int:
+        return self.n_experts_held or self.n_experts
 
     @property
     def padded_vocab(self) -> int:

@@ -249,6 +249,97 @@ def build_paged_program(b: int, kvh: int, g_pad: int, n_words: int,
     )
 
 
+def build_latent_program(b: int, g_pad: int, n_words: int, ppw: int,
+                         page: int, w: int, v_dim: int, *, scale: float,
+                         dtype=jnp.float32, kv_dtype=None, out_dtype=None,
+                         depth: int = 2, streams: int = 1) -> StreamProgram:
+    """Paged *latent* decode attention (MLA's absorbed form): the consumer
+    half of the ``paged_latent_attention`` StreamGraph.
+
+    Every query head of a row reads the row's one shared latent stream:
+    the gathered pages ``[B*n_words*ppw, page, w]`` hold a ``w``-lane row
+    per token (latent, then the rotated RoPE key, then zero padding to a
+    whole 128-lane word). Each word's ``ppw`` pages form one ``(ppw*page,
+    w)`` block: the ``g_pad`` query rows score it over all ``w`` lanes,
+    and the softmax weights sum its first ``v_dim`` lanes (the latent
+    itself is the value). The online softmax is that of
+    :func:`build_paged_program`; a word wholly past the row's length does
+    no compute and rows past it mask to ``-1e30``.
+    """
+    out_dtype = out_dtype or dtype
+    block_kv = ppw * page
+    kv_spec = Pipe(tile=(ppw, page, w), dtype=kv_dtype or dtype,
+                   depth=depth, streams=streams)
+
+    def kv_slicer(ctx, word):
+        return ctx.ref("kv").at[pl.ds(word * ppw, ppw)]
+
+    def consumer(ctx):
+        kj = ctx.g % n_words
+        length = ctx.ref("lengths")[ctx.g // n_words]
+        m_sc, l_sc = ctx.scratch("m"), ctx.scratch("l")
+        acc = ctx.scratch("acc")
+
+        @pl.when(kj == 0)
+        def _():
+            m_sc[...] = jnp.full_like(m_sc, _NEG_INF)
+            l_sc[...] = jnp.zeros_like(l_sc)
+            acc[...] = jnp.zeros_like(acc)
+
+        kv_start = kj * block_kv
+
+        @pl.when(kv_start < length)
+        def _():
+            q = ctx.ref("q")[0]                        # [g_pad, w]
+            kv = ctx.word("kv")[...].reshape(block_kv, w)
+            s_ = jax.lax.dot_general(
+                q, kv, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale   # [g_pad, bkv]
+            cols = kv_start + jax.lax.broadcasted_iota(
+                jnp.int32, (g_pad, block_kv), 1)
+            s_ = jnp.where(cols < length, s_, _NEG_INF)
+            m_prev = m_sc[:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s_, axis=1, keepdims=True))
+            p = jnp.exp(s_ - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_sc[...] = jnp.broadcast_to(
+                l_sc[:, :1] * alpha + jnp.sum(p, axis=1, keepdims=True),
+                l_sc.shape)
+            acc[...] = acc[...] * alpha + jnp.dot(
+                p.astype(kv.dtype), kv[:, :v_dim],
+                preferred_element_type=jnp.float32)
+            m_sc[...] = jnp.broadcast_to(m_new, m_sc.shape)
+
+        @pl.when(kj == n_words - 1)
+        def _():
+            l = l_sc[:, :1]
+            ctx.out[0] = (acc[...] / jnp.where(l == 0.0, 1.0, l)).astype(
+                out_dtype)
+
+    row_map = lambda g, lens: (g // n_words, 0, 0)
+    return StreamProgram(
+        name="ff_paged_latent_attention",
+        n_words=b * n_words,
+        inputs=(
+            ScalarIn("lengths"),
+            BlockIn("q", (1, g_pad, w), row_map, dtype=dtype),
+            # word v reads page block v of the gathered stream: the
+            # identity schedule an ff_gather producer writes (wpb=1)
+            Stream("kv", kv_spec, kv_slicer, index=lambda v: (v, 0, 0)),
+        ),
+        consumer=consumer,
+        out_shape=(b, g_pad, v_dim),
+        out_dtype=out_dtype,
+        out_block=(1, g_pad, v_dim),
+        out_index_map=row_map,
+        scratch=(
+            ScratchSpec("m", (g_pad, 128), jnp.float32),
+            ScratchSpec("l", (g_pad, 128), jnp.float32),
+            ScratchSpec("acc", (g_pad, v_dim), jnp.float32),
+        ),
+    )
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("block_kv", "depth", "streams", "scale", "lane_heads",

@@ -70,6 +70,23 @@ def paged_decode_attention_workload(b: int, h: int, kvh: int, n_pages: int,
     return w, (2 * ppw * page, p * d)
 
 
+def paged_latent_attention_workload(b: int, h: int, n_pages: int,
+                                    page: int, w: int, *, ppw: int = 1,
+                                    dtype=jnp.bfloat16
+                                    ) -> Tuple[Workload, Tuple[int, int]]:
+    """One word per (row, group of ``ppw`` latent pages): every one of
+    the row's ``h`` query heads scores the word's ``ppw * page`` rows of
+    ``w`` lanes and sums them (MLA's absorbed form, one shared stream)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    w_ = Workload(
+        n_words=b * cdiv(n_pages, ppw),
+        word_bytes=float(ppw * page * w * itemsize),
+        flops_per_word=4.0 * h * ppw * page * w,
+        regular=True,
+    )
+    return w_, (ppw * page, w)
+
+
 # KV-cache tile candidates for mode="autotune" (the cache stream's word
 # size; candidates not dividing the call site's S are skipped at measure)
 _TILE_OPTIONS = (

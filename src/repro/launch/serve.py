@@ -264,11 +264,20 @@ def run_continuous(model, params, cfg, requests: List[Request], *,
     # a single empty-pool admission must always fit, else admission stalls
     pool_blocks = max(pool_blocks, n_pages_max)
 
+    # MLA keeps one latent row per token; other attention keeps K and V
+    layout = (dict(latent_dim=cfg.kv_lora_rank + cfg.qk_rope_dim)
+              if cfg.kv_lora_rank else
+              dict(kv_heads=cfg.n_kv_heads, head_dim=cfg.hd))
+
     def fresh_cache():
         return PagedKVCache(
             n_layers=cfg.n_layers, n_blocks=pool_blocks, page=page,
-            kv_heads=cfg.n_kv_heads, head_dim=cfg.hd, n_slots=n_slots,
-            n_pages_max=n_pages_max, dtype=cfg.cdtype)
+            n_slots=n_slots, n_pages_max=n_pages_max, dtype=cfg.cdtype,
+            **layout)
+
+    def prompt_state(cache, pc):
+        """The prefill cache's entries the pool admits, batch row 0."""
+        return [pc[name][:, 0] for name in cache.streams]
 
     buckets = sorted({_bucket(len(r.prompt)) for r in requests})
 
@@ -276,7 +285,7 @@ def run_continuous(model, params, cfg, requests: List[Request], *,
     warm = fresh_cache()
     for i, pb in enumerate(buckets):
         _, wc = prefill(params, {"tokens": jnp.zeros((1, pb), jnp.int32)})
-        warm.admit(i % n_slots, wc["k"][:, 0], wc["v"][:, 0], 4, 4)
+        warm.admit(i % n_slots, *prompt_state(warm, wc), 4, 4)
         warm.retire(i % n_slots)
     jax.block_until_ready(decode(
         params, {"token": jnp.zeros((n_slots,), jnp.int32),
@@ -333,7 +342,7 @@ def run_continuous(model, params, cfg, requests: List[Request], *,
                     with obs.span("admit_launch"):
                         _, pc = prefill(params,
                                         {"tokens": jnp.asarray(toks)})
-                        kv.admit(slot, pc["k"][:, 0], pc["v"][:, 0], plen,
+                        kv.admit(slot, *prompt_state(kv, pc), plen,
                                  plen + r.max_new)
                     with obs.span("admit_wait"):
                         jax.block_until_ready(kv.pool)
@@ -369,6 +378,9 @@ def run_continuous(model, params, cfg, requests: List[Request], *,
                 nxt_np = np.asarray(nxt)
             if telemetry:
                 step.set(**obs.counters())
+                if "expert_counts" in new_caches:
+                    step.set(**_moe_attrs(cfg, new_caches["expert_counts"],
+                                          act))
         dt = time.perf_counter() - t0
         clock += dt
         decode_s += dt
@@ -402,6 +414,16 @@ def run_continuous(model, params, cfg, requests: List[Request], *,
     out["pool_blocks"] = pool_blocks
     out["page"] = page
     return out
+
+
+def _moe_attrs(cfg, assign, active) -> Dict[str, object]:
+    """A decode step's expert load over its active rows, from its
+    per-row assignments summed over the expert layers ([slots,
+    n_experts], read once the step's tokens are on the host):
+    ``moe_tokens`` per held expert and ``moe_assign`` over all experts."""
+    a = np.asarray(assign)[np.asarray(active, bool)].sum(axis=0)
+    held = a[cfg.expert_offset:cfg.expert_offset + cfg.experts_held]
+    return {"moe_tokens": [int(n) for n in held], "moe_assign": int(a.sum())}
 
 
 def _pages_max(requests: List[Request], page: int) -> int:

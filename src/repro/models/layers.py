@@ -301,6 +301,26 @@ def paged_decode_attention_op(q, kv_pool, block_tables, lengths, *,
                                            policy=_session_kernel_policy())
 
 
+def paged_latent_attention_op(q, pool, block_tables, lengths, *, v_dim: int,
+                              scale: float, impl: str = "xla"
+                              ) -> jnp.ndarray:
+    """MLA decode attention (absorbed form) through a paged latent pool.
+
+    q: [B,H,Dq] one token; pool: [nb, page, W] (one layer's latent rows,
+    ``runtime/paged_kv.py``); block_tables: [B, n_pages]; lengths: [B]
+    (0 = inactive slot). Returns [B,H,v_dim]. "xla" dereferences the
+    table densely; "ff" runs the fused gather->attention StreamGraph.
+    """
+    from repro.runtime import paged_kv
+    if impl == "xla":
+        return paged_kv.paged_latent_attention_ref(
+            q, pool, block_tables, lengths.astype(jnp.int32), v_dim=v_dim,
+            scale=scale)
+    return paged_kv.paged_latent_attention(
+        q, pool, block_tables, lengths, v_dim=v_dim, scale=scale,
+        policy=_session_kernel_policy())
+
+
 # ---------------------------------------------------------------------------
 # MLPs
 # ---------------------------------------------------------------------------

@@ -1,11 +1,19 @@
 """Mixture-of-experts FFN (grok-1, deepseek-v2-lite).
 
-Capacity-based top-k routing with scatter dispatch / gather combine:
-tokens are placed into a ``[E, C, d]`` dispatch buffer (expert-sharded under
-the "expert" rule — EP over the model axis), experts run as one batched
-einsum, and results gather back weighted by router probs. Overflow beyond
-capacity ``C = ceil(T/E * k * capacity_factor)`` is dropped (standard
-token-dropping MoE).
+Two routed layers behind one entry point (:func:`moe_ffn_apply`):
+
+* capacity-based top-k routing (``capacity_factor > 0``, grok): tokens are
+  placed into a ``[E, C, d]`` dispatch buffer (expert-sharded under the
+  "expert" rule — EP over the model axis), experts run as one batched
+  einsum, and results gather back weighted by router probs. Overflow
+  beyond capacity ``C = ceil(T/E * k * capacity_factor)`` is dropped
+  (standard token-dropping MoE).
+* no-drop routing over held experts (``capacity_factor == 0``, deepseek):
+  the router scores all ``n_experts`` in f32; the layer holds
+  ``experts_held`` of them (from ``expert_offset`` on: one chip's share of
+  an expert-parallel deployment) and computes only their part of the
+  result, for every token routed to them, plus the shared experts. Its
+  third output counts each token's assignments to every expert.
 
 Paper mapping: the dispatch/combine *is* the irregular-gather microbenchmark
 at system scale — under EP sharding XLA materializes it as all-to-alls, which
@@ -26,11 +34,13 @@ from repro.runtime.sharding import constrain, current
 
 
 def moe_ffn_specs(cfg: ArchConfig) -> Dict[str, Any]:
+    """The router over all ``n_experts``; weights of the held ones."""
     d, e, f = cfg.d_model, cfg.n_experts, cfg.moe_d_ff
+    held = cfg.experts_held
     s = {
         "router": L.ParamSpec((d, e), ("embed", None), scale=0.02),
-        "w1": L.ParamSpec((e, d, 2 * f), ("expert", "embed", "mlp")),
-        "w2": L.ParamSpec((e, f, d), ("expert", "mlp", "embed")),
+        "w1": L.ParamSpec((held, d, 2 * f), ("expert", "embed", "mlp")),
+        "w2": L.ParamSpec((held, f, d), ("expert", "mlp", "embed")),
     }
     if cfg.n_shared_experts:
         fs = cfg.n_shared_experts * cfg.moe_d_ff
@@ -150,8 +160,84 @@ def _local_dispatch_apply(cfg: ArchConfig, p, x
     return out, aux.astype(jnp.float32)
 
 
-def moe_ffn_apply(cfg: ArchConfig, p, x) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """x: [B,S,D] -> (out [B,S,D], aux load-balance loss)."""
+# rows of the grouped expert matmul's tile (``jax.lax.ragged_dot`` on a
+# TPU): at most this many tokens, every held expert's group would be
+# padded to a tile anyway, so one dense pass over the held experts does no
+# more work and launches no kernel
+_GROUP_ROWS = 512
+
+
+def _held_experts_dense(cfg: ArchConfig, p, xf, local, probs, mine):
+    """Every held expert on every token, each weighted by the token's
+    routing weight for it (0 where the token did not pick it)."""
+    dt = xf.dtype
+    held = cfg.experts_held
+    wte = jnp.sum(jnp.where(mine[..., None],
+                            probs[..., None] * jax.nn.one_hot(
+                                local, held, dtype=jnp.float32), 0.0),
+                  axis=1)                                      # [T, held]
+    h = jnp.einsum("td,edf->tef", xf, p["w1"].astype(dt))
+    gate, up = jnp.split(h, 2, axis=-1)
+    h = jax.nn.silu(gate) * up * wte[..., None].astype(dt)
+    return jnp.einsum("tef,efd->td", h, p["w2"].astype(dt))
+
+
+def _held_experts_grouped(cfg: ArchConfig, p, xf, local, probs, mine):
+    """The routed rows sorted by held expert and multiplied in groups
+    (``jax.lax.ragged_dot``); assignments to experts held elsewhere sort
+    last, outside every group, and add nothing."""
+    t, d = xf.shape
+    k, held = local.shape[1], cfg.experts_held
+    dt = xf.dtype
+    e_flat = jnp.where(mine, local, held).reshape(-1)          # [T*k]
+    order = jnp.argsort(e_flat, stable=True)
+    tok = order // k
+    sizes = jnp.bincount(e_flat, length=held + 1)[:held].astype(jnp.int32)
+    inside = (jnp.arange(t * k) < jnp.sum(sizes))[:, None]
+    h = jax.lax.ragged_dot(xf[tok], p["w1"].astype(dt), sizes)
+    gate, up = jnp.split(h, 2, axis=-1)
+    h = jnp.where(inside, jax.nn.silu(gate) * up, 0.0).astype(dt)
+    y = jax.lax.ragged_dot(h, p["w2"].astype(dt), sizes)
+    wt = jnp.where(mine, probs, 0.0).reshape(-1)[order]
+    y = jnp.where(inside, y * wt[:, None].astype(dt), 0.0).astype(dt)
+    return jnp.zeros((t, d), dt).at[tok].add(y)
+
+
+def _dropless_apply(cfg: ArchConfig, p, x, *, grouped=None):
+    """No-drop routing over the held experts: (out, aux, per-token
+    assignment counts over all experts [B*S, n_experts]). ``grouped``
+    forces the grouped (True) or dense (False) expert matmuls; by default
+    a batch of more than :data:`_GROUP_ROWS` tokens is grouped."""
+    b, s, d = x.shape
+    e, k = cfg.n_experts, cfg.top_k
+    t = b * s
+    xf = x.reshape(t, d)
+    gates = jax.nn.softmax(
+        (xf.astype(jnp.float32) @ p["router"].astype(jnp.float32)), axis=-1)
+    probs, idx = jax.lax.top_k(gates, k)                        # [T,k]
+    if cfg.norm_topk_prob:
+        probs = probs / jnp.sum(probs, axis=-1, keepdims=True)
+    probs = probs * cfg.routed_scaling_factor
+    assign = jnp.sum(jax.nn.one_hot(idx, e, dtype=jnp.int32), axis=1)
+    aux = e * jnp.sum(jnp.mean(gates, axis=0)
+                      * jnp.mean(assign.astype(jnp.float32), axis=0))
+    local = idx - cfg.expert_offset
+    mine = (local >= 0) & (local < cfg.experts_held)
+    if grouped is None:
+        grouped = t > _GROUP_ROWS
+    experts = _held_experts_grouped if grouped else _held_experts_dense
+    out = experts(cfg, p, xf, local, probs, mine).reshape(b, s, d)
+    if cfg.n_shared_experts:
+        out = out + L.mlp_apply(p["shared"], x, "swiglu")
+    return out, aux.astype(jnp.float32), assign
+
+
+def moe_ffn_apply(cfg: ArchConfig, p, x):
+    """x: [B,S,D] -> (out [B,S,D], aux load-balance loss); the no-drop
+    layer (``capacity_factor == 0``) adds each token's assignment counts
+    over all experts, [B*S, n_experts]."""
+    if not cfg.capacity_factor:
+        return _dropless_apply(cfg, p, x)
     if cfg.moe_local_dispatch:
         return _local_dispatch_apply(cfg, p, x)
     b, s, d = x.shape

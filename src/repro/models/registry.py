@@ -40,14 +40,22 @@ class BaseLM:
 
     def __init__(self, cfg: ArchConfig):
         self.cfg = cfg
+        k = cfg.first_k_dense
         self.stack = transformer.DecoderStack(
-            cfg,
+            cfg.replace(n_layers=cfg.n_layers - k) if k else cfg,
             mixer_specs=self._mixer_specs(),
             mixer_apply=self._mixer_apply(),
             mixer_cache_spec=self._mixer_cache_spec(),
             ffn_specs=self._ffn_specs(),
             ffn_apply=self._ffn_apply(),
         )
+        # leading dense-FFN layers (deepseek's first_k_dense_replace), a
+        # scanned stack of their own in front of the main one
+        self.dense_stack = transformer.DecoderStack(
+            cfg.replace(n_layers=k),
+            mixer_specs=self._mixer_specs(),
+            mixer_apply=self._mixer_apply(),
+            mixer_cache_spec=self._mixer_cache_spec()) if k else None
 
     # hooks ------------------------------------------------------------------
     def _mixer_specs(self):
@@ -73,6 +81,8 @@ class BaseLM:
             "stack": self.stack.specs(),
             "final_norm": L.norm_specs(cfg.norm, cfg.d_model),
         }
+        if self.dense_stack is not None:
+            s["dense_stack"] = self.dense_stack.specs()
         if not cfg.tie_embeddings:
             s["unembed"] = L.ParamSpec((cfg.padded_vocab, cfg.d_model),
                                        ("vocab", "embed"))
@@ -95,9 +105,64 @@ class BaseLM:
         n = self.param_count()
         if cfg.n_experts and cfg.top_k:
             per_expert = cfg.d_model * 3 * cfg.moe_d_ff
-            inactive = cfg.n_layers * (cfg.n_experts - cfg.top_k) * per_expert
+            inactive = (cfg.n_layers - cfg.first_k_dense) * max(
+                cfg.experts_held - cfg.top_k, 0) * per_expert
             n -= inactive
         return n
+
+    def _stacks(self, params, x, *, positions, caches=None, lengths=None,
+                want_cache: bool = False):
+        """The leading dense layers (if any), then the main stack. Caches
+        carry one leading layer axis over both (the dense layers first);
+        a decode step's per-row expert assignments (``expert_counts``) come
+        back summed over the layers, [B, n_experts]."""
+        k = self.cfg.first_k_dense
+        if isinstance(caches, dict):    # a step's output fed back in
+            caches = {n: c for n, c in caches.items() if n != "expert_counts"}
+        if k and caches is not None and "kv_pool" in caches:
+            return self._paged_stacks(params, x, positions=positions,
+                                      caches=caches, lengths=lengths)
+        parts = []
+        aux = jnp.zeros((), jnp.float32)
+        for stack, name, sl in ((self.dense_stack, "dense_stack",
+                                 slice(0, k)),
+                                (self.stack, "stack", slice(k, None))):
+            if stack is None:
+                continue
+            c = (None if caches is None
+                 else jax.tree.map(lambda a: a[sl], caches))
+            x, nc, a = stack(params[name], x, positions=positions, caches=c,
+                             lengths=lengths, want_cache=want_cache)
+            parts.append(nc)
+            aux = aux + a
+        assign = [p.pop("expert_counts") for p in parts
+                  if isinstance(p, dict) and "expert_counts" in p]
+        new = parts[0] if len(parts) == 1 else (
+            None if parts[-1] is None else jax.tree.map(
+                lambda *xs: jnp.concatenate(xs, axis=0), *parts))
+        if assign:
+            new["expert_counts"] = sum(jnp.sum(a, axis=0) for a in assign)
+        return x, new, aux
+
+    def _paged_stacks(self, params, x, *, positions, caches, lengths):
+        """A decode step through one paged pool over both stacks, each
+        layer updating its own pool layer in place."""
+        pool, bt = caches["kv_pool"], caches["block_tables"]
+        aux = jnp.zeros((), jnp.float32)
+        assign = []
+        for stack, name, first in ((self.dense_stack, "dense_stack", 0),
+                                   (self.stack, "stack",
+                                    self.cfg.first_k_dense)):
+            x, pool, a, au = stack.decode_pool(
+                params[name], x, positions=positions, pool=pool,
+                block_tables=bt, lengths=lengths, first=first)
+            aux = aux + au
+            if a is not None:
+                assign.append(jnp.sum(a, axis=0))
+        new = {"kv_pool": pool, "block_tables": bt}
+        if assign:
+            new["expert_counts"] = sum(assign)
+        return x, new, aux
 
     # forward ------------------------------------------------------------------
     def _extra_embeds(self, params, batch) -> Optional[jnp.ndarray]:
@@ -112,8 +177,8 @@ class BaseLM:
             x = jnp.concatenate([extra.astype(cfg.cdtype), x], axis=1)
             n_extra = extra.shape[1]
         positions = jnp.arange(x.shape[1])
-        x, caches, aux = self.stack(params["stack"], x, positions=positions,
-                                    want_cache=want_cache)
+        x, caches, aux = self._stacks(params, x, positions=positions,
+                                      want_cache=want_cache)
         x = L.norm_apply(cfg.norm, x, params["final_norm"])
         return x, caches, aux, n_extra
 
@@ -145,8 +210,8 @@ class BaseLM:
         lengths = batch["lengths"].astype(jnp.int32)
         x = L.embed_lookup(params["embed"], tok[:, None], cfg.cdtype)
         positions = lengths[:, None]
-        x, new_caches, _ = self.stack(params["stack"], x, positions=positions,
-                                      caches=caches, lengths=lengths)
+        x, new_caches, _ = self._stacks(params, x, positions=positions,
+                                        caches=caches, lengths=lengths)
         x = L.norm_apply(cfg.norm, x, params["final_norm"])
         logits = L.unembed_logits(x, self._unembed(params))[:, 0]
         return logits, new_caches
@@ -169,7 +234,9 @@ class BaseLM:
         return {"token": ("batch",), "lengths": ("batch",)}
 
     def cache_spec(self, shape: ShapeConfig):
-        return self.stack.cache_spec(shape.global_batch, shape.seq_len)
+        return transformer.DecoderStack(
+            self.cfg, mixer_cache_spec=self._mixer_cache_spec()).cache_spec(
+                shape.global_batch, shape.seq_len)
 
 
 class DenseLM(BaseLM):

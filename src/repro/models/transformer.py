@@ -198,7 +198,11 @@ class DecoderStack:
         attn_out = checkpoint_name(attn_out, "attn_out")
         x = x + attn_out
         h = L.norm_apply(cfg.norm, x, p["norm2"])
-        ffn_out, aux = self._ffn_apply(cfg, p["ffn"], h)
+        ffn_out, aux, *routed = self._ffn_apply(cfg, p["ffn"], h)
+        if routed and cache is not None:
+            # a decode step hands out each row's expert assignments
+            new_cache = {**new_cache,
+                         "expert_counts": routed[0].reshape(x.shape[0], -1)}
         ffn_out = checkpoint_name(ffn_out, "ffn_out")
         x = x + ffn_out
         # residual saves use the SP axis (None by default; "model" enables
@@ -260,6 +264,32 @@ class DecoderStack:
         else:
             policy = jax.checkpoint_policies.nothing_saveable
         return jax.checkpoint(fn, policy=policy, static_argnums=(5,))
+
+    def decode_pool(self, params, x, *, positions, pool, block_tables,
+                    lengths, first: int):
+        """Decode one token through a paged pool that spans more layers
+        than this stack: layer ``i`` reads and writes pool layer ``first +
+        i``, in place on the scan's carry, so the pool is never sliced
+        apart and stacked again. Returns (x, pool, per-layer
+        ``expert_counts`` or None, aux)."""
+        layer = self._remat_layer()
+
+        def body(carry, xs):
+            xx, pool, aux = carry
+            p, j = xs
+            cache = {"kv_pool": jax.lax.dynamic_index_in_dim(
+                         pool, j, keepdims=False),
+                     "block_tables": jax.lax.dynamic_index_in_dim(
+                         block_tables, j, keepdims=False)}
+            xx, nc, a = layer(p, xx, positions, cache, lengths, True)
+            pool = jax.lax.dynamic_update_index_in_dim(pool, nc["kv_pool"],
+                                                       j, 0)
+            return (xx, pool, aux + a), nc.get("expert_counts")
+
+        (x, pool, aux), assign = jax.lax.scan(
+            body, (x, pool, jnp.zeros((), jnp.float32)),
+            (params["layers"], first + jnp.arange(self.cfg.n_layers)))
+        return x, pool, assign, aux
 
     def __call__(self, params, x, *, positions, caches=None, lengths=None,
                  want_cache: bool = False):

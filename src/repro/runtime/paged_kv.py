@@ -31,6 +31,14 @@ the serving cache around fixed-size KV blocks (pages):
     VMEM ring and never round-trip HBM. Tuned jointly via
     :func:`repro.core.autotune.resolve_graph`.
 
+A second, *latent* layout serves MLA (deepseek-v2): ``[L, n_blocks,
+page, W]``, one ``W``-lane row per token (the ``kv_lora_rank`` latent,
+then the rotated RoPE key, zero-padded to whole 128-lane words) shared by
+every head, with its own scatters and the registered
+``paged_latent_attention`` graph (the same ``ff_gather`` page producer
+feeding an absorbed-form consumer that scores all query heads of a row
+against one page stream and sums its latent lanes).
+
 The consumer's softmax math is identical to the contiguous
 ``ff_decode_attention`` kernel at ``block_kv == ppw * page`` (same lane
 packing, same tile order, same f32 accumulation), so paged decode is
@@ -113,10 +121,12 @@ _WORD_BYTES = 256 * 1024
 _RING_SLOTS = 17 + 2
 
 
-def pages_per_word(n_pages: int, page: int, lanes: int, dtype) -> int:
+def pages_per_word(n_pages: int, page: int, lanes: int, dtype, *,
+                   page_streams: int = 2) -> int:
     """``ppw``: KV pages per grid word of the ``paged_decode_attention``
     graph for rows of ``n_pages`` pages of ``page`` tokens in ``lanes``-wide
-    lane words of ``dtype``.
+    lane words of ``dtype``; a page holds ``page_streams`` blocks of
+    ``page`` rows (K and V; 1 for a latent page).
 
     Words hold at most ``cap`` pages, about :data:`_WORD_BYTES` of K and
     V; a row's pages spread evenly over ``ceil(n_pages / cap)`` words (under
@@ -126,7 +136,7 @@ def pages_per_word(n_pages: int, page: int, lanes: int, dtype) -> int:
     words, its last padded with sentinel pages.
     """
     assert n_pages >= 1, n_pages
-    page_bytes = 2 * page * lanes * jnp.dtype(dtype).itemsize
+    page_bytes = page_streams * page * lanes * jnp.dtype(dtype).itemsize
     word_bytes = min(_WORD_BYTES,
                      DEFAULT_VMEM_BUDGET_BYTES // 2 // _RING_SLOTS)
     cap = max(1, word_bytes // page_bytes)
@@ -382,6 +392,181 @@ def scatter_token(pool_layer, block_tables, lengths, k_new, v_new,
 
 
 # ---------------------------------------------------------------------------
+# Latent layout (MLA): one shared row per token
+# ---------------------------------------------------------------------------
+
+
+def latent_lanes(latent_dim: int) -> int:
+    """``W``: a latent row's width, rounded up to whole 128-lane words."""
+    return -(-latent_dim // 128) * 128
+
+
+def _latent_rows(c, k_rope, w: int):
+    """``[..., r]`` latent and ``[..., rope]`` key -> ``[..., W]`` rows."""
+    pad = w - c.shape[-1] - k_rope.shape[-1]
+    return jnp.concatenate(
+        [c, k_rope, jnp.zeros((*c.shape[:-1], pad), c.dtype)], axis=-1)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,),
+                   static_argnames=("page", "n_blocks"))
+def scatter_latent_prefill(pool, c, k_rope, block_tables, lengths, *,
+                           page: int, n_blocks: int):
+    """Write prefill latents into the latent pool through the block
+    tables. pool: [L, nb, page, W]; c: [L, B, S_p, r]; k_rope: [L, B, S_p,
+    rope] (rotated); positions past ``lengths`` drop."""
+    s_p = c.shape[2]
+    pos = jnp.arange(s_p)
+    bt = jnp.asarray(block_tables, jnp.int32)
+    blk = bt[:, jnp.clip(pos // page, 0, bt.shape[1] - 1)]     # [B, S_p]
+    blk = jnp.where(pos[None] < lengths[:, None], blk, n_blocks)
+    off = jnp.broadcast_to(pos % page, blk.shape)
+    rows = _latent_rows(c, k_rope, pool.shape[-1]).astype(pool.dtype)
+    return pool.at[:, blk, off].set(rows, mode="drop")
+
+
+def scatter_latent_token(pool_layer, block_tables, lengths, c_new,
+                         k_rope_new):
+    """Append one token's latent row at position ``lengths`` (per row)
+    into one layer's latent pool [nb, page, W]; c_new: [B, r], k_rope_new:
+    [B, rope]. Sentinel table entries (>= nb) drop the write."""
+    nb, page, w = pool_layer.shape
+    b = c_new.shape[0]
+    bt = jnp.asarray(block_tables, jnp.int32)
+    blk = bt[jnp.arange(b), jnp.clip(lengths // page, 0, bt.shape[1] - 1)]
+    rows = _latent_rows(c_new, k_rope_new, w).astype(pool_layer.dtype)
+    return pool_layer.at[blk, lengths % page].set(rows, mode="drop")
+
+
+def paged_latent_attention_ref(q, pool, block_tables, lengths, *,
+                               v_dim: int, scale: float):
+    """XLA oracle of the absorbed form: every head of row ``b`` scores the
+    row's cached latent rows over q's lanes and sums their first
+    ``v_dim`` lanes. q: [B, H, Dq] (Dq <= W); pool: [nb, page, W]."""
+    nb, page, _ = pool.shape
+    b, _, dq = q.shape
+    bt = jnp.clip(jnp.asarray(block_tables, jnp.int32), 0, nb - 1)
+    rows = pool[bt].reshape(b, -1, pool.shape[-1]).astype(jnp.float32)
+    s_ = jnp.einsum("bhd,bsd->bhs", q.astype(jnp.float32),
+                    rows[..., :dq]) * scale
+    cols = jnp.arange(rows.shape[1])
+    s_ = jnp.where(cols[None, None] < lengths[:, None, None], s_, -1e30)
+    out = jnp.einsum("bhs,bsd->bhd", jax.nn.softmax(s_, axis=-1),
+                     rows[..., :v_dim])
+    out = jnp.where(lengths[:, None, None] > 0, out, 0.0)
+    return out.astype(q.dtype)
+
+
+def build_paged_latent_graph(*, b: int, g_pad: int, n_pages: int, page: int,
+                             w: int, v_dim: int, scale: float,
+                             dtype=jnp.float32, kv_dtype=None,
+                             depth: int = 2, streams: int = 1,
+                             ppw: Optional[int] = None):
+    """Declare the paged latent StreamGraph at one shape point: the
+    ``ff_gather`` producer moving ``ppw`` ``(page, W)`` latent pages per
+    word, fused into the absorbed-form consumer over a ``(ppw, page, W)``
+    edge. The grid runs ``b * ceil(n_pages / ppw)`` words."""
+    from repro.core.graph import GraphEdge, GraphNode, StreamGraph
+    from repro.kernels.ff_decode_attention.kernel import \
+        build_latent_program
+    from repro.kernels.ff_decode_attention.ops import \
+        paged_latent_attention_workload
+    from repro.kernels.ff_gather.kernel import build_program as gather_prog
+    from repro.kernels.ff_gather.ops import gather_workload
+
+    kv_dtype = kv_dtype or dtype
+    if ppw is None:
+        ppw = pages_per_word(n_pages, page, w, kv_dtype, page_streams=1)
+    n_words = -(-n_pages // ppw)
+    n = b * n_words * ppw
+    gather = gather_prog(n, w, block_rows=page, blocks_per_word=ppw,
+                         dtype=kv_dtype, depth=depth)
+    attn = build_latent_program(b, g_pad, n_words, ppw, page, w, v_dim,
+                                scale=scale, dtype=dtype, kv_dtype=kv_dtype,
+                                depth=depth, streams=streams)
+    w_g, t_g = gather_workload(n, w, block_rows=page, blocks_per_word=ppw,
+                               dtype=kv_dtype)
+    w_a, t_a = paged_latent_attention_workload(b, g_pad, n_pages, page, w,
+                                               ppw=ppw, dtype=kv_dtype)
+    return StreamGraph(
+        name="paged_latent_attention",
+        nodes=(
+            GraphNode("gather", gather, workload=w_g, plan_tile=t_g),
+            GraphNode("attn", attn, workload=w_a, plan_tile=t_a),
+        ),
+        edges=(
+            GraphEdge("gather", "attn", "kv", reshape=(n, page, w)),
+        ),
+    )
+
+
+def paged_latent_attention(q, pool, block_tables, lengths, *, v_dim: int,
+                           scale: float, policy=None,
+                           ppw: Optional[int] = None) -> jnp.ndarray:
+    """MLA decode attention for one new token per row, absorbed form,
+    through the latent pool's block table (the fused gather -> attention
+    graph; a ``ref`` policy runs the oracle).
+
+    q: [B, H, Dq] (``[q_nope W_UK^T, q_rope]``, Dq <= W); pool: [nb,
+    page, W] (one layer); block_tables: [B, n_pages]; lengths: [B] (0 =
+    idle slot). Returns [B, H, v_dim]: each head's softmax-weighted sum of
+    the latent lanes. ``ppw`` defaults to :func:`pages_per_word` at this
+    shape.
+    """
+    policy = current_policy() if policy is None else policy
+    lens = lengths.astype(jnp.int32)
+    if policy.mode == "ref":
+        return paged_latent_attention_ref(q, pool, block_tables, lens,
+                                          v_dim=v_dim, scale=scale)
+    b, h, dq = q.shape
+    nb, page, w = pool.shape
+    n_pages = block_tables.shape[-1]
+    if ppw is None:
+        ppw = pages_per_word(n_pages, page, w, pool.dtype, page_streams=1)
+    g_pad = -(-h // 8) * 8
+    qp = jnp.pad(q, ((0, 0), (0, g_pad - h), (0, w - dq)))
+    idx = gather_indices(block_tables, lane_words=1, n_blocks=nb, ppw=ppw)
+
+    def build(depth=2, streams=1):
+        return build_paged_latent_graph(
+            b=b, g_pad=g_pad, n_pages=n_pages, page=page, w=w, v_dim=v_dim,
+            scale=scale, dtype=qp.dtype, kv_dtype=pool.dtype, depth=depth,
+            streams=streams, ppw=ppw)
+
+    from repro.core import graph as graphlib
+    g0 = build()
+    wl, tile = graphlib.graph_workload(g0)
+
+    def runner(tk, depth, streams):
+        cg = graphlib.compile_graph(
+            build(depth=depth, streams=streams),
+            policy=policy.replace(mode="ff", depth=depth, streams=streams))
+        return lambda: cg(idx, pool, lens, qp)
+
+    choice = autotune.resolve_graph(
+        "paged_latent_attention", policy, workload=wl, tile=tile,
+        dtype=pool.dtype, signature=graphlib.graph_signature(g0),
+        workload_fn=lambda tk: graphlib.graph_workload(build()),
+        runner=None if autotune.has_tracers(q, pool, block_tables, lens)
+        else runner,
+        site={"b": b, "h": h, "dq": dq, "n_pages": n_pages, "page": page,
+              "w": w, "v_dim": v_dim, "n_blocks": nb, "ppw": ppw,
+              "q_dtype": str(q.dtype)},
+        site_dynamic=("b", "n_pages", "n_blocks"))
+    mode = "ff" if policy.mode == "autotune" else policy.mode
+    n_words = -(-n_pages // ppw)
+    # the grid this call runs, in the trace of the build
+    with obs.span("latent_attn_grid", grid_words=b * n_words, ppw=ppw,
+                  words_per_row=n_words, rows=b, n_pages=n_pages, page=page,
+                  lanes=w):
+        cg = graphlib.compile_graph(
+            build(depth=choice.depth, streams=choice.streams),
+            policy=policy.replace(mode=mode, depth=choice.depth,
+                                  streams=choice.streams))
+    return cg(idx, pool, lens, qp)[:, :h]
+
+
+# ---------------------------------------------------------------------------
 # Host-side allocator + cache
 # ---------------------------------------------------------------------------
 
@@ -433,20 +618,33 @@ class PagedKVCache:
     page, lanes], "block_tables": [L, B, n_pages]}``. Unallocated table
     entries hold the sentinel id ``n_blocks`` (scatters drop, gathers clip
     + mask).
+
+    With ``latent_dim`` (MLA) the pool is the latent layout ``[L,
+    n_blocks, page, W]`` (:func:`latent_lanes`) and ``kv_heads`` /
+    ``head_dim`` are not used. ``streams`` names the prefill cache entries
+    :meth:`admit` takes, in order: ``("k", "v")`` or ``("c", "k_rope")``.
     """
 
     def __init__(self, *, n_layers: int, n_blocks: int, page: int,
-                 kv_heads: int, head_dim: int, n_slots: int,
-                 n_pages_max: int, dtype=jnp.float32):
+                 n_slots: int, n_pages_max: int, kv_heads: int = 0,
+                 head_dim: int = 0, latent_dim: int = 0,
+                 dtype=jnp.float32):
         self.n_layers = n_layers
         self.n_blocks = n_blocks
         self.page = page
         self.kv_heads = kv_heads
         self.head_dim = head_dim
+        self.latent_dim = latent_dim
         self.n_slots = n_slots
         self.n_pages_max = n_pages_max
-        j, lanes = pool_lanes(kv_heads, head_dim)
-        self.pool = jnp.zeros((n_layers, n_blocks, j, 2, page, lanes), dtype)
+        if latent_dim:
+            self.streams = ("c", "k_rope")
+            shape = (n_layers, n_blocks, page, latent_lanes(latent_dim))
+        else:
+            self.streams = ("k", "v")
+            j, lanes = pool_lanes(kv_heads, head_dim)
+            shape = (n_layers, n_blocks, j, 2, page, lanes)
+        self.pool = jnp.zeros(shape, dtype)
         self.allocator = BlockAllocator(n_blocks)
         # host bookkeeping: per-slot block ids / lengths (sentinel-filled)
         self._tables = np.full((n_slots, n_pages_max), n_blocks, np.int32)
@@ -459,9 +657,11 @@ class PagedKVCache:
     def admit(self, slot: int, k_seq, v_seq, length: int,
               reserve_tokens: int) -> None:
         """Claim ``ceil(reserve_tokens / page)`` blocks for ``slot`` and
-        scatter the prompt KV (``k_seq``/``v_seq``: [L, S_p, KVH, hd],
-        valid prefix ``length``). Raises :class:`OutOfBlocks` atomically
-        (no partial allocation) when the pool cannot hold the reservation.
+        scatter the prompt KV (``k_seq``/``v_seq``: [L, S_p, KVH, hd];
+        in the latent layout the latent [L, S_p, r] and the rotated RoPE
+        key [L, S_p, rope]; valid prefix ``length``). Raises
+        :class:`OutOfBlocks` atomically (no partial allocation) when the
+        pool cannot hold the reservation.
         """
         assert not self._owned[slot], f"slot {slot} already occupied"
         n_pages = -(-int(reserve_tokens) // self.page)
@@ -477,7 +677,9 @@ class PagedKVCache:
         self._live_tokens += int(length)
         bt = jnp.asarray(self._tables[slot:slot + 1])
         lens = jnp.asarray([length], jnp.int32)
-        self.pool = scatter_prefill(
+        scatter = scatter_latent_prefill if self.latent_dim \
+            else scatter_prefill
+        self.pool = scatter(
             self.pool, k_seq[:, None], v_seq[:, None], bt, lens,
             page=self.page, n_blocks=self.n_blocks)
 
@@ -677,3 +879,95 @@ def _register_paged_graph():
 
 
 _register_paged_graph()
+
+
+# b=2 rows of 4 query heads (padded to 8) over a 12-block latent pool of
+# 128-lane rows (a 96-lane latent and a 32-lane key), page 16, 4 pages a
+# row; the tables come from a permutation, one row one page short
+_LATENT_SMOKE = dict(b=2, h=4, n_pages=4, page=16, w=128, v_dim=96, nb=12,
+                     scale=0.125)
+
+
+def _latent_build(*, depth: int = 2, streams: int = 1):
+    c = _LATENT_SMOKE
+    return build_paged_latent_graph(
+        b=c["b"], g_pad=8, n_pages=c["n_pages"], page=c["page"], w=c["w"],
+        v_dim=c["v_dim"], scale=c["scale"], depth=depth, streams=streams)
+
+
+def _latent_inputs(key):
+    """Operands in CompiledGraph.arg_names order: (gather.idx,
+    gather.table, attn.lengths, attn.q) — q padded to 8 heads."""
+    c = _LATENT_SMOKE
+    pool = jax.random.normal(key, (c["nb"], c["page"], c["w"]), jnp.float32)
+    bt = jax.random.permutation(jax.random.fold_in(key, 1), c["nb"])[
+        :c["b"] * c["n_pages"]].reshape(c["b"], c["n_pages"])
+    ppw = pages_per_word(c["n_pages"], c["page"], c["w"], jnp.float32,
+                         page_streams=1)
+    idx = gather_indices(bt.astype(jnp.int32), lane_words=1,
+                         n_blocks=c["nb"], ppw=ppw)
+    lens = jnp.array([41, c["n_pages"] * c["page"]], jnp.int32)
+    q = 0.3 * jax.random.normal(jax.random.fold_in(key, 2),
+                                (c["b"], 8, c["w"]), jnp.float32)
+    return (idx, pool, lens, q)
+
+
+def _latent_ref(idx, table, lengths, q):
+    c = _LATENT_SMOKE
+    n = idx.shape[0] // c["b"]
+    bt = jnp.arange(c["b"] * n, dtype=jnp.int32).reshape(c["b"], n)
+    return paged_latent_attention_ref(
+        q, table[idx], bt, lengths, v_dim=c["v_dim"], scale=c["scale"])
+
+
+def _latent_unfused(idx, table, lengths, q):
+    """The page gather as its own ``repro.ops`` call (the pages round-trip
+    HBM), then the absorbed attention on XLA."""
+    import repro
+
+    n_tab, rows, lanes = table.shape
+    pages = repro.ops.gather(table.reshape(n_tab, rows * lanes), idx)
+    return _latent_ref(jnp.arange(idx.shape[0], dtype=jnp.int32),
+                       pages.reshape(-1, rows, lanes), lengths, q)
+
+
+def _latent_sweep_inputs(key, site):
+    """Rebuild paged_latent_attention operands at a recorded call-site
+    shape (plan sweep), with the site's pages per word."""
+    b, h, dq = int(site["b"]), int(site["h"]), int(site["dq"])
+    n_pages, page = int(site["n_pages"]), int(site["page"])
+    w, nb = int(site["w"]), int(site["n_blocks"])
+    kv_dt = jnp.dtype(site.get("dtype", "float32"))
+    q = 0.3 * jax.random.normal(key, (b, h, dq),
+                                jnp.dtype(site.get("q_dtype", "float32")))
+    pool = jax.random.normal(jax.random.fold_in(key, 1), (nb, page, w),
+                             kv_dt)
+    bt = (jax.random.permutation(jax.random.fold_in(key, 2),
+                                 max(nb, b * n_pages))[:b * n_pages]
+          % nb).reshape(b, n_pages).astype(jnp.int32)
+    lens = jnp.full((b,), n_pages * page, jnp.int32)
+    return (q, pool, bt, lens), {"v_dim": int(site["v_dim"]),
+                                 "scale": dq ** -0.5,
+                                 "ppw": int(site["ppw"])}
+
+
+def _register_latent_graph():
+    from repro.kernels.registry import register_graph
+
+    register_graph(
+        name="paged_latent_attention",
+        build=_latent_build,
+        make_inputs=_latent_inputs,
+        ref=_latent_ref,
+        unfused=_latent_unfused,
+        tile_options=(),
+        tol=2e-4,
+        doc="block-table latent page gather -> MLA absorbed decode "
+            "attention: every query head of a row against one shared "
+            "latent stream (MQA with one wide KV head)",
+        op=paged_latent_attention,
+        sweep_inputs=_latent_sweep_inputs,
+    )
+
+
+_register_latent_graph()

@@ -106,7 +106,7 @@ def test_assigned_dims_exact():
         "llama3_2_1b": (16, 2048, 32, 8, 8192, 128256),
         "qwen1_5_0p5b": (24, 1024, 16, 16, 2816, 151936),
         "grok1_314b": (64, 6144, 48, 8, 32768, 131072),
-        "deepseek_v2_lite_16b": (27, 2048, 16, 16, 1408, 102400),
+        "deepseek_v2_lite_16b": (27, 2048, 16, 16, 10944, 102400),
         "whisper_tiny": (4, 384, 6, 6, 1536, 51865),
         "internvl2_1b": (24, 896, 14, 2, 4864, 151655),
         "rwkv6_7b": (32, 4096, 64, 64, 14336, 65536),
@@ -122,6 +122,8 @@ def test_assigned_dims_exact():
     ds = get_config("deepseek_v2_lite_16b")
     assert ds.kv_lora_rank == 512 and ds.n_experts == 64 and ds.top_k == 6
     assert ds.n_shared_experts == 2
+    # the published dense width is layer 0's; the experts are 1408 wide
+    assert ds.moe_d_ff == 1408 and ds.first_k_dense == 1
 
 
 def test_shape_set_exact():

@@ -109,6 +109,26 @@ def test_paged_decode_graph_compiles(one_chip, model):
     assert "tpu_custom_call" in text
 
 
+def test_paged_latent_graph_compiles(one_chip):
+    """DeepSeek-V2-Lite's absorbed MLA decode: 16 heads of a 576-lane
+    query ([q_lat 512, q_rope 64]) against one latent stream of 640-lane
+    rows (576 padded to whole 128-lane words), 512 value lanes, at the
+    table width of its benchmark cell (9216 tokens, page 16)."""
+    from repro.runtime.paged_kv import latent_lanes, paged_latent_attention
+    b, h, dq, v_dim, n_pages = B, 16, 512 + 64, 512, 576
+    w = latent_lanes(dq)
+    assert w == 640
+
+    def f(q, pool, bt, lens):
+        return paged_latent_attention(q, pool, bt, lens, v_dim=v_dim,
+                                      scale=0.11, policy=compiled())
+
+    text = _compile_text(f, one_chip, ((b, h, dq), BF16),
+                         ((b * n_pages, PAGE, w), BF16),
+                         ((b, n_pages), jnp.int32), ((b,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
 def test_gather_compiles(one_chip):
     import repro
 
